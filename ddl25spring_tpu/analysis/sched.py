@@ -539,8 +539,12 @@ def check_schedule_safety(
             })
 
     # (3) one channel_id, different participant groups: the rendezvous
-    # identity is shared but the participant sets disagree
-    for ch, chops in by_channel.items():
+    # identity is shared but the participant sets disagree.  Judged only
+    # where the ids identify anything: jax 0.9.0 lowers EVERY shard_map
+    # collective with channel_id=1 (a cross-partition marker; the
+    # runtime orders the sites by program position), so a module whose
+    # sites all carry one id names no rendezvous by it
+    for ch, chops in by_channel.items() if len(by_channel) > 1 else ():
         keys = {_groups_key(o) for o in chops}
         if len(keys) > 1:
             hazards.append({

@@ -51,6 +51,27 @@ def load_native_lib(lib_name: str) -> ctypes.CDLL:
         raise NativeLoaderUnavailable(f"loading {so} failed: {e}") from e
 
 
+def rebuild_native_libs() -> list[str]:
+    """The strict path (chip runs — ``chip_smoke.py`` calls it first):
+    rebuild EVERY native library from ``native/*.cc`` and
+    ``native/Makefile``, the files git commits, whatever already sits in
+    ``native/`` (the ``.so`` files are git-ignored build outputs and may
+    be stale).  A failed build raises ``RuntimeError`` — an error, not a
+    slower loader.  Returns the libraries built; :func:`load_native_lib`
+    then finds them."""
+    try:
+        subprocess.run(
+            ["make", "-B", "-C", str(_NATIVE_DIR), "all"],
+            check=True, capture_output=True, text=True,
+        )
+    except (OSError, subprocess.CalledProcessError) as e:
+        detail = getattr(e, "stderr", "") or str(e)
+        raise RuntimeError(
+            f"building the native libraries failed: {detail}"
+        ) from e
+    return sorted(p.name for p in _NATIVE_DIR.glob("*.so"))
+
+
 def _load_lib():
     global _lib
     with _lock:

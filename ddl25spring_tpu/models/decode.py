@@ -35,9 +35,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import pcast
 
 from ddl25spring_tpu.models import llama
-from ddl25spring_tpu.utils.compat import pcast
 from ddl25spring_tpu.utils.config import LlamaConfig
 
 Params = dict[str, Any]
@@ -360,7 +360,7 @@ def make_tp_generate(
     :func:`generate` — pinned in ``tests/test_decode.py``."""
     from functools import partial as _partial
 
-    from ddl25spring_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ddl25spring_tpu.parallel.tp import tp_param_specs

@@ -172,18 +172,21 @@ def block_forward(
         if cfg.use_flash:
 
             def attn_fn(q, k, v, dtype):
-                from ddl25spring_tpu.ops.flash_attention import flash_attention
+                from ddl25spring_tpu.ops.flash_attention import (
+                    flash_attention,
+                    off_tpu,
+                )
 
                 # Off-TPU the kernel runs in Pallas interpret mode, which
                 # cannot execute inside shard_map under JAX 0.9's VMA
                 # checking (interpret lowering mixes varying data with
                 # invariant block indices).  Detect that context — varying
                 # mesh axes on the operand + non-TPU backend — and use the
-                # dense path there; flash stays the default on TPU.
-                from ddl25spring_tpu.utils.compat import typeof
-
-                in_shard_map = bool(getattr(typeof(q), "vma", None))
-                if in_shard_map and jax.default_backend() != "tpu":
+                # dense path there, saying so; on TPU it is the kernel.
+                in_shard_map = bool(jax.typeof(q).vma)
+                if in_shard_map and off_tpu(
+                    "use_flash inside shard_map runs DENSE attention"
+                ):
                     return causal_attention(q, k, v, dtype)
                 return flash_attention(q, k, v)
         else:

@@ -20,9 +20,8 @@ count)::
     python -m ddl25spring_tpu.obs.compile_report --strategies dp,zero3
     python -m ddl25spring_tpu.obs.compile_report --bench
 
-A strategy that cannot trace/compile on the running jax (e.g. the
-homogeneous-pipeline grad path pre-VMA) reports ``{"error": ...}`` for
-its entry and never takes the others down.
+A strategy that cannot trace/compile reports ``{"error": ...}`` for its
+entry and never takes the others down.
 """
 
 from __future__ import annotations
@@ -108,8 +107,8 @@ def bench_compile_report(
     produces, lowered on a fake CPU mesh at a REDUCED batch (collective
     structure and grad bytes are batch-invariant for DP; compile time is
     not).  Two entries: ``bench-dp`` (pure DP) and ``bench-dppp`` (the
-    DPxPP het pipeline — on pre-VMA jax its grad path cannot trace, and
-    the entry degrades to an error string, which is itself signal)."""
+    DPxPP het pipeline; an entry that cannot trace degrades to an error
+    string, which is itself signal)."""
     import jax
 
     from ddl25spring_tpu.obs import xla_analytics

@@ -16,7 +16,7 @@ decomposition behind it:
   goodput number is quoted over, because each attempt's own artifacts
   (flight.json, metrics.jsonl) are overwritten by the next one.
 
-- **Badput taxonomy.**  :class:`GoodputMeter` decomposes one attempt's
+- **Badput classes.**  :class:`GoodputMeter` decomposes one attempt's
   wall into typed buckets (:data:`BUCKETS`) from *measured* windows:
   ``useful_step`` (timed dispatch walls), ``warmup_compile`` (the
   bracketed warmup/compile phase of every ``timed_run`` call),

@@ -169,10 +169,9 @@ def _synth_collective(mesh, kind, nbytes, dtype, axes, group_size):
     re-synthesized (caller records the site as uncosted)."""
     import jax
     import numpy as np
-    from jax import lax
+    from jax import lax, shard_map
+    from jax.lax import pcast
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ddl25spring_tpu.utils.compat import pcast, shard_map
 
     n = int(group_size)
     np_dtype = np.dtype(_HLO_TO_NP.get(dtype or "f32", "float32"))
@@ -182,8 +181,8 @@ def _synth_collective(mesh, kind, nbytes, dtype, axes, group_size):
     spec_sharded = P(tuple(axes))
 
     # the replicated-input bodies (all-reduce / reduce-scatter) pcast
-    # their operand varying first: VMA-typed shard_map rejects a psum
-    # of an unvarying value (identity shim on pre-VMA jax)
+    # their operand varying first: shard_map rejects a psum of an
+    # unvarying value
     if kind == "all-reduce":
         # per-device payload == result bytes; replicated in and out
         def body(v):

@@ -377,7 +377,7 @@ def summarize_run(run_dir: str) -> dict[str, Any]:
             out["mem"] = {"error": f"unreadable {MEM_BASENAME}: {e}"}
 
     # goodput decomposition, when a graft-goodput run/lineage dropped
-    # one here (ddl25spring_tpu/obs/goodput.py): the badput taxonomy,
+    # one here (ddl25spring_tpu/obs/goodput.py): the badput classes,
     # the sum-to-wall contract, and — for serve scopes — SLO attainment
     # and availability; trend/gate with tools/goodput_report.py
     from ddl25spring_tpu.obs.goodput import (

@@ -31,6 +31,7 @@ tests against the dense reference implementation.
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,21 @@ NEG_INF = -1e30
 _DIMS3 = ("parallel", "parallel", "arbitrary")
 
 
+def off_tpu(instead: str) -> bool:
+    """True when the attached backend is not a TPU — and SAYS what runs
+    ``instead`` of the compiled kernel there (a warning, once per call
+    site under Python's default filter), so that no run takes the CPU
+    branch in silence.  A chip run never gets here: ``chip_smoke.py``
+    asserts the platform first and the kernel in the lowered step."""
+    if jax.default_backend() == "tpu":
+        return False
+    warnings.warn(
+        f"no TPU backend ({jax.default_backend()}): {instead}",
+        stacklevel=3,
+    )
+    return True
+
+
 def _sds(shape, dtype, *refs):
     """``ShapeDtypeStruct`` carrying the union of ``refs``' varying mesh
     axes (vma).  Under ``shard_map`` with VMA checking (JAX 0.9 default),
@@ -49,11 +65,9 @@ def _sds(shape, dtype, *refs):
     — without this the kernel cannot be used inside the pipeline/DP
     shard_maps.  Outside shard_map every vma is empty and this degrades to
     a plain ShapeDtypeStruct."""
-    from ddl25spring_tpu.utils.compat import typeof
-
     vma: frozenset = frozenset()
     for r in refs:
-        vma = vma | getattr(typeof(r), "vma", frozenset())
+        vma = vma | jax.typeof(r).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -420,8 +434,8 @@ def flash_attention(
 ) -> jax.Array:
     """Causal flash attention.  ``q/k/v``: ``[B, L, H, hd]`` -> ``[B, L, H, hd]``.
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the same call
-    works in CPU tests and in TPU production.  Block sizes are requests:
+    ``interpret=None`` selects interpreter mode off-TPU (and warns that it
+    did) so the same call works in CPU tests and on the chip.  Block sizes are requests:
     ``_choose_block`` shrinks each to a legal divisor of ``L`` (TPU sublane
     rules), so any ctx works with the defaults.  The 512 default measured
     ~1.5-3x faster than 128 at ctx 2-4k on v5e (fewer grid ticks, same
@@ -429,7 +443,7 @@ def flash_attention(
     """
     B, L, H, hd = q.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = off_tpu("flash attention runs in Pallas interpret mode")
     bq, bk = _choose_block(L, block_q), _choose_block(L, block_k)
 
     def fold(x):
@@ -466,7 +480,7 @@ def flash_attention_with_lse(
             f"causal flash requires square q/kv lengths, got L={L} Lk={Lk}"
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = off_tpu("flash attention runs in Pallas interpret mode")
     bq, bk = _choose_block(L, block_q), _choose_block(Lk, block_k)
 
     def fold(x):

@@ -312,23 +312,40 @@ def bucketed_psum(tree, axis: str,
 # ---------------------------------------------------- overlapped backward
 
 
-def overlap_wrap(tree, plan: BucketPlan, reduce_bucket):
+def _vary(x, axes):
+    """``x`` typed varying over ``axes`` — cast only over those it is not
+    varying over yet (``lax.pcast`` of an already-varying value raises)."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
+def overlap_wrap(tree, plan: BucketPlan, reduce_bucket, vary_axes=()):
     """Route ``tree`` through one identity ``custom_vjp`` per bucket so
     each bucket's gradient reduction is issued INSIDE the backward, at
     the dataflow point where that bucket's cotangents are complete.
 
-    Must be applied to the (device-varying) params *inside the
-    differentiated function* — wrapping outside ``jax.grad``'s scope
-    means the bwd rules never run and the grads come back unreduced.
-    The forward is identity (zero HLO once XLA folds it); the backward
-    of bucket ``b`` receives the bucket's cotangent leaves and returns
-    ``reduce_bucket(cts, b)`` — a tuple of reduced cotangents in the
-    same shapes.  With buckets planned ``order="backward"`` the k-th
-    wrapper's bwd fires while layer k-1's backward still computes, so
-    its collective is schedulable concurrently with the remaining
-    backward — the overlap the sync post-hoc path (:func:`bucketed_
-    pmean` after ``value_and_grad``) structurally forfeits when buckets
-    span distant layers.
+    Must be applied to the params *inside the differentiated function*
+    — wrapping outside ``jax.grad``'s scope means the bwd rules never
+    run and the grads come back unreduced.  The forward is identity
+    (zero HLO once XLA folds it); the backward of bucket ``b`` receives
+    the bucket's cotangent leaves and returns ``reduce_bucket(cts, b)``
+    — a tuple of reduced cotangents in the same shapes.  With buckets
+    planned ``order="backward"`` the k-th wrapper's bwd fires while
+    layer k-1's backward still computes, so its collective is
+    schedulable concurrently with the remaining backward — the overlap
+    the sync post-hoc path (:func:`bucketed_pmean` after
+    ``value_and_grad``) structurally forfeits when buckets span distant
+    layers.
+
+    Typing: the leaves must reach the loss device-varying, or autodiff
+    psums each leaf's cotangent itself before the bwd rule sees it.
+    ZeRO hands in params it has already cast; DP hands in its INVARIANT
+    params and names ``vary_axes`` — the forward casts them there, under
+    the rule, so the only transpose is ``reduce_bucket`` and a ``pmean``
+    there leaves the grads invariant, as DP's ``out_specs=P()`` needs.
+    Either way the bwd rule re-types what ``reduce_bucket`` returns to
+    the primal's own type (a custom_vjp must; see
+    :func:`_bucket_barrier`).
 
     ``reduce_bucket(cts: tuple, b: int) -> tuple`` owns the collective:
     :func:`flat_bucket_reduce` builds the flat-concat ``pmean``/``psum``
@@ -338,27 +355,35 @@ def overlap_wrap(tree, plan: BucketPlan, reduce_bucket):
     leaves = plan.treedef.flatten_up_to(tree)
     out = list(leaves)
     for b, idxs in enumerate(plan.buckets):
-        barrier = _bucket_barrier(reduce_bucket, b)
-        reduced = barrier(tuple(leaves[i] for i in idxs))
-        for i, o in zip(idxs, reduced):
+        group = tuple(leaves[i] for i in idxs)
+        barrier = _bucket_barrier(
+            reduce_bucket, b, tuple(vary_axes),
+            [jax.typeof(g).vma for g in group],
+        )
+        for i, o in zip(idxs, barrier(group)):
             out[i] = o
     return plan.treedef.unflatten(out)
 
 
-def _bucket_barrier(reduce_bucket, b: int):
+def _bucket_barrier(reduce_bucket, b: int, vary_axes: tuple, primal_vma):
     """One bucket's identity-forward / reduce-backward ``custom_vjp``
     (a factory so the loop in :func:`overlap_wrap` closes over the
-    right bucket index)."""
+    right bucket index).  ``primal_vma`` is each input leaf's set of
+    varying axes: the cotangents the rule returns must carry exactly
+    that type, so a reduction that made them invariant (``pmean`` of a
+    varying primal's cotangent) is cast back — the collective stays
+    where the bwd issued it, only its result's type changes."""
 
     @jax.custom_vjp
     def barrier(group: tuple):
-        return group
+        return tuple(_vary(g, vary_axes) for g in group)
 
     def fwd(group):
-        return group, None
+        return barrier(group), None
 
     def bwd(_, cts):
-        return (tuple(reduce_bucket(tuple(cts), b)),)
+        reduced = reduce_bucket(tuple(cts), b)
+        return (tuple(_vary(r, v) for r, v in zip(reduced, primal_vma)),)
 
     barrier.defvjp(fwd, bwd)
     return barrier
@@ -399,9 +424,12 @@ def flat_bucket_reduce(plan: BucketPlan, axis, op: str = "pmean"):
 def overlapped_grad_reduce(tree, axis, bucket_bytes, op: str = "pmean"):
     """Convenience wrapper: plan ``tree``'s leaves into backward-
     readiness buckets and :func:`overlap_wrap` them with the flat
-    ``pmean``/``psum`` reducer.  Apply to the device-varying params
+    ``pmean``/``psum`` reducer.  Apply to the (axis-invariant) params
     inside the differentiated function; ``jax.value_and_grad`` then
-    returns already-reduced grads, with one collective per bucket
-    embedded in the backward dataflow."""
+    returns already-reduced, invariant grads, with one collective per
+    bucket embedded in the backward dataflow."""
     plan = plan_buckets(tree, bucket_bytes, order="backward")
-    return overlap_wrap(tree, plan, flat_bucket_reduce(plan, axis, op))
+    axes = axis if isinstance(axis, tuple) else (axis,)
+    return overlap_wrap(
+        tree, plan, flat_bucket_reduce(plan, axis, op), vary_axes=axes
+    )

@@ -32,11 +32,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
 from ddl25spring_tpu.parallel import bucketing
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import HAS_VMA, pcast, shard_map
 
 # loss_fn(params, batch, key) -> scalar
 LossFn = Callable[[Any, Any, jax.Array], jax.Array]
@@ -162,14 +162,15 @@ def make_dp_train_step(
             # overlapped path: the per-bucket pmean is emitted by each
             # bucket's custom_vjp bwd rule, INSIDE the backward dataflow
             # — value_and_grad returns already-reduced grads, and bucket
-            # k's all-reduce is schedulable against layer k-1's backward
-            lparams = pcast(params, axis, to="varying")
-
+            # k's all-reduce is schedulable against layer k-1's backward.
+            # The params go in INVARIANT: the barrier casts them varying
+            # under its own rule, so the pmean'd grads come back typed
+            # invariant, as out_specs=P() requires
             def reduced_loss(p):
                 p = bucketing.overlapped_grad_reduce(p, axis, bucket_bytes)
                 return loss_fn(p, batch, key)
 
-            loss, grads = jax.value_and_grad(reduced_loss)(lparams)
+            loss, grads = jax.value_and_grad(reduced_loss)(params)
             return lax.pmean(loss, axis), grads
 
         if bucket_bytes:
@@ -191,14 +192,6 @@ def make_dp_train_step(
             return lax.pmean(loss_fn(params, batch, key), axis)
 
         loss, grads = jax.value_and_grad(global_loss)(params)
-        if not HAS_VMA:
-            # pre-VMA jax can't see that ``params`` is axis-invariant, and
-            # its psum transposes to psum (the pmap convention), so the
-            # body-level autodiff hands each shard its UNREDUCED local
-            # gradient; the explicit pmean completes the all_reduce+divide.
-            # On current jax the invariant-param transpose already reduced
-            # — another collective here would be wrong, hence the gate.
-            grads = lax.pmean(grads, axis)
         return loss, grads
 
     @partial(jax.jit, donate_argnums=donate_argnums(donate))

@@ -31,10 +31,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import pcast, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Params = dict[str, Any]

@@ -40,10 +40,10 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import pcast, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any
@@ -241,11 +241,9 @@ def describe(
     *different* boundary widths — the property the flat-buffer packing
     exists for) + its analytic collective signature: one
     ``collective-permute`` of the padded boundary buffer per tick,
-    ``M + S - 1`` ticks per direction (forward-only pre-VMA, where the
-    grad path of the scan-over-ppermute schedule cannot be transposed —
-    same gating as ``tests/test_het_pipeline.py::needs_vma_grad``)."""
-    from ddl25spring_tpu.utils.compat import HAS_VMA
-
+    ``M + S - 1`` ticks per direction, plus the all-reduce autodiff puts
+    on the grads of params this (replicated) path holds whole on every
+    stage."""
     if data_axis is None and "data" in mesh.axis_names:
         data_axis = "data"
     S = mesh.shape[stage_axis]
@@ -274,14 +272,15 @@ def describe(
         "x": jnp.zeros((B, d_in), jnp.float32),
         "y": jnp.zeros((B, d_out), jnp.float32),
     }
-    fn = jax.jit(jax.value_and_grad(loss) if HAS_VMA else loss)
+    fn = jax.jit(jax.value_and_grad(loss))
     T = M + S - 1
-    hops = 2 * T if HAS_VMA else T
+    hops = 2 * T  # the scan transpose replays the ring in reverse
     buf_bytes = mb * max(d_mid, d_out) * 4  # padded flat boundary, f32
+    param_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
     return {
         "fn": fn,
         "args": (params, batch),
-        "lowered": "value_and_grad" if HAS_VMA else "loss",
+        "lowered": "value_and_grad",
         "meta": {
             "num_stages": S,
             "num_microbatches": M,
@@ -295,6 +294,14 @@ def describe(
                 "min_count": hops,
                 "max_count": hops + T,
                 "axes": [stage_axis],
+            },
+            # every stage holds every param: each grad leaf is summed
+            # over the stage axis once (and over data under DP x PP),
+            # beside the scalar loss reductions
+            "all-reduce": {
+                "min_bytes": param_bytes,
+                "max_bytes": (2 if data_axis else 1) * param_bytes + 256,
+                "axes": [stage_axis] + ([data_axis] if data_axis else []),
             },
             "forbidden": ["all-to-all", "reduce-scatter", "all-gather"],
             "memory": {"max_peak_hbm_bytes": 8 * 1024 * 1024},

@@ -38,10 +38,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import pcast, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ddl25spring_tpu.models import llama
@@ -152,7 +152,9 @@ def ring_flash_attention(
     s_idx = lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
-    on_tpu = jax.default_backend() == "tpu"
+    from ddl25spring_tpu.ops.flash_attention import off_tpu
+
+    on_tpu = not off_tpu("ring attention runs its DENSE-with-lse blocks")
 
     def attn(qq, kk, vv, causal):
         if on_tpu:
@@ -220,9 +222,9 @@ def ulysses_attention(q, k, v, axis: str, dtype, use_flash: bool = True):
     qkv = jnp.stack((q, k, v))  # [3, B, Ll, H, hd]
     qkv = lax.all_to_all(qkv, axis, split_axis=3, concat_axis=2, tiled=True)
     qg, kg, vg = qkv[0], qkv[1], qkv[2]
-    if use_flash and jax.default_backend() == "tpu":
-        from ddl25spring_tpu.ops.flash_attention import flash_attention
+    from ddl25spring_tpu.ops.flash_attention import flash_attention, off_tpu
 
+    if use_flash and not off_tpu("ulysses use_flash runs DENSE attention"):
         o = flash_attention(qg, kg, vg)
     else:
         o = llama.causal_attention(qg, kg, vg, dtype)

@@ -48,10 +48,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddl25spring_tpu.models import llama

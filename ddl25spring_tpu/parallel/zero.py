@@ -41,11 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ddl25spring_tpu.parallel import bucketing
 from ddl25spring_tpu.parallel.bucketing import donate_argnums
-from ddl25spring_tpu.utils.compat import pcast, shard_map
 
 LossFn = Callable[[Any, Any, jax.Array], jax.Array]
 
@@ -578,9 +578,8 @@ def make_zero_partitioned_train_step(
         def sharded_step(params, ostate, b, key):
             if per_shard_rng:
                 key = jax.random.fold_in(key, lax.axis_index(axis))
-            # local copies -> local grads on every jax vintage (an
-            # invariant param's autodiff would psum pre-emptively under
-            # VMA but not pre-VMA; the pcast makes both explicit)
+            # local copies -> local grads (an invariant param's
+            # autodiff would psum each leaf's cotangent pre-emptively)
             lparams = pcast(params, axis, to="varying")
             i = lax.axis_index(axis)
             if overlap:

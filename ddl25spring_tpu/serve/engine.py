@@ -619,31 +619,24 @@ def _stream_layer_stack(cfg: LlamaConfig, model_axis: str, n: int):
     return layer_stack, plan
 
 
-def _tp_jit(body, mesh, *, model_axis: str, tp_axis: str | None,
-            n_extra: int, p_specs, donate: bool):
+def _tp_jit(body, mesh, *, model_axis: str, n_extra: int, p_specs,
+            donate: bool):
     """shard_map + jit one serve program body under the TP pool/param
-    layout: pool k/v re-typed tp-varying at entry (identity shim
-    pre-VMA), scalars/tables replicated, pool donated like the dense
-    programs when asked."""
+    layout: pool k/v enter split over ``model_axis`` (so the in-spec
+    already types them varying there — no cast; ``lax.pcast`` of an
+    already-varying value raises), scalars/tables replicated, pool
+    donated like the dense programs when asked."""
     from jax.sharding import PartitionSpec as P
 
-    from ddl25spring_tpu.utils.compat import pcast, shard_map
-
     pool_specs = _tp_pool_specs(model_axis)
-
-    def wrapped(params, pool, *rest):
-        if tp_axis is not None:
-            pool = {
-                **pool,
-                "k": pcast(pool["k"], (tp_axis,), to="varying"),
-                "v": pcast(pool["v"], (tp_axis,), to="varying"),
-            }
-        return body(params, pool, *rest)
-
-    fn = shard_map(
-        wrapped, mesh=mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(p_specs, pool_specs) + (P(),) * n_extra,
         out_specs=(pool_specs, P(), P()),
+        # on a one-device mesh the bodies carry no psum (tp_axis is
+        # None) to re-type what the in-specs and the streamed gathers
+        # mark varying, and one device has nothing that could vary
+        check_vma=mesh.shape[model_axis] > 1,
     )
     pool_kw = {"donate_argnums": (1,)} if donate else {}
     return jax.jit(fn, **pool_kw)
@@ -699,7 +692,7 @@ def _tp_prefill_variant(
                 return inner(full, pool, *rest)
 
         _TP_PREFILL_CACHE[key] = _tp_jit(
-            body, mesh, model_axis=model_axis, tp_axis=tp_axis,
+            body, mesh, model_axis=model_axis,
             n_extra=5,
             p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
             donate=donate,
@@ -728,7 +721,7 @@ def _tp_compiled_programs(
         )
         _TP_PROGRAM_CACHE[key] = (
             _tp_jit(
-                tick_body, mesh, model_axis=model_axis, tp_axis=tp_axis,
+                tick_body, mesh, model_axis=model_axis,
                 n_extra=2,
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
@@ -763,7 +756,7 @@ def _tp_spec_programs(
 
         def build(body, body_cfg, n_extra):
             return _tp_jit(
-                body, mesh, model_axis=model_axis, tp_axis=tp_axis,
+                body, mesh, model_axis=model_axis,
                 n_extra=n_extra,
                 p_specs=_tp_param_specs(body_cfg, model_axis, False),
                 donate=donate,
@@ -2590,7 +2583,7 @@ def make_tp_serve_program(
             )
             n_extra = 2
         fn = _tp_jit(
-            body, mesh, model_axis=model_axis, tp_axis=tp_axis,
+            body, mesh, model_axis=model_axis,
             n_extra=n_extra, p_specs=p_specs, donate=False,
         )
     return fn, pool, pool_specs
@@ -2620,7 +2613,7 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
 
     ``per_chip=True`` (the ``-tp`` entries) tightens the screws to the
     sharded-engine claim itself: the peak-HBM budget drops to 64 KiB —
-    strictly BELOW the ~83 KiB the same program measures on one chip,
+    strictly BELOW the ~75 KiB the same program measures on one chip,
     so the budget only holds because per-chip KV pages and Megatron
     params divided by ``tp`` — and the all-reduce payload is pinned
     byte-exact (activation-sized: positions x dmodel x 4, UNCHANGED by
@@ -2631,9 +2624,9 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
     resident Megatron params for ZeRO-3 ``[L, n, k]`` rows: the decode
     scan all-gathers exactly ``n_layers x n_buckets`` times (the
     double-buffered prefetch — all-gather leaves the forbidden list,
-    count-pinned instead), and the budget relaxes only to 128 KiB:
-    params/n resident + ONE gathered layer transient, still under the
-    one-chip dense peak."""
+    count-pinned instead), under the same 64 KiB budget: params/n
+    resident + ONE gathered layer transient, still under the one-chip
+    dense peak."""
     from ddl25spring_tpu.parallel.tp import shard_tp_params
 
     cfg = LlamaConfig(
@@ -2702,7 +2695,7 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
         "memory": {"max_peak_hbm_bytes": 256 * 1024},
     }
     if per_chip and t > 1:
-        # the PR-18 shrink gate: the SAME program measures ~83 KiB on
+        # the PR-18 shrink gate: the SAME program measures ~75 KiB on
         # one chip (pool 58 KiB + params 25 KiB all resident), so a
         # 64 KiB budget can only hold with the head dim and the
         # Megatron splits genuinely dividing residency by tp (measured
@@ -2713,12 +2706,11 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
         # all-gather leaves the forbidden list unconditionally
         expected["forbidden"].remove("all-gather")
     if weight_stream and t > 1:
-        # params/n resident + one gathered layer in flight: measured
-        # ~83 KiB at tp=2 vs ~85 KiB dense one-chip on the tiny cfg
-        # (the pool halves, the transient layer buys most of it back at
-        # toy sizes; at real sizes param_bytes/n dominates).  128 KiB
-        # still sits far under the 256 KiB dense pin.
-        expected["memory"] = {"max_peak_hbm_bytes": 128 * 1024}
+        # params/n resident + one gathered layer in flight: jax 0.9.0's
+        # CPU backend measures ~42 KiB at tp=2 against ~75 KiB for the
+        # same program on one chip, so the streamed program holds the
+        # resident-weight entries' 64 KiB shrink budget too
+        expected["memory"] = {"max_peak_hbm_bytes": 64 * 1024}
         # the double-buffered prefetch is count-exact: one bucketed
         # gather per layer (decode streams per position; prefill
         # reconstructs the stack once, transiently)

@@ -1,94 +1,27 @@
-"""JAX version compatibility for the manual-SPMD primitives.
+"""Compiled-program probes, normalized across BACKENDS.
 
-The parallel stack is written against the current ``jax.shard_map`` API
-with varying-manual-axes (VMA) typing: ``lax.pcast(x, axes, to="varying")``
-marks a value as device-varying so shard_map's rep-checker accepts
-non-uniform control flow and the transpose inserts cotangent psums in the
-right places.  Older jax (<= 0.4.x, e.g. this build image's 0.4.37) ships
-``shard_map`` under ``jax.experimental`` and has no ``pcast`` / VMA typing
-at all — there, rep-checking is the coarse ``check_rep`` flag and every
-value inside the body is implicitly allowed to vary.
+The compile-time analytics (obs/xla_analytics.py) lean on two
+``Compiled`` APIs whose answers depend on the backend that compiled the
+program:
 
-This module is the single import point for both symbols:
+- ``compiled.cost_analysis()``: one dict of counters, or nothing where
+  the backend has no cost model;
+- ``compiled.memory_analysis()``: a ``CompiledMemoryStats`` whose
+  ``peak_memory_in_bytes`` some backends leave at 0 (the peak is then
+  assembled from argument/output/temp sizes), and which some backends
+  don't implement at all.
 
-- :func:`shard_map` — the current top-level API when present; otherwise the
-  experimental one with ``check_rep=False`` (the VMA annotations the code
-  carries are exactly the facts ``check_rep=True`` cannot verify on the old
-  tracer, and the collectives/psums are all explicit in this codebase, so
-  disabling the checker changes nothing about the lowered program);
-- :func:`pcast` — ``lax.pcast`` when present, identity otherwise (on old
-  jax there is no varying/invariant distinction to cast between).
-
-Keeping the call sites written against the NEW API (and shimming the old
-one) means the code reads idiomatically on current jax and still imports
-and runs — tests, CPU smokes, bench — on the older runtime.
+These two helpers are the single call-sites for both APIs — everything
+else (utils/flops.compiled_flops included) goes through them.  The
+manual-SPMD primitives need no wrapper: the code imports
+``jax.shard_map``, ``lax.pcast`` and ``jax.typeof`` directly (jax 0.9.0,
+the one installation there is; ``tests/test_compat.py`` pins the typing
+rules of theirs the parallel stack relies on).
 """
 
 from __future__ import annotations
 
-import functools
-
-from jax import lax
-
-try:  # jax >= 0.6: top-level export, VMA typing
-    from jax import shard_map as _shard_map
-
-    _LEGACY = False
-except ImportError:  # jax <= 0.4.x: experimental API, check_rep world
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _LEGACY = True
-
-HAS_VMA = hasattr(lax, "pcast")
-
-
-def shard_map(f=None, **kwargs):
-    """``jax.shard_map`` across versions (usable as ``partial(shard_map,
-    mesh=..., in_specs=..., out_specs=...)`` decorator like the real one)."""
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    if _LEGACY:
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(f, **kwargs)
-
-
-if HAS_VMA:
-    pcast = lax.pcast
-else:
-
-    def pcast(x, axis_name, to="varying"):
-        """No-op stand-in for ``lax.pcast`` on pre-VMA jax: without the
-        varying/invariant type system there is nothing to cast."""
-        del axis_name, to
-        return x
-
-
-def typeof(x):
-    """``jax.typeof`` across versions.  Callers only probe the aval's
-    ``vma`` field (absent pre-VMA, where ``get_aval`` serves)."""
-    import jax
-
-    if hasattr(jax, "typeof"):
-        return jax.typeof(x)
-    return jax.core.get_aval(x)
-
-
-# --------------------------------------------------- compiled-program probes
-#
-# The compile-time analytics (obs/xla_analytics.py) lean on two Compiled
-# APIs whose shape drifts across jax versions:
-#
-# - ``compiled.cost_analysis()``: current jax returns one dict; 0.4.x
-#   returns a per-module LIST of dicts (take the entry module's);
-# - ``compiled.memory_analysis()``: a ``CompiledMemoryStats`` whose field
-#   set grew over time (``peak_memory_in_bytes`` is absent on 0.4.x,
-#   where the peak must be assembled from argument/output/temp sizes),
-#   and which some backends don't implement at all.
-#
-# These two helpers are the single call-sites for both APIs — everything
-# else (utils/flops.compiled_flops included) goes through them.
-
-# CompiledMemoryStats fields worth surfacing, oldest-API first
+# CompiledMemoryStats fields worth surfacing
 _MEMORY_FIELDS = (
     "argument_size_in_bytes",
     "output_size_in_bytes",
@@ -106,8 +39,6 @@ def compiled_cost_analysis(compiled) -> dict | None:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — no cost model on this backend
         return None
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: per-module list
-        ca = ca[0] if ca else None
     if not ca:
         return None
     return dict(ca)
@@ -115,8 +46,8 @@ def compiled_cost_analysis(compiled) -> dict | None:
 
 def compiled_memory_stats(compiled) -> dict | None:
     """``compiled.memory_analysis()`` normalized to a plain dict, with a
-    ``peak_hbm_bytes`` estimate that works on every API vintage: the
-    backend's own ``peak_memory_in_bytes`` when present, else
+    ``peak_hbm_bytes`` estimate that works on every backend: its own
+    ``peak_memory_in_bytes`` where it reports one, else
     ``arguments + outputs + temps + generated code - aliased`` (the
     compiled buffers that must coexist)."""
     ma = getattr(compiled, "memory_analysis", None)
@@ -128,17 +59,10 @@ def compiled_memory_stats(compiled) -> dict | None:
         return None
     if ma is None:
         return None
-    out: dict = {}
-    if isinstance(ma, dict):  # hypothetical dict-shaped future API
-        out = {
-            k: int(v) for k, v in ma.items()
-            if isinstance(v, (int, float)) and k in _MEMORY_FIELDS
-        }
-    else:
-        for k in _MEMORY_FIELDS:
-            v = getattr(ma, k, None)
-            if v is not None:
-                out[k] = int(v)
+    out = {
+        k: int(getattr(ma, k)) for k in _MEMORY_FIELDS
+        if getattr(ma, k, None) is not None
+    }
     if not out:
         return None
     peak = out.get("peak_memory_in_bytes")

@@ -80,8 +80,9 @@ def parse_args(argv=None):
                          "device (needs microbatches %% stages == 0 and "
                          "n_layers %% (stages*V) == 0)")
     ap.add_argument("--no-flash", action="store_true",
-                    help="llama: disable the Pallas flash-attention kernel "
-                         "(ON by default on TPU; CPU always runs dense)")
+                    help="llama: dense attention in place of the Pallas "
+                         "flash-attention kernel (flash is the default on "
+                         "TPU; a CPU run is dense and says so)")
     ap.add_argument("--trace-dir", default="",
                     help="capture a jax.profiler trace of the timed loop")
     return ap.parse_args(argv)
@@ -97,7 +98,7 @@ def run_llama(args, jax, jnp):
         make_pipeline_train_step,
         shard_staged_params,
     )
-    from ddl25spring_tpu.utils.config import LlamaConfig
+    from ddl25spring_tpu.utils.config import LlamaConfig, replace
     from ddl25spring_tpu.utils.mesh import make_mesh
 
     devices = jax.devices()
@@ -115,19 +116,23 @@ def run_llama(args, jax, jnp):
 
     on_tpu = devices[0].platform == "tpu"
     tokenizer = get_tokenizer()
-    # fastest correct path by default: Pallas flash attention on TPU,
-    # dense on CPU (where Pallas would run interpreted)
-    cfg = LlamaConfig(
-        vocab_size=tokenizer.vocab_size, dmodel=288, num_heads=6,
-        n_layers=6, ctx_size=256,
+    # the reference constants (LlamaConfig(): 288-d, 6 heads, 6 layers,
+    # ctx 256, vocab 4096 — wider only if the tokenizer's is).  The
+    # platform picks dtype and attention, and the line below SAYS which:
+    # Pallas flash + bf16 on TPU; dense fp32 on CPU, where Pallas would
+    # run interpreted
+    ref = LlamaConfig()
+    cfg = replace(
+        ref, vocab_size=max(ref.vocab_size, tokenizer.vocab_size),
         dtype="bfloat16" if on_tpu else "float32",
         use_flash=on_tpu and not args.no_flash,
     )
     M = args.microbatches or 3
     batch = args.batch or 3 * dp  # reference: batch 3 per pipeline
     iters = args.iters or 200
-    print(f"llama DPxPP: mesh(data={dp}, stage={S}), batch={batch}, "
-          f"microbatches={M}, schedule={args.schedule}, "
+    print(f"llama DPxPP on {devices[0].platform}: mesh(data={dp}, "
+          f"stage={S}), batch={batch}, microbatches={M}, "
+          f"schedule={args.schedule}, dtype={cfg.dtype}, "
           f"attention={'flash' if cfg.use_flash else 'dense'}")
 
     params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
@@ -140,14 +145,11 @@ def run_llama(args, jax, jnp):
     tx = optax.adam(args.lr or 8e-4)
     opt_state = tx.init(staged)
 
-    def build_step(c):
-        return make_pipeline_train_step(
-            c, tx, mesh, M, data_axis="data" if dp > 1 else None,
-            schedule=args.schedule,
-            num_chunks=args.chunks if chunked else 1,
-        )
-
-    step = build_step(cfg)
+    step = make_pipeline_train_step(
+        cfg, tx, mesh, M, data_axis="data" if dp > 1 else None,
+        schedule=args.schedule,
+        num_chunks=args.chunks if chunked else 1,
+    )
 
     start_it = 0
     ckpt = None
@@ -173,14 +175,13 @@ def run_llama(args, jax, jnp):
     # warmup outside the timer: jit compile dominates the first step.  The
     # outputs are DISCARDED — a warmup that stepped the optimizer would give
     # every resumed run one extra update and break kill-and-resume
-    # equivalence with an uninterrupted run
-    from ddl25spring_tpu.parallel.pipeline import warmup_with_flash_fallback
-
+    # equivalence with an uninterrupted run.  A kernel that does not
+    # lower fails here, loudly: there is no quiet retry with dense.
+    # The step donates its params/opt-state, so it warms up on COPIES
     tokens_w = jnp.asarray(next(ds))
-    _, step, cfg = warmup_with_flash_fallback(
-        cfg, build_step, step, staged, opt_state, tokens_w,
-    )
-    float(_[2])
+    t_c = time.perf_counter()
+    float(step(*jax.tree.map(jnp.copy, (staged, opt_state)), tokens_w)[2])
+    compile_s = time.perf_counter() - t_c
 
     import contextlib
 
@@ -189,6 +190,7 @@ def run_llama(args, jax, jnp):
     ctx = trace(args.trace_dir) if args.trace_dir else contextlib.nullcontext()
     t0 = time.perf_counter()
     last_it = start_it - 1
+    logged: list[tuple[int, float]] = []
     with ctx:
         for it in range(start_it, start_it + iters):
             staged, opt_state, loss = step(
@@ -196,7 +198,8 @@ def run_llama(args, jax, jnp):
             )
             if (args.log_every and it % args.log_every == 0) \
                     or it == start_it + iters - 1:
-                print(f"iter {it:5d}  loss {float(loss):.4f}", flush=True)
+                logged.append((it, float(loss)))
+                print(f"iter {it:5d}  loss {logged[-1][1]:.4f}", flush=True)
             if ckpt is not None and args.ckpt_every > 0 \
                     and (it + 1) % args.ckpt_every == 0:
                 ckpt.save(it, {"params": staged, "opt_state": opt_state})
@@ -223,6 +226,13 @@ def run_llama(args, jax, jnp):
               + (f" (MFU {frac:.2%})" if frac is not None else ""))
     if args.trace_dir:
         print(f"profiler trace written to {args.trace_dir}")
+    # what a caller (chip_smoke.py) checks: the logged losses, the times,
+    # and the jitted step with arguments it can be lowered against
+    return {
+        "cfg": cfg, "mesh": mesh, "losses": logged, "compile_s": compile_s,
+        "run_s": dt, "tokens_per_s": tok_s, "step": step,
+        "step_args": (staged, opt_state, tokens_w),
+    }
 
 
 def run_resnet(args, jax, jnp):
@@ -335,6 +345,10 @@ def main(argv=None) -> None:
     from ddl25spring_tpu.utils.platform import force_cpu_devices
 
     force_cpu_devices(args.force_cpu_devices)
+    if not args.force_cpu_devices:
+        from ddl25spring_tpu.utils.platform import enable_compilation_cache
+
+        enable_compilation_cache()
 
     import jax
     import jax.numpy as jnp

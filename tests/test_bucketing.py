@@ -138,7 +138,7 @@ def test_bucketed_pmean_matches_per_leaf_bitwise(devices8):
 
     from jax.sharding import PartitionSpec as P
 
-    from ddl25spring_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(devices8[:4], data=4)
     tree = {
@@ -183,29 +183,41 @@ def mlp4(devices8):
     return mesh, params, loss_fn, batch
 
 
-def test_dp_bucketed_equals_per_leaf_bitwise(mlp4):
-    """The acceptance pin: DP's bucketed gradient path is BITWISE equal
-    to the per-leaf path — packing commutes with the elementwise psum."""
+def _dp_three_steps(mlp4, **kw):
+    """Final (params, loss) of three DP steps of one ``make_dp_train_step``
+    variant from the shared ``mlp4`` start."""
     mesh, params, loss_fn, batch = mlp4
     tx = optax.adam(1e-2)
-    key = jax.random.PRNGKey(0)
-    per_leaf = make_dp_train_step(
-        loss_fn, tx, mesh, per_shard_rng=False, bucket_bytes=None
-    )
-    bucketed = make_dp_train_step(
-        loss_fn, tx, mesh, per_shard_rng=False
-    )
-    p1, o1, l1 = params, tx.init(params), None
-    p2, o2 = params, tx.init(params)
+    step = make_dp_train_step(loss_fn, tx, mesh, per_shard_rng=False, **kw)
+    p, o = params, tx.init(params)
     for _ in range(3):
-        p1, o1, l1 = per_leaf(p1, o1, batch, key)
-        p2, o2, l2 = bucketed(p2, o2, batch, key)
-        assert float(l1) == float(l2)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b)
-        ),
-        jax.device_get(p1), jax.device_get(p2),
+        p, o, loss = step(p, o, batch, jax.random.PRNGKey(0))
+    return jax.device_get(p), float(loss)
+
+
+def _assert_trees(check, a, b):
+    jax.tree.map(lambda x, y: check(np.asarray(x), np.asarray(y)), a, b)
+
+
+# the per-leaf path lets autodiff reduce: psum of cotangents the pmean'd
+# loss already scaled by 1/n.  The bucketed paths take LOCAL grads and
+# pmean them — psum, then divide.  Same value, different rounding: jax
+# 0.9.0's CPU backend lands them one ulp apart (1.5e-8 absolute after
+# three adam steps here), where jax 0.4.37 compiled both to one program
+_ULP = dict(rtol=1e-6, atol=1e-7)
+
+
+def test_dp_bucketed_equals_per_leaf_bitwise(mlp4):
+    """The acceptance pin: DP's bucketed gradient path trains the
+    per-leaf path's trajectory — the loss bitwise, the params to the
+    rounding of one reduction (:data:`_ULP`).  That packing itself
+    commutes with the elementwise psum, bit for bit, is
+    ``test_bucketed_pmean_matches_per_leaf_bitwise``'s pin."""
+    p1, l1 = _dp_three_steps(mlp4, bucket_bytes=None)
+    p2, l2 = _dp_three_steps(mlp4)
+    assert l1 == l2
+    _assert_trees(
+        lambda a, b: np.testing.assert_allclose(a, b, **_ULP), p1, p2
     )
 
 
@@ -325,29 +337,16 @@ def test_zero3_llama_prefetch_holds_sharded_state(devices8):
 def test_dp_overlap_equals_per_leaf_bitwise(mlp4):
     """The PR-8 acceptance pin: the backward-overlapped DP step — each
     bucket's all-reduce emitted by its custom_vjp bwd rule, buckets in
-    backward-readiness order — lands BITWISE where per-leaf sync DP
-    lands (psum is elementwise; packing and issue order commute with
-    it)."""
-    mesh, params, loss_fn, batch = mlp4
-    tx = optax.adam(1e-2)
-    key = jax.random.PRNGKey(0)
-    per_leaf = make_dp_train_step(
-        loss_fn, tx, mesh, per_shard_rng=False, bucket_bytes=None
-    )
-    overlapped = make_dp_train_step(
-        loss_fn, tx, mesh, per_shard_rng=False, overlap=True
-    )
-    p1, o1 = params, tx.init(params)
-    p2, o2 = params, tx.init(params)
-    for _ in range(3):
-        p1, o1, l1 = per_leaf(p1, o1, batch, key)
-        p2, o2, l2 = overlapped(p2, o2, batch, key)
-        assert float(l1) == float(l2)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b)
-        ),
-        jax.device_get(p1), jax.device_get(p2),
+    backward-readiness order — lands BITWISE where the sync bucketed
+    step lands (psum is elementwise; issue order commutes with it), and
+    so within :data:`_ULP` of per-leaf sync DP."""
+    p0, l0 = _dp_three_steps(mlp4, bucket_bytes=None)
+    p1, l1 = _dp_three_steps(mlp4)
+    p2, l2 = _dp_three_steps(mlp4, overlap=True)
+    assert l0 == l1 == l2
+    _assert_trees(np.testing.assert_array_equal, p1, p2)
+    _assert_trees(
+        lambda a, b: np.testing.assert_allclose(a, b, **_ULP), p0, p2
     )
 
 

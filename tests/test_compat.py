@@ -1,12 +1,22 @@
-"""Direct unit coverage for the ``utils/compat.py`` shims.
+"""Pins of the jax API facts the parallel stack leans on.
 
-The shims are the single import point that lets the whole stack (written
-against current jax: top-level ``shard_map``, VMA ``pcast``, one-dict
-``cost_analysis``, peak-carrying ``memory_analysis``) import and run on
-jax 0.4.x.  They were previously exercised only through the modules that
-use them; these tests pin each shim's contract on BOTH API vintages —
-every assertion here is phrased so it passes on the legacy runtime this
-image ships AND on a current one.
+The code imports ``jax.shard_map``, ``lax.pcast`` and ``jax.typeof``
+directly (jax 0.9.0 — the one installation there is).  What it RELIES on
+is their varying/invariant typing: every rule pinned here is one a
+program in this repo broke, or is built around —
+
+- ``pcast`` retypes and never changes values;
+- ``pcast`` of an already-varying value RAISES (the fault that kept every
+  TP serve program from lowering: ``serve/engine._tp_jit`` cast pool
+  buffers its in-spec had already made varying);
+- an in-spec that names an axis types that input varying over it;
+- a ``psum`` of a varying value is invariant (why DP's overlapped grads
+  can leave through ``out_specs=P()``);
+- a ``custom_vjp`` bwd rule must return the primal's own varying type
+  (the bucket-barrier fault, ``parallel/bucketing._bucket_barrier``).
+
+Then the two compiled-program probes of ``utils/compat.py``, over every
+answer a backend can give.
 """
 
 import functools
@@ -15,17 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from ddl25spring_tpu.utils import compat
 from ddl25spring_tpu.utils.compat import (
-    HAS_VMA,
     compiled_cost_analysis,
     compiled_memory_stats,
-    pcast,
-    shard_map,
-    typeof,
 )
 from ddl25spring_tpu.utils.mesh import make_mesh
 
@@ -33,6 +38,22 @@ from ddl25spring_tpu.utils.mesh import make_mesh
 @pytest.fixture(scope="module")
 def mesh4(devices8):
     return make_mesh(devices8[:4], data=4)
+
+
+def _vma_of(mesh, fn, in_spec, x):
+    """The varying axes ``fn(x)`` carries inside a shard_map over
+    ``mesh`` — read at trace time, nothing runs."""
+    seen = []
+
+    def body(v):
+        seen.append(jax.typeof(fn(v)).vma)
+        return v
+
+    jax.eval_shape(
+        shard_map(body, mesh=mesh, in_specs=(in_spec,), out_specs=in_spec),
+        x,
+    )
+    return seen[0]
 
 
 # ------------------------------------------------------------- shard_map
@@ -50,8 +71,8 @@ def test_shard_map_direct_call_runs_psum(mesh4):
 
 
 def test_shard_map_partial_decorator_form(mesh4):
-    """The ``shard_map(f=None, **kw)`` curry: usable exactly like the
-    real API's decorator spelling."""
+    """``shard_map(mesh=..., ...)`` without a function curries — the
+    decorator spelling every ``@partial(shard_map, ...)`` site uses."""
     deco = shard_map(mesh=mesh4, in_specs=(P("data"),), out_specs=P("data"))
     assert callable(deco)
     doubled = deco(lambda x: x * 2)
@@ -60,55 +81,86 @@ def test_shard_map_partial_decorator_form(mesh4):
     )
 
 
-def test_shard_map_legacy_flag_matches_runtime():
-    """On pre-VMA jax the shim must route through the experimental API
-    with check_rep defaulted off; on current jax it must NOT inject the
-    (removed) kwarg.  _LEGACY is the single switch for both."""
-    legacy_runtime = not hasattr(jax, "shard_map")
-    assert compat._LEGACY == legacy_runtime
+def test_in_spec_naming_an_axis_types_the_input_varying(mesh4):
+    """Sharded in, varying already; replicated in, invariant.  The TP
+    serve programs' pool k/v enter split over the model axis — no cast
+    is needed (or allowed, see below)."""
+    assert _vma_of(mesh4, lambda v: v, P("data"), jnp.zeros(4)) == {"data"}
+    assert _vma_of(mesh4, lambda v: v, P(), jnp.zeros(4)) == frozenset()
 
 
 # ----------------------------------------------------------------- pcast
 
 
 def test_pcast_is_identity_semantics(mesh4):
-    """pcast never changes VALUES — on VMA jax it only retypes the aval,
-    pre-VMA it is literally identity (nothing to cast between)."""
+    """pcast never changes VALUES — it only retypes the aval."""
     @functools.partial(
-        shard_map, mesh=mesh4, in_specs=(P("data"),), out_specs=P("data")
+        shard_map, mesh=mesh4, in_specs=(P(),), out_specs=P("data")
     )
     def body(x):
-        return pcast(x, "data", to="varying") + 1.0
+        return lax.pcast(x, "data", to="varying") + 1.0
 
     np.testing.assert_array_equal(
-        np.asarray(body(jnp.zeros(4))), np.ones(4)
+        np.asarray(body(jnp.zeros(1))), np.ones(4)
     )
+    cast = lambda v: lax.pcast(v, "data", to="varying")  # noqa: E731
+    assert _vma_of(mesh4, cast, P(), jnp.zeros(4)) == {"data"}
 
 
-def test_pcast_binding_tracks_vma():
-    if HAS_VMA:
-        assert pcast is lax.pcast
-    else:
-        x = jnp.arange(3.0)
-        assert pcast(x, "data", to="varying") is x
+def test_pcast_of_an_already_varying_value_raises(mesh4):
+    """The fault at ``serve/engine.py``'s old ``_tp_jit``: casting what
+    the in-spec already typed varying is an error, not a no-op — cast
+    only what is not varying yet (``bucketing._vary``)."""
+    cast = lambda v: lax.pcast(v, "data", to="varying")  # noqa: E731
+    with pytest.raises(ValueError, match="Unsupported pcast"):
+        _vma_of(mesh4, cast, P("data"), jnp.zeros(4))
+
+    from ddl25spring_tpu.parallel.bucketing import _vary
+
+    once = lambda v: _vary(v, ("data",))  # noqa: E731
+    assert _vma_of(mesh4, once, P("data"), jnp.zeros(4)) == {"data"}
+    assert _vma_of(mesh4, once, P(), jnp.zeros(4)) == {"data"}
 
 
-def test_typeof_exposes_shape_dtype():
-    t = typeof(jnp.zeros((2, 3), jnp.float32))
-    assert tuple(t.shape) == (2, 3) and t.dtype == jnp.float32
-    # the callers' probe pattern: vma is a set on VMA jax, absent before
-    vma = getattr(t, "vma", None)
-    assert vma is None or isinstance(vma, (set, frozenset, tuple))
+def test_psum_of_a_varying_value_is_invariant(mesh4):
+    """...and an all_gather's result stays varying though every device
+    holds the same bytes — re-typing it takes a reduction."""
+    psum = lambda v: lax.psum(v, "data")  # noqa: E731
+    assert _vma_of(mesh4, psum, P("data"), jnp.zeros(4)) == frozenset()
+    gather = lambda v: lax.all_gather(v, "data", tiled=True)  # noqa: E731
+    assert _vma_of(mesh4, gather, P("data"), jnp.zeros(4)) == {"data"}
+
+
+def test_custom_vjp_bwd_must_return_the_primals_varying_type(mesh4):
+    """The bucket-barrier fault: a bwd rule that returns the REDUCED
+    (invariant) cotangent for a varying primal is refused at trace time;
+    re-typed to the primal's type it traces, and the values are the
+    reduction's."""
+    def barrier(retype):
+        @jax.custom_vjp
+        def f(x):
+            return x
+
+        def bwd(_, ct):
+            r = lax.pmean(ct, "data")
+            return (lax.pcast(r, "data", to="varying") if retype else r,)
+
+        f.defvjp(lambda x: (x, None), bwd)
+        return f
+
+    def grads(retype):
+        return shard_map(
+            jax.grad(lambda x: jnp.sum(barrier(retype)(x) ** 2)),
+            mesh=mesh4, in_specs=(P("data"),), out_specs=P("data"),
+        )(jnp.arange(4.0))
+
+    with pytest.raises(ValueError, match="varying manual axes"):
+        grads(False)
+    # d/dx sum(x^2) = 2x per shard, then the mean over the four shards
+    np.testing.assert_array_equal(np.asarray(grads(True)), np.full(4, 3.0))
 
 
 # -------------------------------------------------- cost analysis shapes
-
-
-class _CostList:
-    """jax <= 0.4.x: per-module list; entry module first."""
-
-    def cost_analysis(self):
-        return [{"flops": 12.0, "bytes accessed": 3.0}, {"flops": 99.0}]
 
 
 class _CostDict:
@@ -116,9 +168,9 @@ class _CostDict:
         return {"flops": 7.5}
 
 
-class _CostEmptyList:
+class _CostEmpty:
     def cost_analysis(self):
-        return []
+        return {}
 
 
 class _CostNone:
@@ -131,12 +183,9 @@ class _CostRaises:
         raise NotImplementedError("no cost model on this backend")
 
 
-def test_cost_analysis_normalizes_every_api_shape():
-    assert compiled_cost_analysis(_CostList()) == {
-        "flops": 12.0, "bytes accessed": 3.0,
-    }
+def test_cost_analysis_normalizes_every_backend_answer():
     assert compiled_cost_analysis(_CostDict()) == {"flops": 7.5}
-    assert compiled_cost_analysis(_CostEmptyList()) is None
+    assert compiled_cost_analysis(_CostEmpty()) is None
     assert compiled_cost_analysis(_CostNone()) is None
     assert compiled_cost_analysis(_CostRaises()) is None
 
@@ -153,7 +202,7 @@ def test_cost_analysis_returns_a_fresh_dict():
 
 
 class _MemOld:
-    """CompiledMemoryStats as 0.4.x ships it: no peak field."""
+    """CompiledMemoryStats of a backend that reports no peak."""
 
     argument_size_in_bytes = 1000
     output_size_in_bytes = 300
@@ -174,29 +223,21 @@ def _compiled_with(stats):
     return C()
 
 
-def test_memory_stats_assembles_peak_on_legacy_fields():
+def test_memory_stats_assembles_peak_where_the_backend_reports_none():
     out = compiled_memory_stats(_compiled_with(_MemOld()))
     assert out["peak_hbm_bytes"] == 1000 + 300 + 700 + 50 - 100
     assert out["alias_size_in_bytes"] == 100
+
+    class Zero(_MemOld):
+        peak_memory_in_bytes = 0  # reported, but empty: assemble too
+
+    out = compiled_memory_stats(_compiled_with(Zero()))
+    assert out["peak_hbm_bytes"] == 1000 + 300 + 700 + 50 - 100
 
 
 def test_memory_stats_prefers_backend_peak():
     out = compiled_memory_stats(_compiled_with(_MemNew()))
     assert out["peak_hbm_bytes"] == 4242
-
-
-def test_memory_stats_dict_shaped_future_api():
-    out = compiled_memory_stats(_compiled_with({
-        "argument_size_in_bytes": 10,
-        "temp_size_in_bytes": 5,
-        "not_a_known_field": 77,
-        "generated_code_size_in_bytes": "not-a-number",
-    }))
-    assert out == {
-        "argument_size_in_bytes": 10,
-        "temp_size_in_bytes": 5,
-        "peak_hbm_bytes": 15,
-    }
 
 
 def test_memory_stats_degrades_to_none():

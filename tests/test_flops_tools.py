@@ -36,6 +36,16 @@ def test_chip_peak_prefix_match_prefers_longest():
 
     assert chip_peak_flops(FakeV5p()) == 459e12
 
+    # a chip that is not in the table is an error, never a default
+    class FakeUnknown:
+        platform = "tpu"
+        device_kind = "TPU v99"
+
+    import pytest
+
+    with pytest.raises(KeyError, match="TPU v99"):
+        chip_peak_flops(FakeUnknown())
+
 
 def test_chip_peak_on_cpu_calibrates_host_fallback():
     # datasheet-only callers still get None off-TPU ...

@@ -6,10 +6,8 @@ The contract pins, in order:
 1. **HLO identity** — with sentinels disabled, every instrumented
    train-step builder lowers to HLO byte-identical to a build with the
    guard explicitly off (the PR-1 zero-cost pattern, per strategy); with
-   sentinels enabled the guard actually lands in the program.  Builders
-   whose grad path needs VMA-typed shard_map gate on ``HAS_VMA`` exactly
-   like ``tests/test_pipeline.py`` (their forward-only paths carry no
-   update to guard).  Lowerings are cached per (builder, mode) — the
+   sentinels enabled the guard actually lands in the program.
+   Lowerings are cached per (builder, mode) — the
    ``tests/test_xla_analytics.py`` compile-once pattern.
 2. **Detection** — a NaN injected into a DP and a ZeRO-3 step is caught
    within that step, recorded in the flight ring, and identified down to
@@ -36,7 +34,6 @@ import pytest
 
 from ddl25spring_tpu.obs import flight, sentinels
 from ddl25spring_tpu.obs.watchdog import StallWatchdog, thread_stacks
-from ddl25spring_tpu.utils.compat import HAS_VMA
 from ddl25spring_tpu.utils.mesh import make_mesh
 
 
@@ -236,13 +233,9 @@ def _builder_setups(devices8):
         "tp": tp_step,
         "sp": sp_step,
         "ep": ep_step,
+        "pipeline": pipeline_step,
+        "het_pipeline": het_step,
     }
-    if HAS_VMA:
-        # the scan-over-ppermute schedules transpose only under
-        # VMA-typed shard_map (same gating as tests/test_pipeline.py);
-        # pre-VMA these builders cannot trace a grad path at all
-        setups["pipeline"] = pipeline_step
-        setups["het_pipeline"] = het_step
     return setups
 
 
@@ -731,7 +724,7 @@ def test_bench_classify_failure_reason_codes():
         "accelerator unreachable: device init timed out after 240s"
     ) == "device_unreachable"
     assert bench.classify_failure(
-        "RuntimeError: UNAVAILABLE: tunnel closed"
+        "RuntimeError: UNAVAILABLE: connection closed"
     ) == "device_unreachable"
     assert bench.classify_failure(
         "attempt 2: bench subprocess exceeded 2400s and was killed"

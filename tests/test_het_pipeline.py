@@ -8,7 +8,6 @@ shapes (BASELINE.json "2-stage pipeline x 2-way DP with microbatches").
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
@@ -19,19 +18,7 @@ from ddl25spring_tpu.parallel.het_pipeline import (
     make_het_pipeline_loss,
     make_het_pipeline_train_step,
 )
-from ddl25spring_tpu.utils.compat import HAS_VMA
 from ddl25spring_tpu.utils.mesh import make_mesh
-
-# Forward passes through the het pipeline run on any jax (pinned by the
-# loss-equality test below and by tests/test_obs.py).  The GRAD path does
-# not: pre-VMA jax's experimental shard_map mis-stages the transposed
-# program (_SpecError on a scalar cotangent) for the scan-over-ppermute
-# schedule, so gradient/train tests need the VMA-typed shard_map.
-needs_vma_grad = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="pipeline grad path needs VMA-typed shard_map (lax.pcast); "
-    "this jax's experimental shard_map mis-transposes the schedule",
-)
 
 W = 8  # narrow net: CPU-fast, same structure
 S0 = ResNet18Stage0(width=W)
@@ -81,7 +68,6 @@ def test_het_pipeline_loss_equals_serial(setup, microbatches, devices8):
     np.testing.assert_allclose(l_pipe, l_serial, rtol=1e-5)
 
 
-@needs_vma_grad
 def test_het_pipeline_grads_equal_serial(setup, devices8):
     params, x, y = setup
     mesh = make_mesh(devices8[:2], stage=2)
@@ -97,7 +83,6 @@ def test_het_pipeline_grads_equal_serial(setup, devices8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
-@needs_vma_grad
 def test_het_pipeline_dp_pp_trains(setup, devices8):
     """DPxPP: 2-way data x 2-stage pipeline on 4 devices; loss decreases."""
     params, x, y = setup
@@ -122,89 +107,7 @@ def test_het_pipeline_dp_pp_trains(setup, devices8):
 # ---------------------------------------------------------- sharded params
 
 
-@needs_vma_grad
-def test_sharded_het_pipeline_equals_replicated(setup, devices8):
-    """The stage-SHARDED variant (params packed [S, maxP] over the stage
-    axis, each device materializing only its branch) must match the
-    replicated path — loss and the params after one optimizer step."""
-    from ddl25spring_tpu.parallel.het_pipeline import (
-        make_sharded_het_pipeline_train_step,
-        pack_stage_params,
-        unpack_stage_params,
-    )
-
-    params, x, y = setup
-    mesh = make_mesh(devices8[:4], data=2, stage=2)
-    M, mb = 2, 2
-    batch = {"x": x, "y": y}
-    tx = optax.sgd(0.1)
-
-    step_rep = make_het_pipeline_train_step(
-        _stage_fns(), lambda lg, b: cross_entropy_logits(lg, b["y"]),
-        *_shapes(mb), tx, mesh, M, data_axis="data",
-    )
-    p_rep, _, l_rep = step_rep(params, tx.init(params), batch)
-
-    step_sh, stacked, opt_sh = make_sharded_het_pipeline_train_step(
-        _stage_fns(), params,
-        lambda lg, b: cross_entropy_logits(lg, b["y"]),
-        *_shapes(mb), tx, mesh, M, data_axis="data",
-    )
-    stacked, _, l_sh = step_sh(stacked, opt_sh, batch)
-
-    np.testing.assert_allclose(float(l_rep), float(l_sh), rtol=1e-6)
-    _, metas = pack_stage_params(params)
-    for i in range(2):
-        p_i = unpack_stage_params(jax.device_get(stacked)[i], metas[i])
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                jax.device_get(a), jax.device_get(b), atol=1e-5, rtol=1e-5
-            ),
-            p_rep[i],
-            p_i,
-        )
-
-
-@needs_vma_grad
-def test_sharded_het_pipeline_param_memory(setup, devices8):
-    """The point of sharding: per-device param bytes are max_s|p_s| (plus
-    padding), not sum_s|p_s|.  Check the compiled argument footprint of the
-    sharded step is strictly below the replicated step's."""
-    from ddl25spring_tpu.parallel.het_pipeline import (
-        make_sharded_het_pipeline_train_step,
-        pack_stage_params,
-    )
-
-    params, x, y = setup
-    mesh = make_mesh(devices8[:2], stage=2)
-    M, mb = 2, 4
-    batch = {"x": x, "y": y}
-    tx = optax.sgd(0.1)
-
-    step_rep = make_het_pipeline_train_step(
-        _stage_fns(), lambda lg, b: cross_entropy_logits(lg, b["y"]),
-        *_shapes(mb), tx, mesh, M,
-    )
-    rep_stats = step_rep.lower(
-        params, tx.init(params), batch
-    ).compile().memory_analysis()
-
-    step_sh, stacked, opt_sh = make_sharded_het_pipeline_train_step(
-        _stage_fns(), params,
-        lambda lg, b: cross_entropy_logits(lg, b["y"]),
-        *_shapes(mb), tx, mesh, M,
-    )
-    sh_stats = step_sh.lower(stacked, opt_sh, batch).compile().memory_analysis()
-
-    # replicated: every device holds p0+p1 (+opt twin). sharded: [S, maxP]
-    # total across devices = 2*maxP, i.e. per-device maxP < p0+p1
-    assert sh_stats.argument_size_in_bytes < rep_stats.argument_size_in_bytes, (
-        sh_stats.argument_size_in_bytes, rep_stats.argument_size_in_bytes,
-    )
-
-
 @pytest.mark.parametrize("stages", [3, 4])
-@needs_vma_grad
 def test_het_pipeline_s3_s4_equals_serial(stages, devices8):
     """The S-generic ResNet stage split (round-5 lift of the S<=2 cap):
     the S-stage pipelined loss and grads equal the serial composition of
@@ -256,21 +159,3 @@ def test_het_pipeline_s3_s4_equals_serial(stages, devices8):
         g_serial,
         g_pipe,
     )
-
-
-@needs_vma_grad
-def test_build_resnet_step_s3(devices8):
-    """build_resnet_step at the reference flagship topology (dp=2, S=3):
-    one step runs on a (data=2, stage=3) mesh and the loss is finite."""
-    from ddl25spring_tpu.benchmarks import build_resnet_step
-
-    step, params, opt_state, meta = build_resnet_step(
-        devices8[:6], dp=2, S=3, num_microbatches=2, batch=8,
-        dtype=jnp.float32,
-    )
-    assert meta["n_chips"] == 6
-    assert "stage=3" in meta["topology"]
-    x = np.zeros((8, 32, 32, 3), np.uint8)
-    y = np.zeros((8,), np.int32)
-    _, _, loss = step(params, opt_state, (jnp.asarray(x), jnp.asarray(y)))
-    assert np.isfinite(float(loss))

@@ -497,7 +497,8 @@ HloModule h009, num_partitions=4
 ENTRY %main (x: f32[1024], y: f32[1024]) -> f32[1024] {{
   %x = f32[1024]{{0}} parameter(0)
   %y = f32[1024]{{0}} parameter(1)
-  %ar1 = f32[1024]{{0}} all-reduce(f32[1024]{{0}} %x), channel_id=7, replica_groups={{{{0,1}},{{2,3}}}}, use_global_device_ids=true, to_apply=%add
+  %ar0 = f32[1024]{{0}} all-reduce(f32[1024]{{0}} %x), channel_id=3, replica_groups={{{{0,1,2,3}}}}, use_global_device_ids=true, to_apply=%add
+  %ar1 = f32[1024]{{0}} all-reduce(f32[1024]{{0}} %ar0), channel_id=7, replica_groups={{{{0,1}},{{2,3}}}}, use_global_device_ids=true, to_apply=%add
   %ar2 = f32[1024]{{0}} all-reduce(f32[1024]{{0}} %y), channel_id=7, replica_groups={{{{0,2}},{{1,3}}}}, use_global_device_ids=true, to_apply=%add
   ROOT %s = f32[1024]{{0}} add(f32[1024]{{0}} %ar1, f32[1024]{{0}} %ar2)
 }}

@@ -198,10 +198,11 @@ def test_slowed_step_trips_the_gate_and_clean_rerun_passes(tmp_path):
     ``perf_report --check`` -> clean re-run passes again."""
     import tools.perf_report as perf_report
 
-    # tolerance 1.0 (the wide CI-machine band the perf-smoke job uses):
-    # clean re-measurements sit well inside 2x, while the 0.3 s
-    # injected sleep is a ~10x step regression — unambiguous both ways
-    band = ["--check", "--tolerance", "1.0"]
+    # unambiguous both ways even beside five busy xdist workers (a 2x
+    # band with a 0.3 s sleep flaked there: a CLEAN re-measurement of a
+    # ~20 ms step landed outside 2x): clean runs must stay inside 5x,
+    # the injected 1 s sleep is a >= 30x step regression
+    band = ["--check", "--tolerance", "4.0"]
     led = str(tmp_path / "ledger.jsonl")
     fast_fn, fast_args = _toy_step()
     for _ in range(2):
@@ -211,7 +212,7 @@ def test_slowed_step_trips_the_gate_and_clean_rerun_passes(tmp_path):
     assert perf_report.main(["--ledger", led, *band]) == 0
 
     perfscope.append_ledger(perfscope.measure_callable(
-        *_slowed_step(), strategy="toy", reps=4, warmup=1
+        *_slowed_step(1.0), strategy="toy", reps=4, warmup=1
     ), led)
     assert perf_report.main(["--ledger", led, *band]) == 1
 
@@ -455,8 +456,8 @@ def test_bench_smoke_emits_perf_cell(tmp_path):
     led = str(tmp_path / "ledger.jsonl")
     obs_dir = str(tmp_path / "run")
     # the CI smoke environment: single CPU device (the suite's 8-device
-    # XLA_FLAGS would build the DPxPP pipeline, whose grad path cannot
-    # trace on pre-VMA jax), production donation defaults
+    # XLA_FLAGS would build the slower DPxPP pipeline), production
+    # donation defaults
     env = {
         k: v for k, v in os.environ.items()
         if k not in ("XLA_FLAGS", "DDL25_DONATE", "DDL25_CHAOS")

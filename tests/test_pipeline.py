@@ -17,41 +17,19 @@ from ddl25spring_tpu.ops.losses import causal_lm_loss
 from ddl25spring_tpu.parallel.pipeline import (
     make_1f1b_value_and_grad,
     make_grad_accum_step,
-    make_interleaved_pipeline_loss,
     make_pipeline_loss,
     make_pipeline_train_step,
     shard_staged_params,
 )
-from ddl25spring_tpu.utils.compat import HAS_VMA
 from ddl25spring_tpu.utils.config import LlamaConfig
 from ddl25spring_tpu.utils.mesh import make_mesh
-
-# The homogeneous pipeline schedules lean on VMA-typed shard_map autodiff
-# (pcast-varying carries, collectives under lax.cond); pre-VMA jax traces
-# them into _SpecError / wrong-transpose territory — not worth 6 minutes
-# of CI to confirm on every run.  DP, ZeRO, TP, SP, EP, and het-pipeline
-# FORWARD suites run on both; het-pipeline grad tests carry their own
-# per-test skip (tests/test_het_pipeline.py::needs_vma_grad).
-pytestmark = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="homogeneous pipeline schedules need VMA-typed shard_map "
-    "(lax.pcast); this jax predates it",
+from pipeline_common import (  # noqa: F401 — the fixture is used by name
+    CFG,
+    MOE_CFG,
+    params_and_tokens,
+    serial_loss,
+    serial_moe_loss,
 )
-
-CFG = LlamaConfig(
-    vocab_size=64, dmodel=32, num_heads=2, n_layers=4, ctx_size=16, dtype="float32"
-)
-
-
-def serial_loss(params, tokens):
-    return causal_lm_loss(llama.llama_forward(params, tokens, CFG), tokens)
-
-
-@pytest.fixture(scope="module")
-def params_and_tokens():
-    params = llama.init_llama_params(jax.random.PRNGKey(0), CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (6, 16), 0, 64)
-    return params, tokens
 
 
 @pytest.mark.parametrize("stages,microbatches", [(2, 3), (4, 2), (2, 6)])
@@ -293,281 +271,6 @@ def test_1f1b_bounds_activation_memory(devices8):
     assert temps["1f1b"] * 2 < temps["gpipe"], temps
 
 
-MOE_CFG = LlamaConfig(
-    vocab_size=64, dmodel=32, num_heads=2, n_layers=4, ctx_size=16,
-    dtype="float32", n_experts=4, capacity_factor=2.0,
-)
-
-# 4-head variant for TP tests (heads must divide the model axis)
-CFG4H = LlamaConfig(
-    vocab_size=64, dmodel=32, num_heads=4, n_layers=4, ctx_size=16,
-    dtype="float32",
-)
-
-
-def serial_moe_loss(params, tokens, M):
-    """Per-microbatch oracle: the pipeline's MoE dispatch groups are the
-    ``[mb*L]`` token groups each stage sees, so the reference composite
-    loss is the mean over microbatches of ``ce + w * aux`` from
-    ``llama_forward_with_aux`` — routing (and any capacity drops) is then
-    IDENTICAL on both sides, so equality is exact, not just ample-capacity."""
-    B, L = tokens.shape
-    mbs = tokens.reshape(M, B // M, L)
-
-    def per_mb(mb):
-        logits, aux = llama.llama_forward_with_aux(params, mb, MOE_CFG)
-        return causal_lm_loss(logits, mb) + MOE_CFG.moe_aux_weight * aux
-
-    return jnp.mean(jax.vmap(per_mb)(mbs))
-
-
-def test_gpipe_moe_loss_and_grads_equal_serial(devices8):
-    """Switch-MoE rides GPipe: aux loss accumulates through the scan carry
-    (VERDICT r3 #3 — the flagship MoE-LLaMA x PP composition)."""
-    S, M = 2, 3
-    mesh = make_mesh(devices8[:S], stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (6, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    pipe_loss = make_pipeline_loss(MOE_CFG, mesh, M)
-    l_pipe = float(jax.jit(pipe_loss)(staged, tokens))
-    l_serial = float(serial_moe_loss(params, tokens, M))
-    np.testing.assert_allclose(l_pipe, l_serial, rtol=1e-5)
-
-    g_pipe = llama.merge_blocks_from_stages(
-        jax.jit(jax.grad(pipe_loss))(staged, tokens)
-    )
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        g_pipe,
-    )
-
-
-def test_1f1b_moe_equals_gpipe_and_serial(devices8):
-    """The memory-bounded schedule carries the per-stage aux term too
-    (uniform 1.0 loss-cotangent seed across stages)."""
-    S, M = 2, 3
-    mesh = make_mesh(devices8[:S], stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (6, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    l_1f1b, g_1f1b = jax.jit(
-        make_1f1b_value_and_grad(MOE_CFG, mesh, M)
-    )(staged, tokens)
-    l_gpipe, g_gpipe = jax.jit(
-        jax.value_and_grad(make_pipeline_loss(MOE_CFG, mesh, M))
-    )(staged, tokens)
-
-    np.testing.assert_allclose(float(l_1f1b), float(l_gpipe), rtol=1e-5)
-    np.testing.assert_allclose(
-        float(l_1f1b), float(serial_moe_loss(params, tokens, M)), rtol=1e-5
-    )
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-5, rtol=2e-4
-        ),
-        g_gpipe,
-        g_1f1b,
-    )
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_from_stages(g_1f1b),
-    )
-
-
-def test_moe_dp_pp_2d_mesh_equals_serial(devices8):
-    """MoE x the flagship DP x PP topology on a 2-D mesh."""
-    S, M = 2, 2
-    mesh = make_mesh(devices8[:4], data=2, stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    pipe_loss = make_pipeline_loss(MOE_CFG, mesh, M, data_axis="data")
-    l_pipe = float(jax.jit(pipe_loss)(staged, tokens))
-    # DP shards the microbatch dim: each replica sees its own [mb] rows, so
-    # the oracle groups are the M*dp per-replica microbatches
-    l_serial = float(serial_moe_loss(params, tokens, M * 2))
-    np.testing.assert_allclose(l_pipe, l_serial, rtol=1e-5)
-
-
-@pytest.mark.parametrize("cf", [2.0, 0.5])
-def test_ep_dp_pp_expert_sharded_equals_dense(cf, devices8):
-    """EP x DP x PP: expert stacks sharded over the data axis, capacity
-    buckets moved between data rows by all_to_all each tick.  Routing and
-    capacity are decided per data shard BEFORE the a2a, so loss and grads
-    are EXACTLY the replicated-expert pipeline's — at ample capacity
-    (cf=2.0) and under heavy drops (cf=0.5) alike — while each device
-    holds only E/n experts per stage."""
-    import dataclasses
-
-    cfg = dataclasses.replace(MOE_CFG, capacity_factor=cf)
-    S, M = 2, 2
-    mesh = make_mesh(devices8[:4], data=2, stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    dense_loss = make_pipeline_loss(cfg, mesh, M, data_axis="data")
-    l_dense, g_dense = jax.jit(jax.value_and_grad(dense_loss))(staged, tokens)
-
-    sharded = shard_staged_params(staged, mesh, ep_axis="data")
-    w = sharded["blocks"]["moe"]["w_gate"]
-    assert w.addressable_shards[0].data.shape[2] == cfg.n_experts // 2, (
-        "expert stacks not sharded over the data axis"
-    )
-    ep_loss = make_pipeline_loss(
-        cfg, mesh, M, data_axis="data", ep_axis="data"
-    )
-    l_ep, g_ep = jax.jit(jax.value_and_grad(ep_loss))(sharded, tokens)
-
-    np.testing.assert_allclose(float(l_ep), float(l_dense), rtol=1e-6)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-5, rtol=2e-4
-        ),
-        g_dense,
-        g_ep,
-    )
-
-
-def test_ep_pipeline_train_step_and_guards(devices8):
-    """The EP x DP x PP train step runs (loss falls over steps); EP and
-    TP remain mutually exclusive in the staged specs."""
-    S, M = 2, 2
-    mesh = make_mesh(devices8[:4], data=2, stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    staged = shard_staged_params(
-        llama.split_blocks_for_stages(params, S), mesh, ep_axis="data"
-    )
-    tx = optax.adam(1e-2)
-    step = make_pipeline_train_step(
-        MOE_CFG, tx, mesh, M, data_axis="data", ep_axis="data"
-    )
-    opt = tx.init(staged)
-    losses = []
-    toks = jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 64)
-    for _ in range(5):
-        staged, opt, loss = step(staged, opt, toks)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0]
-
-    with pytest.raises(NotImplementedError, match="exclusive"):
-        make_pipeline_train_step(
-            MOE_CFG, tx, mesh, M, data_axis="data", ep_axis="data",
-            tp_axis="data",
-        )
-
-
-@pytest.mark.parametrize("schedule", ["interleaved", "interleaved-1f1b"])
-def test_ep_interleaved_expert_sharded_equals_dense(schedule, devices8):
-    """EP rides BOTH interleaved schedules (round-5 closure of the
-    chunked-EP guard): the 5-d expert stacks shard their expert dim over
-    the data axis, the per-tick a2a sits in uniform control flow (the
-    interleaved tick runs its chunk unconditionally under EP), and loss
-    + grads equal the dense replicated-expert run exactly — heavy drops
-    included."""
-    import dataclasses
-
-    cfg = dataclasses.replace(MOE_CFG, capacity_factor=0.5)
-    S, V, M, dp = 2, 2, 2, 2
-    mesh = make_mesh(devices8[:4], data=dp, stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-    staged = llama.split_blocks_interleaved(params, S, V)
-
-    if schedule == "interleaved":
-        def vag(ep_axis, p):
-            return jax.jit(jax.value_and_grad(make_interleaved_pipeline_loss(
-                cfg, mesh, M, V, data_axis="data", ep_axis=ep_axis
-            )))(p, tokens)
-    else:
-        def vag(ep_axis, p):
-            return jax.jit(make_1f1b_value_and_grad(
-                cfg, mesh, M, data_axis="data", num_chunks=V,
-                ep_axis=ep_axis,
-            ))(p, tokens)
-
-    l_dense, g_dense = vag(None, staged)
-    sharded = shard_staged_params(staged, mesh, ep_axis="data", chunked=True)
-    w = sharded["blocks"]["moe"]["w_gate"]
-    assert w.addressable_shards[0].data.shape[3] == cfg.n_experts // dp
-    l_ep, g_ep = vag("data", sharded)
-
-    np.testing.assert_allclose(float(l_ep), float(l_dense), rtol=1e-6)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-5, rtol=2e-4
-        ),
-        g_dense,
-        g_ep,
-    )
-
-
-@pytest.mark.parametrize("cf,stash", [
-    (2.0, "input"), (0.5, "input"), (2.0, "residuals"),
-])
-def test_ep_1f1b_expert_sharded_equals_dense(cf, stash, devices8):
-    """EP x DP x PP under the 1F1B schedules: the forward slot runs the
-    stage body unconditionally (output masked) so the EP all_to_all sits
-    in uniform control flow, and expert-slice grads take the 1/n
-    normalization.  Loss and grads must equal the dense replicated-expert
-    1F1B run EXACTLY — ample capacity and heavy drops alike (routing and
-    capacity are per data shard, decided before the a2a)."""
-    import dataclasses
-
-    cfg = dataclasses.replace(MOE_CFG, capacity_factor=cf)
-    S, M = 2, 2
-    mesh = make_mesh(devices8[:4], data=2, stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    l_dense, g_dense = jax.jit(
-        make_1f1b_value_and_grad(
-            cfg, mesh, M, data_axis="data", stash=stash
-        )
-    )(staged, tokens)
-
-    sharded = shard_staged_params(staged, mesh, ep_axis="data")
-    l_ep, g_ep = jax.jit(
-        make_1f1b_value_and_grad(
-            cfg, mesh, M, data_axis="data", stash=stash, ep_axis="data"
-        )
-    )(sharded, tokens)
-
-    np.testing.assert_allclose(float(l_ep), float(l_dense), rtol=1e-6)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-5, rtol=2e-4
-        ),
-        g_dense,
-        g_ep,
-    )
-    # and the dense 1F1B itself is pinned to GPipe elsewhere; close the
-    # loop cheaply against the serial per-microbatch oracle on the loss
-    def oracle(p):
-        mbs = tokens.reshape(M * 2, tokens.shape[0] // (M * 2), -1)
-
-        def per_mb(mb):
-            logits, aux = llama.llama_forward_with_aux(p, mb, cfg)
-            return causal_lm_loss(logits, mb) + cfg.moe_aux_weight * aux
-
-        return jnp.mean(jax.vmap(per_mb)(mbs))
-
-    np.testing.assert_allclose(float(l_ep), float(oracle(params)), rtol=1e-5)
-
-
 def test_grad_accum_equals_full_batch():
     """Microbatch grad accumulation == full-batch step (linearity), the
     standalone capability of s01_b1 without the stage split."""
@@ -633,731 +336,4 @@ def test_fused_steps_equal_sequential(schedule, devices8):
         ),
         p_fused,
         p_seq,
-    )
-
-
-# ---------------------------------------------------------------- interleaved
-
-
-def test_interleaved_split_merge_roundtrip():
-    params = llama.init_llama_params(jax.random.PRNGKey(2), CFG)
-    split = llama.split_blocks_interleaved(params, 2, 2)
-    leaf = jax.tree.leaves(split["blocks"])[0]
-    assert leaf.shape[:3] == (2, 2, 1)  # [S, V, Lc]
-    back = llama.merge_blocks_interleaved(split)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(a, b), params, back
-    )
-    # chunk mapping: blocks[s][v] is global chunk v*S + s
-    l0 = params["blocks"]["wq"]
-    np.testing.assert_array_equal(split["blocks"]["wq"][1, 0, 0], l0[1])
-    np.testing.assert_array_equal(split["blocks"]["wq"][0, 1, 0], l0[2])
-
-
-@pytest.mark.parametrize("mbs", [2, 4])
-def test_interleaved_loss_and_grads_equal_serial(
-    params_and_tokens, mbs, devices8
-):
-    """The virtual-stage schedule (V=2 chunks/device) must match the
-    serial model exactly — the tick algebra (slot -> (chunk, microbatch)
-    map, single-ring delay-1 transfers, wrap-to-chunk-v+1) is all pinned
-    by this equality."""
-    params, tokens = params_and_tokens
-    tokens = tokens[:4]  # B=4: divisible by both M values
-    S, V = 2, 2
-    mesh = make_mesh(devices8[:S], stage=S)
-    staged = llama.split_blocks_interleaved(params, S, V)
-    loss = make_interleaved_pipeline_loss(CFG, mesh, mbs, V)
-    np.testing.assert_allclose(
-        float(jax.jit(loss)(staged, tokens)),
-        float(serial_loss(params, tokens)),
-        rtol=1e-5,
-    )
-    g = jax.jit(jax.grad(loss))(staged, tokens)
-    g_serial = jax.grad(serial_loss)(params, tokens)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-def test_interleaved_rejects_indivisible_microbatches(devices8):
-    mesh = make_mesh(devices8[:2], stage=2)
-    with pytest.raises(ValueError, match="divisible"):
-        make_interleaved_pipeline_loss(CFG, mesh, 3, 2)
-
-
-def test_interleaved_dp_pp_train_step(params_and_tokens, devices8):
-    """schedule='interleaved' on the 2-D (data, stage) mesh: one step
-    equals the serial step."""
-    params, tokens = params_and_tokens
-    tokens = tokens[:4]
-    S, V, M = 2, 2, 2
-    mesh = make_mesh(devices8[:4], data=2, stage=S)
-    staged = shard_staged_params(
-        llama.split_blocks_interleaved(params, S, V), mesh
-    )
-    tx = optax.adam(1e-3)
-    step = make_pipeline_train_step(
-        CFG, tx, mesh, M, data_axis="data", schedule="interleaved",
-        num_chunks=V,
-    )
-    new_params, _, loss = step(staged, tx.init(staged), tokens)
-
-    sloss, g = jax.value_and_grad(serial_loss)(params, tokens)
-    updates, _ = tx.update(g, tx.init(params), params)
-    expect = optax.apply_updates(params, updates)
-    np.testing.assert_allclose(float(loss), float(sloss), rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=1e-5, rtol=1e-4
-        ),
-        llama.merge_blocks_interleaved(jax.device_get(new_params)),
-        expect,
-    )
-
-
-def test_interleaved_moe_equals_serial(devices8):
-    """Switch-MoE rides the interleaved schedule: per-(chunk, microbatch)
-    dispatch groups are the per-layer-per-microbatch groups of the serial
-    oracle, so equality is exact."""
-    S, V, M = 2, 2, 2
-    mesh = make_mesh(devices8[:S], stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    staged = llama.split_blocks_interleaved(params, S, V)
-    loss = make_interleaved_pipeline_loss(MOE_CFG, mesh, M, V)
-    np.testing.assert_allclose(
-        float(jax.jit(loss)(staged, tokens)),
-        float(serial_moe_loss(params, tokens, M)),
-        rtol=1e-5,
-    )
-    g = jax.jit(jax.grad(loss))(staged, tokens)
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-# ---------------------------------------------------------------- DPxPPxTP
-
-
-@pytest.mark.parametrize("dp", [1, 2])
-def test_pipeline_tp_equals_serial(params_and_tokens, dp, devices8):
-    """Full 3-D parallelism (data, stage, model): Megatron TP inside each
-    pipeline stage.  Loss AND sharded-weight grads must equal the serial
-    model — the pmean-over-TP transpose and the in-block psums are what
-    this pins."""
-    params, tokens = params_and_tokens
-    S, T = 2, 2
-    tokens = tokens[:4]
-    if dp > 1:
-        mesh = make_mesh(devices8[: dp * S * T], data=dp, stage=S, model=T)
-    else:
-        mesh = make_mesh(devices8[: S * T], stage=S, model=T)
-    staged = llama.split_blocks_for_stages(params, S)
-    loss = make_pipeline_loss(
-        CFG, mesh, 2, data_axis="data" if dp > 1 else None, tp_axis="model"
-    )
-    np.testing.assert_allclose(
-        float(jax.jit(loss)(staged, tokens)),
-        float(serial_loss(params, tokens)),
-        rtol=1e-5,
-    )
-    g = jax.jit(jax.grad(loss))(staged, tokens)
-    g_serial = jax.grad(serial_loss)(params, tokens)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_from_stages(g),
-    )
-
-
-def test_pipeline_tp_train_step_sharded_placement(params_and_tokens, devices8):
-    """The 3-D train step with actually-sharded param placement: one step
-    runs, block weights are placed over (stage, model), loss is finite."""
-    import optax as _optax
-
-    params, tokens = params_and_tokens
-    tokens = tokens[:4]
-    mesh = make_mesh(devices8, data=2, stage=2, model=2)
-    staged = shard_staged_params(
-        llama.split_blocks_for_stages(params, 2), mesh, tp_axis="model"
-    )
-    shard = staged["blocks"]["wq"].sharding.spec
-    assert shard == jax.sharding.PartitionSpec("stage", None, None, "model")
-    tx = _optax.adam(1e-3)
-    step = make_pipeline_train_step(
-        CFG, tx, mesh, 2, data_axis="data", tp_axis="model"
-    )
-    new_params, _, loss = step(staged, tx.init(staged), tokens)
-    sloss = float(serial_loss(params, tokens))
-    np.testing.assert_allclose(float(loss), sloss, rtol=1e-5)
-    # the TP placement must SURVIVE the step — a train step that silently
-    # drops tp_axis would return P('stage', ...) params (regression guard:
-    # the first wiring of this feature did exactly that)
-    out_spec = new_params["blocks"]["wq"].sharding.spec
-    assert out_spec == jax.sharding.PartitionSpec(
-        "stage", None, None, "model"
-    ), out_spec
-    # the 1F1B schedule accepts tp_axis through the SAME train-step
-    # builder (regression guard on the pass-through at the vag dispatch):
-    # loss == serial and the TP placement survives the optimizer step
-    step1f = make_pipeline_train_step(
-        CFG, tx, mesh, 2, data_axis="data", tp_axis="model",
-        schedule="1f1b",
-    )
-    p1f, _, loss1f = step1f(staged, tx.init(staged), tokens)
-    np.testing.assert_allclose(float(loss1f), sloss, rtol=1e-5)
-    assert p1f["blocks"]["wq"].sharding.spec == jax.sharding.PartitionSpec(
-        "stage", None, None, "model"
-    )
-
-    # the interleaved schedule composes with TP too: 5-d chunked specs
-    # (chunked=True), loss == serial, placement survives the step
-    staged_il = shard_staged_params(
-        llama.split_blocks_interleaved(params, 2, 2), mesh,
-        tp_axis="model", chunked=True,
-    )
-    assert staged_il["blocks"]["wq"].sharding.spec == (
-        jax.sharding.PartitionSpec("stage", None, None, None, "model")
-    )
-    step_il = make_pipeline_train_step(
-        CFG, tx, mesh, 2, data_axis="data", tp_axis="model",
-        schedule="interleaved", num_chunks=2,
-    )
-    p_il, _, loss_il = step_il(staged_il, tx.init(staged_il), tokens)
-    np.testing.assert_allclose(float(loss_il), sloss, rtol=1e-5)
-    assert p_il["blocks"]["wq"].sharding.spec == (
-        jax.sharding.PartitionSpec("stage", None, None, None, "model")
-    )
-
-
-def test_interleaved_tp_grads_equal_serial(params_and_tokens, devices8):
-    """Interleaved virtual stages x Megatron TP: grads ≡ serial through
-    the chunk-indexed TP blocks (the chunked 5-d specs must shard the
-    OUTPUT dim of column weights, not the input dim)."""
-    params, tokens = params_and_tokens
-    tokens = tokens[:4]
-    mesh = make_mesh(devices8[:4], stage=2, model=2)
-    staged = llama.split_blocks_interleaved(params, 2, 2)
-    loss = make_interleaved_pipeline_loss(CFG, mesh, 2, 2, tp_axis="model")
-    np.testing.assert_allclose(
-        float(jax.jit(loss)(staged, tokens)),
-        float(serial_loss(params, tokens)),
-        rtol=1e-5,
-    )
-    g = jax.jit(jax.grad(loss))(staged, tokens)
-    g_serial = jax.grad(serial_loss)(params, tokens)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-@pytest.mark.parametrize("stash", ["input", "residuals"])
-def test_1f1b_tp_equals_serial(params_and_tokens, stash, devices8):
-    """TP inside the hand-rolled 1F1B backward: the cooperative vjp runs
-    the in-block psum transposes across TP members, and the final 1/t
-    normalization (see make_1f1b_value_and_grad) makes loss AND grads
-    equal the serial model — both stash variants, on the 3-D mesh."""
-    params, tokens = params_and_tokens
-    tokens = tokens[:4]
-    mesh = make_mesh(devices8, data=2, stage=2, model=2)
-    staged = llama.split_blocks_for_stages(params, 2)
-    l, g = jax.jit(
-        make_1f1b_value_and_grad(
-            CFG, mesh, 2, data_axis="data", stash=stash, tp_axis="model"
-        )
-    )(staged, tokens)
-    np.testing.assert_allclose(
-        float(l), float(serial_loss(params, tokens)), rtol=1e-5
-    )
-    g_serial = jax.grad(serial_loss)(params, tokens)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_from_stages(g),
-    )
-
-
-@pytest.mark.parametrize("cf", [2.0, 0.5])
-def test_pipeline_tp_moe_equals_serial(cf, devices8):
-    """Switch-MoE under pipeline TP on the full (data, stage, model) mesh:
-    expert stacks shard their expert dim over the tp axis
-    (staged_param_specs n_experts schema), routing stays global per
-    (data-shard, stage, microbatch) group via make_tp_moe_fn, and the
-    block's row-parallel psum completes the partial combine — so loss and
-    grads equal the serial per-microbatch oracle EXACTLY, at ample
-    capacity (cf=2.0) and under heavy drops (cf=0.5) alike."""
-    import dataclasses
-
-    cfg = dataclasses.replace(MOE_CFG, capacity_factor=cf)
-    S, T, dp, M = 2, 2, 2, 2
-    mesh = make_mesh(devices8[: dp * S * T], data=dp, stage=S, model=T)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    # sharpen router margins: TP's psum reorders fp summation by ulps,
-    # and with the near-uniform init logits a ulp can flip a near-tie
-    # routing decision under tight capacity — the test pins the drop
-    # MECHANISM (global capacity, identical bucketing on every shard),
-    # not fp tie-breaking, so give the router decisive margins
-    params["blocks"]["moe"]["router"] = (
-        30.0 * params["blocks"]["moe"]["router"]
-    )
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    sharded = shard_staged_params(staged, mesh, tp_axis="model")
-    w = sharded["blocks"]["moe"]["w_gate"]
-    assert w.addressable_shards[0].data.shape[2] == cfg.n_experts // T, (
-        "expert stacks not sharded over the model axis"
-    )
-
-    loss = make_pipeline_loss(
-        cfg, mesh, M, data_axis="data", tp_axis="model"
-    )
-    l_pipe, g_pipe = jax.jit(jax.value_and_grad(loss))(sharded, tokens)
-
-    # per-microbatch oracle at THIS cf (serial_moe_loss is pinned to
-    # MOE_CFG's ample capacity): dp shards the microbatch dim -> M*dp
-    # per-replica dispatch groups
-    def oracle(p):
-        mbs = tokens.reshape(M * dp, tokens.shape[0] // (M * dp), -1)
-
-        def per_mb(mb):
-            logits, aux = llama.llama_forward_with_aux(p, mb, cfg)
-            return causal_lm_loss(logits, mb) + cfg.moe_aux_weight * aux
-
-        return jnp.mean(jax.vmap(per_mb)(mbs))
-
-    l_serial = float(oracle(params))
-    np.testing.assert_allclose(float(l_pipe), l_serial, rtol=1e-5)
-
-    g_serial = jax.grad(oracle)(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_from_stages(g_pipe),
-    )
-
-
-@pytest.mark.parametrize("stash", ["input", "residuals"])
-def test_1f1b_tp_moe_equals_serial(stash, devices8):
-    """MoE x TP inside the hand-rolled 1F1B backward: the router grad is
-    replicated across tp (pmean re-typing) while the expert slices follow
-    the 1/t matmul normalization — pinned against the serial oracle, for
-    both the remat and residual-stash backward variants."""
-    S, T, M = 2, 2, 2
-    mesh = make_mesh(devices8[: S * T], stage=S, model=T)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    staged = llama.split_blocks_for_stages(params, S)
-
-    l, g = jax.jit(
-        make_1f1b_value_and_grad(
-            MOE_CFG, mesh, M, tp_axis="model", stash=stash
-        )
-    )(staged, tokens)
-    l_serial = float(serial_moe_loss(params, tokens, M))
-    np.testing.assert_allclose(float(l), l_serial, rtol=1e-5)
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_from_stages(g),
-    )
-
-
-def test_interleaved_tp_moe_equals_serial(devices8):
-    """MoE x TP x the interleaved virtual-stage schedule: the chunked
-    5-d expert stacks shard their expert dim over tp."""
-    S, V, M, T = 2, 2, 2, 2
-    mesh = make_mesh(devices8[: S * T], stage=S, model=T)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    staged = llama.split_blocks_interleaved(params, S, V)
-    loss = make_interleaved_pipeline_loss(
-        MOE_CFG, mesh, M, V, tp_axis="model"
-    )
-    np.testing.assert_allclose(
-        float(jax.jit(loss)(staged, tokens)),
-        float(serial_moe_loss(params, tokens, M)),
-        rtol=1e-5,
-    )
-    g = jax.jit(jax.grad(loss))(staged, tokens)
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-# ------------------------------------------------------- interleaved 1F1B
-
-
-@pytest.mark.parametrize("stages,chunks,microbatches,dp,tp", [
-    (2, 2, 2, 1, 1),
-    (2, 3, 4, 1, 1),
-    (4, 2, 4, 1, 1),
-    (2, 2, 4, 2, 2),
-])
-def test_interleaved_1f1b_equals_serial(
-    stages, chunks, microbatches, dp, tp, devices8
-):
-    """The production Megatron schedule — interleaved virtual stages WITH
-    the memory-bounded hand-rolled 1F1B backward: loss and grads must
-    equal the serial model across chunk counts, stage counts, and the
-    full DP x PP x TP composition (the backward stream's reversed slot
-    map and ring indexing are what this pins)."""
-    S, V, M = stages, chunks, microbatches
-    cfg = LlamaConfig(
-        vocab_size=64, dmodel=32, num_heads=2, n_layers=S * V, ctx_size=16,
-        dtype="float32",
-    )
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (M * dp * 2, 16), 0, 64
-    )
-
-    def serial(p):
-        return causal_lm_loss(llama.llama_forward(p, tokens, cfg), tokens)
-
-    kw = {}
-    names = {"stage": S}
-    if dp > 1:
-        names = {"data": dp, "stage": S}
-        kw["data_axis"] = "data"
-    if tp > 1:
-        names["model"] = tp
-        kw["tp_axis"] = "model"
-    mesh = make_mesh(devices8[: S * dp * tp], **names)
-    staged = llama.split_blocks_interleaved(params, S, V)
-    l, g = jax.jit(
-        make_1f1b_value_and_grad(cfg, mesh, M, num_chunks=V, **kw)
-    )(staged, tokens)
-    np.testing.assert_allclose(float(l), float(serial(params)), rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        jax.grad(serial)(params),
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-def test_interleaved_1f1b_moe_equals_serial(devices8):
-    """Switch-MoE rides interleaved 1F1B: every (chunk, microbatch)
-    backward slot banks its chunk's weighted aux term."""
-    S, V, M = 2, 2, 2
-    mesh = make_mesh(devices8[:S], stage=S)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), MOE_CFG)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    staged = llama.split_blocks_interleaved(params, S, V)
-    l, g = jax.jit(
-        make_1f1b_value_and_grad(MOE_CFG, mesh, M, num_chunks=V)
-    )(staged, tokens)
-    np.testing.assert_allclose(
-        float(l), float(serial_moe_loss(params, tokens, M)), rtol=1e-5
-    )
-    g_serial = jax.grad(lambda p: serial_moe_loss(p, tokens, M))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        g_serial,
-        llama.merge_blocks_interleaved(g),
-    )
-
-
-def test_interleaved_1f1b_bounds_activation_memory(devices8):
-    """The point of composing the two schedules: at V=2 the interleaved
-    scan-transpose saves every chunk-tick's residuals (O(M·V)); the
-    interleaved 1F1B ring-stashes 2VS-1 chunk inputs and rematerializes —
-    compiled temp memory must be several times smaller at M=8."""
-    cfg = LlamaConfig(
-        vocab_size=128, dmodel=32, num_heads=2, n_layers=4, ctx_size=256,
-        dtype="float32",
-    )
-    S, V, M = 2, 2, 8
-    mesh = make_mesh(devices8[:S], stage=S)
-    staged = shard_staged_params(
-        llama.split_blocks_interleaved(
-            llama.init_llama_params(jax.random.PRNGKey(0), cfg), S, V
-        ),
-        mesh, chunked=True,
-    )
-    tx = optax.adam(1e-3)
-    opt = tx.init(staged)
-    tokens = jnp.zeros((M, cfg.ctx_size), jnp.int32)
-
-    temps = {}
-    for sched in ("interleaved", "interleaved-1f1b"):
-        step = make_pipeline_train_step(
-            cfg, tx, mesh, M, schedule=sched, num_chunks=V
-        )
-        stats = step.lower(staged, opt, tokens).compile().memory_analysis()
-        temps[sched] = stats.temp_size_in_bytes
-    assert temps["interleaved-1f1b"] * 2 < temps["interleaved"], temps
-
-
-def test_interleaved_1f1b_train_step_and_guards(devices8):
-    """The train-step builder dispatches the interleaved-1f1b schedule
-    (loss falls over steps) and the guards hold: residual stash and EP
-    are not wired for chunked stacks, num_chunks >= 2 required."""
-    S, V, M = 2, 2, 2
-    mesh = make_mesh(devices8[:S], stage=S)
-    cfg = LlamaConfig(
-        vocab_size=64, dmodel=32, num_heads=2, n_layers=S * V, ctx_size=16,
-        dtype="float32",
-    )
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    staged = shard_staged_params(
-        llama.split_blocks_interleaved(params, S, V), mesh, chunked=True
-    )
-    tx = optax.adam(1e-2)
-    step = make_pipeline_train_step(
-        cfg, tx, mesh, M, schedule="interleaved-1f1b", num_chunks=V
-    )
-    opt = tx.init(staged)
-    toks = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, 64)
-    losses = []
-    for _ in range(5):
-        staged, opt, loss = step(staged, opt, toks)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0]
-
-    with pytest.raises(NotImplementedError, match="residual"):
-        make_1f1b_value_and_grad(
-            cfg, mesh, M, stash="residuals", num_chunks=V
-        )
-    with pytest.raises(ValueError, match="num_chunks"):
-        make_pipeline_train_step(
-            cfg, tx, mesh, M, schedule="interleaved-1f1b", num_chunks=1
-        )
-    with pytest.raises(ValueError, match="divisible"):
-        make_1f1b_value_and_grad(cfg, mesh, 3, num_chunks=V)
-
-
-# ------------------------------------------------------- SP inside the pipe
-
-
-@pytest.mark.parametrize("mode,dp,flash", [
-    ("ring", 1, False),
-    ("ring", 2, True),
-    ("ulysses", 1, False),
-    ("ulysses", 2, False),
-])
-def test_pipeline_sp_equals_serial(mode, dp, flash, devices8):
-    """Sequence parallelism INSIDE pipeline stages (round-5 closure of
-    the SP x PP hole): tokens shard their length dim over a seq axis,
-    every stage runs ring/Ulysses attention at global positions, targets
-    come from one pre-scan boundary ppermute, and loss + grads equal the
-    serial model on the (data, stage, seq) mesh."""
-    import dataclasses
-
-    cfg = dataclasses.replace(CFG, use_flash=flash)
-    S, sq, M = 2, 2, 2
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-
-    def serial(p):
-        return causal_lm_loss(llama.llama_forward(p, tokens, cfg), tokens)
-
-    names = (
-        {"data": dp, "stage": S, "seq": sq} if dp > 1
-        else {"stage": S, "seq": sq}
-    )
-    mesh = make_mesh(devices8[: S * sq * dp], **names)
-    staged = llama.split_blocks_for_stages(params, S)
-    loss = make_pipeline_loss(
-        cfg, mesh, M, data_axis="data" if dp > 1 else None,
-        seq_axis="seq", sp_mode=mode,
-    )
-    l, g = jax.jit(jax.value_and_grad(loss))(staged, tokens)
-    np.testing.assert_allclose(float(l), float(serial(params)), rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        jax.grad(serial)(params),
-        llama.merge_blocks_from_stages(g),
-    )
-
-
-def test_pipeline_sp_train_step_and_guards(devices8):
-    """The train-step builder threads seq_axis (gpipe only); the guarded
-    compositions raise instead of silently deadlocking or mis-training."""
-    S, sq, M = 2, 2, 2
-    mesh = make_mesh(devices8[: S * sq], stage=S, seq=sq)
-    params = llama.init_llama_params(jax.random.PRNGKey(0), CFG)
-    staged = shard_staged_params(
-        llama.split_blocks_for_stages(params, S), mesh
-    )
-    tx = optax.adam(1e-2)
-    step = make_pipeline_train_step(CFG, tx, mesh, M, seq_axis="seq")
-    opt = tx.init(staged)
-    toks = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, 64)
-    losses = []
-    for _ in range(5):
-        staged, opt, loss = step(staged, opt, toks)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0]
-
-    with pytest.raises(NotImplementedError, match="residual"):
-        make_pipeline_train_step(
-            CFG, tx, mesh, M, seq_axis="seq", schedule="1f1b-stash"
-        )
-    with pytest.raises(NotImplementedError, match="dense"):
-        make_1f1b_value_and_grad(MOE_CFG, mesh, M, seq_axis="seq")
-
-
-@pytest.mark.parametrize("tp", [1, 2])
-def test_pipeline_sp_moe_equals_sp_oracle(tp, devices8):
-    """Switch-MoE under SP x PP (round 5), with and without TP inside
-    the stages: per-(seq-shard, layer, microbatch) dispatch groups with
-    the aux term on its OWN scan carry (the CE slot holds
-    token-count-normalized sums under seq — one denominator cannot
-    serve both).  The oracle is make_sp_loss itself, per microbatch on
-    a seq-only mesh: identical routing groups and the identical
-    sharded-MoE aux estimator, so equality is exact (TP members compute
-    identical global routing, so the same oracle serves tp > 1)."""
-    from ddl25spring_tpu.parallel.sp import make_sp_loss
-
-    S, sq, M = 2, 2, 2
-    cfg = (
-        LlamaConfig(
-            vocab_size=64, dmodel=32, num_heads=4, n_layers=4,
-            ctx_size=16, dtype="float32", n_experts=4,
-            capacity_factor=2.0,
-        )
-        if tp > 1 else MOE_CFG
-    )
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    names = {"stage": S, "seq": sq}
-    kw = {}
-    if tp > 1:
-        names["model"] = tp
-        kw["tp_axis"] = "model"
-    mesh = make_mesh(devices8[: S * sq * tp], **names)
-    staged = llama.split_blocks_for_stages(params, S)
-    loss = make_pipeline_loss(cfg, mesh, M, seq_axis="seq", **kw)
-    l, g = jax.jit(jax.value_and_grad(loss))(staged, tokens)
-
-    mesh_sq = make_mesh(devices8[:sq], seq=sq)
-    sp_loss = make_sp_loss(cfg, mesh_sq, seq_axis="seq")
-
-    def oracle(p):
-        mbs = tokens.reshape(M, tokens.shape[0] // M, -1)
-        return jnp.mean(
-            jnp.stack([sp_loss(p, mbs[m]) for m in range(M)])
-        )
-
-    np.testing.assert_allclose(float(l), float(oracle(params)), rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        jax.device_get(jax.grad(oracle)(params)),
-        jax.device_get(llama.merge_blocks_from_stages(g)),
-    )
-
-
-@pytest.mark.parametrize("mode,num_chunks,tp", [
-    ("ring", 1, 1), ("ulysses", 1, 1), ("ring", 2, 1),
-    ("ring", 1, 2), ("ulysses", 1, 2), ("ring", 2, 2),
-])
-def test_sp_1f1b_equals_serial(mode, num_chunks, tp, devices8):
-    """SP under the hand-rolled 1F1B backwards (plain AND interleaved
-    chunks, AND composed with TP): sequence-sharded stages with
-    ring/Ulysses attention, the forward slot running unconditionally
-    (masked) so the seq collectives stay uniform, blocks pcast varying
-    over seq so the final psum-over-seq assembles each shard's local
-    grad paths exactly once (the TP 1/t normalization then composes
-    unchanged) — loss and grads equal the serial model."""
-    S, sq, M, V = 2, 2, 2, num_chunks
-    cfg = CFG4H if tp > 1 else CFG
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-
-    def serial(p):
-        return causal_lm_loss(llama.llama_forward(p, tokens, cfg), tokens)
-
-    names = {"stage": S, "seq": sq}
-    kw = {}
-    if tp > 1:
-        names["model"] = tp
-        kw["tp_axis"] = "model"
-    mesh = make_mesh(devices8[: S * sq * tp], **names)
-    staged = (
-        llama.split_blocks_interleaved(params, S, V) if V > 1
-        else llama.split_blocks_for_stages(params, S)
-    )
-    l, g = jax.jit(
-        make_1f1b_value_and_grad(
-            cfg, mesh, M, seq_axis="seq", sp_mode=mode, num_chunks=V, **kw
-        )
-    )(staged, tokens)
-    np.testing.assert_allclose(float(l), float(serial(params)), rtol=1e-5)
-    merged = (
-        llama.merge_blocks_interleaved(g) if V > 1
-        else llama.merge_blocks_from_stages(g)
-    )
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        jax.grad(serial)(params),
-        merged,
-    )
-
-
-@pytest.mark.parametrize("mode", ["ring", "ulysses"])
-def test_pipeline_sp_tp_equals_serial(mode, devices8):
-    """The full PP x SP x TP composition on a (stage, seq, model) mesh:
-    Megatron-split matmuls operate on the per-shard head subset, ring /
-    Ulysses attention runs over the seq axis within each stage, and loss
-    + grads equal the serial model."""
-    cfg = CFG4H
-    S, sq, T, M = 2, 2, 2, 2
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-
-    def serial(p):
-        return causal_lm_loss(llama.llama_forward(p, tokens, cfg), tokens)
-
-    mesh = make_mesh(devices8[:8], stage=S, seq=sq, model=T)
-    staged = llama.split_blocks_for_stages(params, S)
-    loss = make_pipeline_loss(
-        cfg, mesh, M, seq_axis="seq", sp_mode=mode, tp_axis="model"
-    )
-    l, g = jax.jit(jax.value_and_grad(loss))(staged, tokens)
-    np.testing.assert_allclose(float(l), float(serial(params)), rtol=1e-5)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            jax.device_get(a), jax.device_get(b), atol=2e-4, rtol=2e-3
-        ),
-        jax.grad(serial)(params),
-        llama.merge_blocks_from_stages(g),
     )

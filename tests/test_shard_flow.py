@@ -22,6 +22,7 @@ pays for ZERO extra strategy compiles.
 """
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -412,6 +413,22 @@ def test_h013_serve_pair_mismatch_and_declared_dim():
 # ----------------------------------------- pinned real-strategy contracts
 
 
+_SOURCE_TABLE_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.M,
+)
+
+
+def _program(hlo_text: str) -> str:
+    """The optimized HLO minus what names the Python call stack it was
+    compiled from: jax 0.9.0 prints source-location tables in the module
+    header and a ``stack_frame_id`` on every op, and the session cache
+    compiles each strategy under whichever test asked first."""
+    return re.sub(
+        r" ?stack_frame_id=\d+", "", _SOURCE_TABLE_RE.sub("", hlo_text)
+    )
+
+
 @pytest.mark.parametrize("bespoke,ruled", [
     ("dp", "dp-rules"), ("zero3", "zero3-rules"),
 ])
@@ -423,7 +440,8 @@ def test_rule_table_strategy_is_bitwise_identical_to_bespoke(
     eventually replace — the rule engine changes where the strategy is
     written down, not what XLA compiles."""
     a, b = _report(bespoke), _report(ruled)
-    assert a["hlo_text"] == b["hlo_text"]
+    assert _program(a["hlo_text"]) == _program(b["hlo_text"])
+    assert "ENTRY" in _program(a["hlo_text"])  # the program survived
     assert a["signature_violations"] == [] == b["signature_violations"]
 
 

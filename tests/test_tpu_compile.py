@@ -1,0 +1,227 @@
+"""The main path's programs, compiled for a TPU v5e that is DESCRIBED, not
+attached (``on-chip-measurement`` guide §2, third rehearsal).
+
+Interpret-mode tests cannot see what the chip's compiler refuses: a slice
+off the tiling, too much fast memory, a program that does not fit 16 GB.
+These compile the kernels and the step programs ``chip_smoke.py`` runs, at
+the widths it runs them, in this process, without a chip.  A compile that
+passes is not a chip run and is never reported as one.
+
+Rules this file keeps (the guide says why): the topology is described
+inside a module-scoped, non-autouse fixture — never at import, in a
+``skipif`` or a ``parametrize`` argument, or in ``conftest.py``; nothing
+built from it exists outside a fixture or a test; every compile happens in
+the test's own process; the persistent compile cache is off around them (a
+described-device executable cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The program asks ``jax.default_backend()`` to choose the kernel
+    over interpret mode / the dense path; with a described chip the
+    attached backend is still the CPU.  The test steers that here — the
+    program has no option for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+# --------------------------------------------------------------- kernels
+
+# [B, L, H, hd]: the reference LLaMA's own width (head_dim 48 — not a
+# multiple of 128), a long-ish context, and the 32k-token single sequence
+FLASH_SHAPES = [(8, 256, 6, 48), (2, 2048, 8, 128), (1, 32768, 4, 128)]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize(
+    "shape", FLASH_SHAPES, ids=lambda s: "x".join(map(str, s))
+)
+def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
+    from ddl25spring_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    lowered = jax.jit(fwd if direction == "fwd" else bwd).lower(x, x, x)
+    assert "tpu_custom_call" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_flash_attention_with_lse_compiles_for_v5e(one_chip):
+    from ddl25spring_tpu.ops.flash_attention import flash_attention_with_lse
+
+    x = jax.ShapeDtypeStruct((2, 2048, 8, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    fn = jax.jit(
+        lambda q, k, v: flash_attention_with_lse(q, k, v, interpret=False)
+    )
+    lowered = fn.lower(x, x, x)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert len(lowered.compile().output_shardings) == 2  # (o, lse)
+
+
+# ------------------------------------------------------------ serve path
+
+
+@pytest.fixture(scope="module")
+def ref_serve(one_chip):
+    """``bench.py --serve --serve-model ref``'s model, pool geometry and
+    the abstract arguments of its two programs."""
+    from ddl25spring_tpu.models import llama
+    from ddl25spring_tpu.serve import driver, kv_pages
+
+    cfg = driver.serve_model("ref")
+    knobs = driver.engine_knobs()
+    pages_per_seq = -(-cfg.ctx_size // knobs["page_len"])
+    params = jax.eval_shape(
+        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    )
+    pool = jax.eval_shape(lambda: kv_pages.init_page_pool(
+        cfg, n_pages=knobs["n_pages"], page_len=knobs["page_len"],
+        max_slots=knobs["max_slots"], pages_per_seq=pages_per_seq,
+    ))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    return cfg, knobs, *_abstract((params, pool, key), one_chip)
+
+
+def test_paged_decode_tick_compiles_for_v5e(ref_serve, one_chip):
+    from ddl25spring_tpu.serve.engine import make_decode_tick
+
+    cfg, knobs, params, pool, key = ref_serve
+    tokens = jax.ShapeDtypeStruct(
+        (knobs["max_slots"],), jnp.int32, sharding=one_chip
+    )
+    tick = jax.jit(
+        make_decode_tick(cfg, temperature=0.0, sentinel=False),
+        donate_argnums=(1,),
+    )
+    compiled = tick.lower(params, pool, tokens, key).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_prefill_program_compiles_for_v5e(ref_serve, one_chip):
+    from ddl25spring_tpu.serve.engine import make_prefill
+
+    cfg, knobs, params, pool, key = ref_serve
+    B, Lp = knobs["prefill_batch"], knobs["max_prompt_len"]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    prefill = jax.jit(
+        make_prefill(cfg, max_prompt_len=Lp, start=0, temperature=0.0,
+                     sentinel=False),
+        donate_argnums=(1,),
+    )
+    compiled = prefill.lower(
+        params, pool, i32(B, Lp), i32(B), i32(B), i32(B), key
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+
+
+# ----------------------------------------------------------- train steps
+
+
+def test_llama_ref_train_step_compiles_with_the_kernel(topo, as_on_tpu):
+    """``lab/s01_b2_dp_pp.py --workload llama`` on one chip: the jitted
+    pipeline step (mesh ``data=1, stage=1``) at the reference constants,
+    flash ON — and the kernel is in the lowered text, not the dense
+    path."""
+    import optax
+
+    from ddl25spring_tpu.models import llama
+    from ddl25spring_tpu.parallel.pipeline import make_pipeline_train_step
+    from ddl25spring_tpu.utils.config import LlamaConfig, replace
+
+    cfg = replace(LlamaConfig(), use_flash=True)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "stage"))
+    rep = NamedSharding(mesh, P())
+    tx = optax.adam(8e-4)
+    staged = jax.eval_shape(lambda: llama.split_blocks_for_stages(
+        llama.init_llama_params(jax.random.PRNGKey(0), cfg), 1
+    ))
+    opt_state = jax.eval_shape(tx.init, staged)
+    staged, opt_state = _abstract((staged, opt_state), rep)
+    tokens = jax.ShapeDtypeStruct((24, cfg.ctx_size), jnp.int32, sharding=rep)
+    step = make_pipeline_train_step(cfg, tx, mesh, 3)
+    lowered = step.lower(staged, opt_state, tokens)
+    assert "tpu_custom_call" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.slow  # ~60 s of TPU compile alone; chip_smoke.py runs this
+# very step on the chip, and the LLaMA step above keeps a whole train
+# step in the tier-1 run
+def test_resnet18_step_at_batch_1024_fits_a_v5e(topo):
+    """``python bench.py`` on one chip: the ResNet-18 DP step at per-chip
+    batch 1024 (bf16 — the builder reads the device's platform), under
+    the chip's 16 GB.  Compiled by hand before the first chip run."""
+    from ddl25spring_tpu.benchmarks import build_resnet_step
+
+    step, params, opt_state, meta = build_resnet_step(
+        topo.devices[:1], 1, 1, 1, 1024
+    )
+    rep = NamedSharding(meta["mesh"], P())
+    params, opt_state = _abstract((params, opt_state), rep)
+    raw = (
+        jax.ShapeDtypeStruct((1024, 32, 32, 3), jnp.uint8, sharding=rep),
+        jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=rep),
+    )
+    mem = step.lower(params, opt_state, raw).compile().memory_analysis()
+    total = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert 0 < total < V5E_HBM_BYTES, mem

@@ -22,7 +22,6 @@ import pytest
 
 from ddl25spring_tpu.obs import xla_analytics as xa
 from ddl25spring_tpu.utils.compat import (
-    HAS_VMA,
     compiled_cost_analysis,
     compiled_memory_stats,
 )
@@ -160,11 +159,11 @@ def test_roofline_projection_bounds():
     assert p["bound"] == "ici"
 
 
-# ------------------------------------------------ compat fallbacks (0.4.x)
+# ------------------------------------- compiled-program probes (compat)
 
 
 class _FakeMemStatsOld:
-    """CompiledMemoryStats as jax 0.4.x ships it: no peak field."""
+    """CompiledMemoryStats of a backend that reports no peak."""
 
     argument_size_in_bytes = 1000
     output_size_in_bytes = 300
@@ -211,11 +210,7 @@ def test_memory_stats_absent_or_raising_is_none():
     assert compiled_memory_stats(ReturnsNone()) is None
 
 
-def test_cost_analysis_per_module_list_and_failures():
-    class ListShaped:
-        def cost_analysis(self):
-            return [{"flops": 7.0}, {"flops": 1.0}]
-
+def test_cost_analysis_dict_and_failures():
     class DictShaped:
         def cost_analysis(self):
             return {"flops": 9.0}
@@ -226,9 +221,8 @@ def test_cost_analysis_per_module_list_and_failures():
 
     class Empty:
         def cost_analysis(self):
-            return []
+            return {}
 
-    assert compiled_cost_analysis(ListShaped()) == {"flops": 7.0}
     assert compiled_cost_analysis(DictShaped()) == {"flops": 9.0}
     assert compiled_cost_analysis(Raising()) is None
     assert compiled_cost_analysis(Empty()) is None
@@ -429,13 +423,9 @@ def test_pipeline_signature_ticks_times_permutes():
     assert r["signature_violations"] == []
     T = r["meta"]["ticks"]  # M + S - 1
     hops = _count(r, "collective-permute")
-    if r["lowered"] == "loss":  # pre-VMA: forward schedule only
-        assert hops == T, (
-            f"GPipe forward must hop exactly microbatches+stages-1={T} "
-            f"times, measured {hops}"
-        )
-    else:  # value_and_grad: the scan transpose replays the ring
-        assert T * 2 <= hops <= T * 3
+    # value_and_grad: the scan transpose replays the M+S-1 forward hops
+    assert r["lowered"] == "value_and_grad"
+    assert T * 2 <= hops <= T * 3
     assert all(
         o["axes"] == ["stage"]
         for o in r["collectives"]["ops"]
@@ -504,13 +494,8 @@ def test_reports_carry_memory_and_flops():
     assert "TPU v4" in r["projection"]
 
 
-@pytest.mark.skipif(
-    not HAS_VMA,
-    reason="pipeline grad-path signatures need VMA-typed shard_map "
-    "(same gating as tests/test_pipeline.py); forward-only covered above",
-)
 def test_pipeline_grad_signature_doubles_the_ring():
-    # on VMA jax the pipeline strategy lowers value_and_grad: the
+    # the pipeline strategy lowers value_and_grad: the
     # transpose must replay the forward's M+S-1 hops in reverse
     r = _report("pipeline")
     assert r["lowered"] == "value_and_grad"

@@ -105,7 +105,7 @@ meshes and long-running training):
 
 Schedules: `--schedule {gpipe,1f1b,1f1b-stash,interleaved,interleaved-1f1b}`.
 Equivalence with the serial model is pinned in `tests/test_pipeline.py`;
-measured schedule memory/throughput tables live in `RESULTS.md` §4/§7b.
+measured schedule memory tables live in `RESULTS.md` §4.
 """),
 ]
 
