@@ -16,7 +16,16 @@ TPU-first choices:
   block body regardless of depth);
 - RMSNorm / RoPE / SwiGLU per LLaMA convention; attention einsums run in
   ``cfg.dtype`` (bfloat16 on TPU: MXU-native) with fp32 softmax and fp32
-  master params.
+  master params;
+- the parts of the model carry ``jax.named_scope`` names — ``embed``,
+  ``attn``, ``mlp``, and ``blocks`` for the layer scan's own plumbing
+  around them; the head is named by its callers, with what they make of
+  the logits (``head_loss`` in ``parallel/pipeline.py``, ``head`` in the
+  serving programs) — that every compiled program keeps in
+  its operations' ``op_name`` (JAX wraps them as ``jvp(attn)`` /
+  ``transpose(jvp(attn))`` in a backward pass), so that a profiler trace
+  can be summed by part (``benchmark/tools/trace_scopes.py``).  Names are
+  metadata: they change no operation.
 """
 
 from __future__ import annotations
@@ -157,7 +166,15 @@ def block_forward(
       this shard's tokens and a ring-attention implementation.
     """
     dtype = jnp.dtype(cfg.dtype)
-    B, L, D = x.shape
+    with jax.named_scope("attn"):
+        x = _attn_half(p, x, cfg, dtype, tp_axis, pos, attn_fn)
+    with jax.named_scope("mlp"):
+        return _ffn_half(p, x, cfg, dtype, tp_axis, moe_fn)
+
+
+def _attn_half(p, x, cfg, dtype, tp_axis, pos, attn_fn):
+    """``x + attention(rms_norm(x))``: the first half of a block."""
+    B, L, _ = x.shape
     hd = cfg.head_dim
 
     h = rms_norm(x, p["ln1"])
@@ -196,8 +213,12 @@ def block_forward(
     attn_out = attn @ p["wo"].astype(dtype)
     if tp_axis is not None:
         attn_out = lax.psum(attn_out, tp_axis)
-    x = x + attn_out
+    return x + attn_out
 
+
+def _ffn_half(p, x, cfg, dtype, tp_axis, moe_fn):
+    """``(x + ffn(rms_norm(x)), aux)``: the second half of a block."""
+    B, L, D = x.shape
     h = rms_norm(x, p["ln2"])
     if cfg.n_experts > 0:
         if tp_axis is not None and moe_fn is None:
@@ -253,7 +274,10 @@ def apply_blocks(
         h, aux = block_forward(block_p, h, cfg, **block_kw)
         return h, aux
 
-    out, aux = lax.scan(body, x, stacked)
+    # "blocks" names what the layer scan itself adds around attn / mlp:
+    # slicing the stacked weights, stacking and reading back residuals
+    with jax.named_scope("blocks"):
+        out, aux = lax.scan(body, x, stacked)
     if with_aux:
         return out, aux.sum()
     return out
@@ -262,7 +286,8 @@ def apply_blocks(
 def embed(params: Params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """Token embedding (parity: ``LLamaFirstStage.embed``,
     ``lab/s01_b1_microbatches.py:84``)."""
-    return params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]
+    with jax.named_scope("embed"):
+        return params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]
 
 
 def unembed(params: Params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:

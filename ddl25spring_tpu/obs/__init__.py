@@ -7,13 +7,19 @@ package is the always-on, low-overhead substrate that needs no XLA
 profiler (whether a device trace completes on the chip at hand is probed
 by ``chip_smoke.py``'s ``runtime_probe`` on every chip run):
 
-- :mod:`~ddl25spring_tpu.obs.spans` — host-side nested span tracer
-  producing Chrome-trace/Perfetto JSON (and mirroring every span into
-  ``jax.profiler.TraceAnnotation`` so it shows inside real device traces
-  when those work);
+- :mod:`~ddl25spring_tpu.obs.spans` — ``span()``, the one way to mark a
+  host-side region.  ALWAYS ON: every span enters a
+  ``jax.profiler.TraceAnnotation`` (so any open profiler session sees it,
+  in ``/host:CPU`` on the device trace's clock) and writes ``(t_start,
+  duration)`` into the ring of its name in ``counters``.  Behind the
+  flag: the nested Chrome-trace/Perfetto JSON of ``SpanRecorder``;
 - :mod:`~ddl25spring_tpu.obs.logger` — append-only JSONL step metrics with
   a run-metadata header (mesh, layout, git sha, jax version);
-- :mod:`~ddl25spring_tpu.obs.counters` — values from INSIDE jitted
+- :mod:`~ddl25spring_tpu.obs.counters` — ALWAYS ON: bounded, stamped,
+  process-global host rings (``sample`` / ``window`` / ``wrapped`` /
+  ``oldest_t``) that hold every span and those of the serving
+  scheduler's per-pass counts that a reader cuts a window out of after
+  the fact (the benchmark's per-layer metrics).  Behind the flag: values from INSIDE jitted
   programs via ``jax.debug.callback`` (MoE aux/load stats, pipeline tick
   cadence, ZeRO collective bytes);
 - ``tools/obs_report.py`` — folds a run directory into a summary table
@@ -45,11 +51,14 @@ runtime counterpart):
   merged with spans + flight into one Perfetto trace by
   ``tools/trace_export.py``).
 
-Everything is gated by one trace-time flag (:mod:`~ddl25spring_tpu.obs.
-state`): disabled (the default), instrumented step functions lower to HLO
-identical to uninstrumented ones — zero cost, pinned in
-``tests/test_obs.py``.  Enable with ``DDL25_OBS=1`` or ``obs.enable()``
-*before* building/tracing the step.
+What is always on costs the host about 2.5 us a span and 0.7 us a sample
+(``benchmark/tools/span_cost.py``) and touches no compiled program; the
+compiled programs carry ``jax.named_scope`` and kernel names, which are
+metadata.  Everything ELSE is gated by one trace-time flag
+(:mod:`~ddl25spring_tpu.obs.state`): disabled (the default), instrumented
+step functions lower to HLO identical to uninstrumented ones — zero cost,
+pinned in ``tests/test_obs.py``.  Enable with ``DDL25_OBS=1`` or
+``obs.enable()`` *before* building/tracing the step.
 """
 
 from ddl25spring_tpu.obs import sentinels

@@ -18,6 +18,17 @@ sees every shard's value, which is exactly what load-balance stats want).
 Static facts that are known at trace time and carry no runtime cost even
 when enabled — e.g. bytes moved by ZeRO's all_gather per step — go through
 :func:`add_static`.
+
+Always on, whatever the flag says: the host-side RINGS
+(:meth:`CounterSet.sample` / :meth:`CounterSet.window`).  Every
+:func:`ddl25spring_tpu.obs.spans.span` writes ``(t_start, duration)`` into
+the ring of its name, and the serving scheduler writes its per-pass counts
+(``serve.active_slots``, ``serve.prefill.prompt_tokens``, ...), stamped on
+absolute ``time.perf_counter()``.  A ring keeps a name's newest
+:data:`RING_CAP` samples in insertion order, so a reader in the same
+process can cut any window out of it after the fact; ``wrapped`` and
+``oldest_t`` tell a ring that has lost the window's first samples from a
+window in which nothing happened.  Nothing here touches a jitted program.
 """
 
 from __future__ import annotations
@@ -27,9 +38,37 @@ import math
 import os
 import threading
 import time
+from array import array
 from typing import Any
 
+import numpy as np
+
 from ddl25spring_tpu.obs import state
+
+# samples a ring keeps per name: a 51 s window of 3 ms decode ticks is
+# 17,000; 2 x 2^17 doubles are 2 MiB a name, allocated on its first sample
+RING_CAP = 1 << 17
+
+
+class _Ring:
+    """The newest ``cap`` ``(t, value)`` pairs of one name, in the order
+    they were written."""
+
+    __slots__ = ("t", "v", "n")
+
+    def __init__(self, cap: int) -> None:
+        self.t = array("d", bytes(8 * cap))
+        self.v = array("d", bytes(8 * cap))
+        self.n = 0  # samples ever written
+
+    def ordered(self) -> tuple[np.ndarray, np.ndarray]:
+        """Copies of the kept stamps and values, oldest first."""
+        cap = len(self.t)
+        kept = min(self.n, cap)
+        t = np.frombuffer(self.t, np.float64, kept)
+        v = np.frombuffer(self.v, np.float64, kept)
+        i = self.n % cap if self.n > cap else 0  # the oldest kept sample
+        return np.roll(t, -i), np.roll(v, -i)  # np.roll copies
 
 
 class CounterSet:
@@ -40,6 +79,7 @@ class CounterSet:
         self._scalars: dict[str, dict[str, float]] = {}
         self._series: dict[str, list[tuple[float, float]]] = {}
         self._static: dict[str, Any] = {}
+        self._rings: dict[str, _Ring] = {}
         self._t0 = time.perf_counter()
 
     # ---- host-side ------------------------------------------------------
@@ -64,6 +104,53 @@ class CounterSet:
         t = time.perf_counter() - self._t0
         with self._lock:
             self._series.setdefault(name, []).append((float(index), t))
+
+    def sample(self, name: str, value: float, t: float | None = None) -> None:
+        """Write ``(t, value)`` into the ring of ``name``; ``t`` is on
+        absolute ``time.perf_counter()`` (now, when not given).  Always
+        on; not part of :meth:`snapshot`."""
+        if t is None:
+            t = time.perf_counter()
+        with self._lock:
+            ring = self._rings.get(name)
+            if ring is None:
+                ring = self._rings[name] = _Ring(RING_CAP)
+            i = ring.n % len(ring.t)
+            ring.t[i] = t
+            ring.v[i] = value
+            ring.n += 1
+
+    def window(self, name: str, t0: float, t1: float) -> list[tuple[float, float]]:
+        """The kept samples of ``name`` stamped inside ``[t0, t1]``, in
+        the order they were written (a span is written when it closes,
+        under the time it opened).  Empty for a name never sampled; where
+        the ring :meth:`wrapped`, ask :meth:`oldest_t` whether it still
+        reaches back to ``t0``."""
+        with self._lock:
+            ring = self._rings.get(name)
+            if ring is None:
+                return []
+            t, v = ring.ordered()
+        inside = (t >= t0) & (t <= t1)
+        return list(zip(t[inside].tolist(), v[inside].tolist()))
+
+    def wrapped(self, name: str) -> bool:
+        """Whether ``name``'s ring has dropped samples: more were written
+        than it keeps.  One that has not holds the whole series."""
+        with self._lock:
+            ring = self._rings.get(name)
+            return ring is not None and ring.n > len(ring.t)
+
+    def oldest_t(self, name: str) -> float | None:
+        """The stamp of the oldest sample ``name``'s ring still holds;
+        ``None`` when it holds none.  Of a ring that :meth:`wrapped`, a
+        window that opens before it has lost samples."""
+        with self._lock:
+            ring = self._rings.get(name)
+            if ring is None or ring.n == 0:
+                return None
+            cap = len(ring.t)
+            return ring.t[ring.n % cap if ring.n > cap else 0]
 
     def add_static(self, name: str, value: Any) -> None:
         """Record a trace-time fact (idempotent per name: last write wins —
@@ -125,6 +212,7 @@ class CounterSet:
             self._scalars.clear()
             self._series.clear()
             self._static.clear()
+            self._rings.clear()
             self._t0 = time.perf_counter()
 
 

@@ -1,13 +1,21 @@
-"""Host-side span tracer emitting Chrome-trace / Perfetto-loadable JSON.
+"""The one way to mark a host-side region: :func:`span`.
 
-Why host-side: a device-side ``jax.profiler`` capture is a short window in
-the one process that holds the chip; the always-available instrument is
-nested wall-clock spans recorded on the host and written in the Chrome
-Trace Event format — loadable in ``chrome://tracing`` /
-https://ui.perfetto.dev without any XLA profiler involvement.  Each span
-*also* enters a ``jax.profiler.TraceAnnotation``, so when a device trace
-is being taken the same spans appear inside it for free
-(``annotate()``-compatible by construction).
+ALWAYS, whatever ``DDL25_OBS`` says, a span
+
+- enters a ``jax.profiler.TraceAnnotation`` carrying its stats: while any
+  profiler session is open it lands in the trace's ``/host:CPU`` plane on
+  the device trace's own clock, and it costs one ``TraceMe`` check
+  otherwise;
+- writes ``(t_start, duration)``, on absolute ``time.perf_counter()``,
+  into the ring of its name in the process-global
+  :data:`ddl25spring_tpu.obs.counters.counters`, which a reader in the
+  same process windows after the fact.
+
+ONLY when telemetry is enabled (:mod:`~ddl25spring_tpu.obs.state`) it is
+also recorded by the :class:`SpanRecorder`, which writes nested wall-clock
+spans in the Chrome Trace Event format — loadable in ``chrome://tracing``
+/ https://ui.perfetto.dev without any XLA profiler involvement.
+:func:`instant` is gated the same way.
 
 Format: the JSON Object Format — ``{"traceEvents": [...], ...}`` — with
 ``"X"`` (complete) duration events carrying ``name``/``cat``/``ph``/
@@ -26,21 +34,13 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Any, Iterator
 
+from jax.profiler import TraceAnnotation
+
 from ddl25spring_tpu.obs import state
-
-
-def _annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when jax is importable (it always
-    is in this package, but spans must not *require* a working backend)."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler API missing/broken
-        return nullcontext()
+from ddl25spring_tpu.obs.counters import counters
 
 
 class SpanRecorder:
@@ -90,7 +90,7 @@ class SpanRecorder:
         real profiler timeline when one is active."""
         tid = threading.get_ident()
         ts = self._now_us()
-        with _annotation(name):
+        with TraceAnnotation(name, **args):
             try:
                 yield
             finally:
@@ -166,12 +166,40 @@ def set_recorder(rec: SpanRecorder) -> SpanRecorder:
     return prev
 
 
-def span(name: str, cat: str = "host", **args: Any):
-    """Module-level convenience on the default recorder.  A no-op context
-    when telemetry is disabled — call sites need no guard."""
-    if not state.enabled():
-        return nullcontext()
-    return _default.span(name, cat=cat, **args)
+class _Span:
+    """What :func:`span` returns: annotation + ring sample always, the
+    default recorder's Chrome event when telemetry is enabled."""
+
+    __slots__ = ("name", "cat", "stats", "_inner", "_t0")
+
+    def __init__(self, name: str, cat: str, stats: dict[str, Any]) -> None:
+        self.name, self.cat, self.stats = name, cat, stats
+
+    def __enter__(self) -> None:
+        if state.enabled():
+            self._inner = _default.span(self.name, cat=self.cat, **self.stats)
+        else:
+            self._inner = TraceAnnotation(self.name, **self.stats)
+        self._inner.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        counters.sample(self.name, time.perf_counter() - self._t0, self._t0)
+        self._inner.__exit__(*exc)
+
+
+def span(name: str, cat: str = "host", **stats: Any):
+    """Mark the block as the region ``name``.  ``stats`` (counts of the
+    boundary: plain numbers and strings) ride into the profiler trace and
+    the Chrome event; call sites need no guard."""
+    return _Span(name, cat, stats)
+
+
+def watched() -> bool:
+    """Whether anything will show a span's stats: a profiler session is
+    open, or telemetry is enabled.  For the caller whose stat costs
+    something to count."""
+    return state.enabled() or TraceAnnotation.is_enabled()
 
 
 def instant(name: str, **args: Any) -> None:

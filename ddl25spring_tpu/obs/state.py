@@ -1,6 +1,15 @@
 """The one global switch for run telemetry.
 
-Everything in :mod:`ddl25spring_tpu.obs` keys off this flag **at trace
+What the flag governs: everything that writes a file or enters a compiled
+program — the ``SpanRecorder``'s Chrome-trace JSON and ``instant()``, the
+timeline, memscope, the metrics logger's consumers, and the in-jit
+``counters.emit`` / ``counters.mark`` callbacks.  What it does NOT govern
+(always on, host-only, bounded): ``spans.span()``'s profiler annotation and
+its ``(t_start, duration)`` sample in the ``counters`` rings, and those of
+the serving scheduler's per-pass counts that a reader windows, in the same
+rings.
+
+The gated helpers key off this flag **at trace
 time**: when disabled, the instrumentation helpers are Python-level no-ops
 that insert nothing into jitted programs, so an instrumented step function
 lowers to HLO *identical* to an uninstrumented one (asserted in

@@ -169,6 +169,7 @@ def _fwd(q3, k3, v3, block_q, block_k, scale, causal, interpret):
         ],
         compiler_params=_params3(),
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return o, lse
 
@@ -336,6 +337,7 @@ def _bwd_pallas(q3, k3, v3, o, lse, do, delta, block_q, block_k, causal,
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         compiler_params=_params3(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -366,6 +368,7 @@ def _bwd_pallas(q3, k3, v3, o, lse, do, delta, block_q, block_k, causal,
         ],
         compiler_params=_params3(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do, lse, delta)
     return dq, dk, dv
 

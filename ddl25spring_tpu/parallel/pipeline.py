@@ -26,6 +26,15 @@ per-stage-group ``all_reduce`` (``lab/s01_b1_microbatches.py:66-178``,
 The schedule computed is exactly GPipe: all forwards stream through, then
 all backwards (the transpose drains in reverse) — matching the homework B1
 solution's schedule, with the bubble fraction (S-1)/(M+S-1).
+
+Beside the model's own scopes (``models/llama.py``) the step's parts carry
+``jax.named_scope`` names in every operation's ``op_name``:
+``schedule`` (what the tick scan adds around the parts: carries, stacked
+residuals, summed weight gradients), ``stage_permute`` (the hand-off
+between stages), ``grad_allreduce`` (the cast whose transpose all-reduces
+the head's gradients), ``head_loss`` (the last stage's unembed and loss:
+one scope around their ``cond``), ``optimizer``.  Names are metadata and
+change no operation.
 """
 
 from __future__ import annotations
@@ -417,11 +426,13 @@ def make_pipeline_loss(
         # uniformly on every device.  Using the invariant originals inside
         # ``lax.cond`` would put that psum inside a branch only the last
         # stage takes — a collective in non-uniform control flow.
-        head = pcast(
-            {k: params[k] for k in ("embed", "ln_f", "unembed")},
-            axes,
-            to="varying",
-        )
+        # (scoped: that psum is the head's gradient all-reduce)
+        with jax.named_scope("grad_allreduce"):
+            head = pcast(
+                {k: params[k] for k in ("embed", "ln_f", "unembed")},
+                axes,
+                to="varying",
+            )
 
         def tick(carry, t):
             incoming, loss_sum, aux_sum = carry
@@ -494,21 +505,23 @@ def make_pipeline_loss(
                 def loss_branch(x, y):
                     return causal_lm_loss(llama.unembed(head, x, cfg), y)
 
-            loss_mb = lax.cond(
-                jnp.logical_and(finish, active),
-                loss_branch,
-                lambda x, y: pcast(jnp.float32(0.0), axes, to="varying"),
-                x_out,
-                targets_mb[m],
-            )
+            with jax.named_scope("head_loss"):
+                loss_mb = lax.cond(
+                    jnp.logical_and(finish, active),
+                    loss_branch,
+                    lambda x, y: pcast(jnp.float32(0.0), axes, to="varying"),
+                    x_out,
+                    targets_mb[m],
+                )
 
             # hand activation to the next stage: the isend/irecv chain of
             # s01_b1_microbatches.py:87-140 as one collective-permute (at
             # V > 1 the wrap S-1 -> 0 is the chunk v -> v+1 hand-off,
             # arriving exactly one tick before its consumption slot)
-            outgoing = lax.ppermute(
-                x_out, stage_axis, [(i, (i + 1) % S) for i in range(S)]
-            )
+            with jax.named_scope("stage_permute"):
+                outgoing = lax.ppermute(
+                    x_out, stage_axis, [(i, (i + 1) % S) for i in range(S)]
+                )
             # the aux loss rides its OWN carry: under seq_axis the CE
             # slot holds token-count-normalized SUMS while aux stays a
             # per-dispatch-group mean — one denominator cannot serve both
@@ -520,9 +533,13 @@ def make_pipeline_loss(
             pcast(jnp.float32(0.0), axes, to="varying"),
         )
         tick_fn = jax.checkpoint(tick) if remat else tick
-        (_, loss_sum, aux_sum), _ = lax.scan(
-            tick_fn, carry0, jnp.arange(M * V + S - 1)
-        )
+        # "schedule" names what the tick scan itself adds around the parts:
+        # injecting and carrying activations and, in its transpose,
+        # stacking every tick's residuals and summing weight gradients
+        with jax.named_scope("schedule"):
+            (_, loss_sum, aux_sum), _ = lax.scan(
+                tick_fn, carry0, jnp.arange(M * V + S - 1)
+            )
 
         total = lax.psum(loss_sum, stage_axis)
         aux_total = lax.psum(aux_sum, stage_axis) / M
@@ -880,12 +897,13 @@ def make_1f1b_value_and_grad(
                 def loss_branch(x):
                     return causal_lm_loss(llama.unembed(hd, x, cfg), tgt)
 
-            loss = lax.cond(
-                finish,
-                loss_branch,
-                lambda x: pcast(jnp.float32(0.0), axes, to="varying"),
-                x_out,
-            )
+            with jax.named_scope("head_loss"):
+                loss = lax.cond(
+                    finish,
+                    loss_branch,
+                    lambda x: pcast(jnp.float32(0.0), axes, to="varying"),
+                    x_out,
+                )
             return x_out, loss + aux_term
 
         def chunk_slice(tree, v):
@@ -1006,12 +1024,13 @@ def make_1f1b_value_and_grad(
             loss_sum = loss_sum + w * loss_b
 
             # ---- boundary hops: activations forward, cotangents back ------
-            fwd_next = lax.ppermute(
-                x_out, stage_axis, [(i, (i + 1) % S) for i in range(S)]
-            )
-            cot_next = lax.ppermute(
-                dx, stage_axis, [(i, (i - 1) % S) for i in range(S)]
-            )
+            with jax.named_scope("stage_permute"):
+                fwd_next = lax.ppermute(
+                    x_out, stage_axis, [(i, (i + 1) % S) for i in range(S)]
+                )
+                cot_next = lax.ppermute(
+                    dx, stage_axis, [(i, (i - 1) % S) for i in range(S)]
+                )
             return (fwd_next, cot_next, ring, gblocks, ghead, loss_sum), None
 
         def vzeros(x, dt=None):
@@ -1118,12 +1137,15 @@ def make_1f1b_value_and_grad(
                 gblocks = jax.tree.map(lambda a, g: a + w * g, gblocks, db)
                 ghead = jax.tree.map(lambda a, g: a + w * g, ghead, dh)
 
-                fwd_next = lax.ppermute(
-                    x_out, stage_axis, [(i, (i + 1) % S) for i in range(S)]
-                )
-                cot_next = lax.ppermute(
-                    dx, stage_axis, [(i, (i - 1) % S) for i in range(S)]
-                )
+                with jax.named_scope("stage_permute"):
+                    fwd_next = lax.ppermute(
+                        x_out, stage_axis,
+                        [(i, (i + 1) % S) for i in range(S)],
+                    )
+                    cot_next = lax.ppermute(
+                        dx, stage_axis,
+                        [(i, (i - 1) % S) for i in range(S)],
+                    )
                 return (
                     fwd_next, cot_next, ring, tok_ring, gblocks, ghead,
                     loss_sum,
@@ -1364,8 +1386,9 @@ def make_pipeline_train_step(
     @partial(jax.jit, donate_argnums=donate_argnums(donate))
     def step(params, opt_state, tokens):
         loss, grads = vag(params, tokens)
-        updates, new_state = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_state = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         new_params, new_state = sentinels.guard(
             "pipeline", (new_params, new_state), loss=loss, grads=grads,
             params=params, updates=updates,

@@ -53,6 +53,7 @@ from ddl25spring_tpu.obs import (
     spans as _spans,
     state as _obs_state,
 )
+from ddl25spring_tpu.obs.counters import counters as _counters
 from ddl25spring_tpu.obs.timeline import timeline as _timeline
 from ddl25spring_tpu.serve import kv_pages
 from ddl25spring_tpu.serve.prefix import Match, PrefixCache
@@ -95,45 +96,52 @@ def _paged_block(p, x, kp, vp, layer, rows, pages, offs, pos, cos, sin,
     fp32 equivalence pin holds bitwise.  ``rows`` is the clamped page
     table ``[B, P]`` of the sequences in this batch; ``pages``/``offs``
     the write coordinates of position ``pos`` (trash-routed where
-    masked)."""
+    masked).  Its parts are scoped ``attn`` / ``page_write`` /
+    ``page_gather`` / ``mlp`` (``jax.named_scope``: names in the
+    operations' metadata, no operation changes)."""
     dtype = jnp.dtype(cfg.dtype)
     B = x.shape[0]
     hd = cfg.head_dim
 
-    h = llama.rms_norm(x, p["ln1"])
-    q = (h @ p["wq"].astype(dtype)).reshape(B, 1, -1, hd)
-    k = (h @ p["wk"].astype(dtype)).reshape(B, 1, -1, hd)
-    v = (h @ p["wv"].astype(dtype)).reshape(B, 1, -1, hd)
-    q = _rope_rows(q, cos, sin)
-    k = _rope_rows(k, cos, sin)
+    with jax.named_scope("attn"):
+        h = llama.rms_norm(x, p["ln1"])
+        q = (h @ p["wq"].astype(dtype)).reshape(B, 1, -1, hd)
+        k = (h @ p["wk"].astype(dtype)).reshape(B, 1, -1, hd)
+        v = (h @ p["wv"].astype(dtype)).reshape(B, 1, -1, hd)
+        q = _rope_rows(q, cos, sin)
+        k = _rope_rows(k, cos, sin)
 
-    kp, vp = kv_pages.append_layer_kv(
-        kp, vp, layer, pages, offs, k[:, 0], v[:, 0]
-    )
-    ks = kp[rows, layer]  # [B, P, page_len, H, hd]
-    vs = vp[rows, layer]
-    P, page_len = ks.shape[1], ks.shape[2]
-    ks = ks.reshape(B, P * page_len, -1, hd)
-    vs = vs.reshape(B, P * page_len, -1, hd)
+    with jax.named_scope("page_write"):
+        kp, vp = kv_pages.append_layer_kv(
+            kp, vp, layer, pages, offs, k[:, 0], v[:, 0]
+        )
+    with jax.named_scope("page_gather"):
+        ks = kp[rows, layer]  # [B, P, page_len, H, hd]
+        vs = vp[rows, layer]
+        P, page_len = ks.shape[1], ks.shape[2]
+        ks = ks.reshape(B, P * page_len, -1, hd)
+        vs = vs.reshape(B, P * page_len, -1, hd)
 
-    s = jnp.einsum("bqhd,bmhd->bhqm", q, ks).astype(jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(hd))
-    live = jnp.arange(P * page_len)[None, :] <= pos[:, None]
-    s = jnp.where(live[:, None, None, :], s, -1e30)
-    probs = jax.nn.softmax(s, axis=-1).astype(dtype)
-    attn = jnp.einsum("bhqm,bmhd->bqhd", probs, vs)
-    attn_out = attn.reshape(B, 1, -1) @ p["wo"].astype(dtype)
-    if tp_axis is not None:
-        attn_out = lax.psum(attn_out, tp_axis)
-    x = x + attn_out
+    with jax.named_scope("attn"):
+        s = jnp.einsum("bqhd,bmhd->bhqm", q, ks).astype(jnp.float32)
+        s = s / jnp.sqrt(jnp.float32(hd))
+        live = jnp.arange(P * page_len)[None, :] <= pos[:, None]
+        s = jnp.where(live[:, None, None, :], s, -1e30)
+        probs = jax.nn.softmax(s, axis=-1).astype(dtype)
+        attn = jnp.einsum("bhqm,bmhd->bqhd", probs, vs)
+        attn_out = attn.reshape(B, 1, -1) @ p["wo"].astype(dtype)
+        if tp_axis is not None:
+            attn_out = lax.psum(attn_out, tp_axis)
+        x = x + attn_out
 
-    h = llama.rms_norm(x, p["ln2"])
-    gate = jax.nn.silu(h @ p["w_gate"].astype(dtype))
-    up = h @ p["w_up"].astype(dtype)
-    ffn_out = (gate * up) @ p["w_down"].astype(dtype)
-    if tp_axis is not None:
-        ffn_out = lax.psum(ffn_out, tp_axis)
-    return x + ffn_out, kp, vp
+    with jax.named_scope("mlp"):
+        h = llama.rms_norm(x, p["ln2"])
+        gate = jax.nn.silu(h @ p["w_gate"].astype(dtype))
+        up = h @ p["w_up"].astype(dtype)
+        ffn_out = (gate * up) @ p["w_down"].astype(dtype)
+        if tp_axis is not None:
+            ffn_out = lax.psum(ffn_out, tp_axis)
+        return x + ffn_out, kp, vp
 
 
 def make_decode_tick(
@@ -180,8 +188,9 @@ def make_decode_tick(
         slots = jnp.arange(S, dtype=jnp.int32)
 
         need = active & (pos % page_len == 0)
-        pool, ok = kv_pages.reserve_pages(pool, slots, pos, need)
-        pages, offs = kv_pages.write_page_ids(pool, slots, pos, active)
+        with jax.named_scope("page_write"):
+            pool, ok = kv_pages.reserve_pages(pool, slots, pos, need)
+            pages, offs = kv_pages.write_page_ids(pool, slots, pos, active)
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)  # [S, P]
 
         x = llama.embed(params, tokens[:, None], cfg)
@@ -199,10 +208,11 @@ def make_decode_tick(
                 )
                 return (x, kp, vp), None
 
-            (x, kp, vp), _ = lax.scan(
-                layer, (x, pool["k"], pool["v"]),
-                (params["blocks"], jnp.arange(cfg.n_layers)),
-            )
+            with jax.named_scope("blocks"):
+                (x, kp, vp), _ = lax.scan(
+                    layer, (x, pool["k"], pool["v"]),
+                    (params["blocks"], jnp.arange(cfg.n_layers)),
+                )
         else:
             def run_layer(bp, li, x, kp, vp):
                 return _paged_block(
@@ -213,13 +223,15 @@ def make_decode_tick(
             x, kp, vp = layer_stack(
                 params, run_layer, x, pool["k"], pool["v"]
             )
-        logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
-        if temperature == 0.0:
-            new_tok = logits.argmax(-1).astype(jnp.int32)
-        else:
-            new_tok = decode_mod.sample_logits(
-                logits, key, temperature, top_k, top_p
-            )
+        with jax.named_scope("head"):
+            logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
+        with jax.named_scope("sample"):
+            if temperature == 0.0:
+                new_tok = logits.argmax(-1).astype(jnp.int32)
+            else:
+                new_tok = decode_mod.sample_logits(
+                    logits, key, temperature, top_k, top_p
+                )
         pool = {
             **pool, "k": kp, "v": vp,
             "seq_len": jnp.where(active, pos + 1, pos),
@@ -304,10 +316,11 @@ def make_prefill(
             pos = jnp.full((B,), i, jnp.int32)
             writing = valid_row & (i >= starts) & (i < lens)
             need = writing & (i % page_len == 0)
-            pool, ok = kv_pages.reserve_pages(pool, slot_ids, pos, need)
-            pages, offs = kv_pages.write_page_ids(
-                pool, slot_ids, pos, writing
-            )
+            with jax.named_scope("page_write"):
+                pool, ok = kv_pages.reserve_pages(pool, slot_ids, pos, need)
+                pages, offs = kv_pages.write_page_ids(
+                    pool, slot_ids, pos, writing
+                )
             rows = jnp.clip(
                 pool["page_table"][
                     jnp.clip(slot_ids, 0, pool["page_table"].shape[0] - 1)
@@ -329,14 +342,16 @@ def make_prefill(
                 )
                 return (x, kp, vp), None
 
-            (x, kp, vp), _ = lax.scan(
-                layer, (x, pool["k"], pool["v"]),
-                (params["blocks"], jnp.arange(cfg.n_layers)),
-            )
-            logits = llama.unembed(params, x, cfg)[:, 0]
-            last_logits = jnp.where(
-                (i == lens - 1)[:, None], logits, last_logits
-            )
+            with jax.named_scope("blocks"):
+                (x, kp, vp), _ = lax.scan(
+                    layer, (x, pool["k"], pool["v"]),
+                    (params["blocks"], jnp.arange(cfg.n_layers)),
+                )
+            with jax.named_scope("head"):
+                logits = llama.unembed(params, x, cfg)[:, 0]
+                last_logits = jnp.where(
+                    (i == lens - 1)[:, None], logits, last_logits
+                )
             pool = {**pool, "k": kp, "v": vp}
             return (pool, last_logits, ok_all & ok), None
 
@@ -346,12 +361,13 @@ def make_prefill(
              jnp.bool_(True)),
             jnp.arange(start, max_prompt_len),
         )
-        if temperature == 0.0:
-            first = last_logits.argmax(-1).astype(jnp.int32)
-        else:
-            first = decode_mod.sample_logits(
-                last_logits, key, temperature, top_k, top_p
-            )
+        with jax.named_scope("sample"):
+            if temperature == 0.0:
+                first = last_logits.argmax(-1).astype(jnp.int32)
+            else:
+                first = decode_mod.sample_logits(
+                    last_logits, key, temperature, top_k, top_p
+                )
         sent = jnp.where(
             valid_row, slot_ids, pool["seq_len"].shape[0]
         )
@@ -999,6 +1015,13 @@ class ServeEngine:
         # shift when a drained replica leaves).
         self.trace_label = trace_label
         self.replica_id = 0
+        # what tells this engine's spans and rings (obs.spans.span,
+        # obs.counters) from another's in the same process: nothing for
+        # an unlabelled engine (the benchmark's; the driver's A/B arms)
+        # or the default label, ``@<label>`` after every name otherwise
+        self._obs_key = (
+            "" if trace_label in (None, "serve") else f"@{trace_label}"
+        )
         self._key = jax.random.PRNGKey(seed)
         # kept for the lazily-compiled start-offset prefill variants
         self._temperature = temperature
@@ -1305,6 +1328,41 @@ class ServeEngine:
             kind, vt=self.now(), engine=self.trace_label,
             replica=self.replica_id, **fields,
         )
+
+    def _span(self, name: str, **stats):
+        """A scheduler span (``obs.spans.span``: always in a profiler
+        trace and in the ring of its name), keyed by ``_obs_key``."""
+        return _spans.span(name + self._obs_key, cat="serve", **stats)
+
+    def _sample(self, name: str, value: float, t: float) -> None:
+        _counters.sample(name + self._obs_key, value, t)
+
+    def _tick_counts(self) -> dict[str, int]:
+        """The counts a decode pass starts with, from host state alone
+        (no device sync), as the pass's span stats.  The two that a
+        reader windows (the benchmark's ``slot_occupancy_pct`` and
+        ``kv_gather_live_pct``) are also sampled, under one stamp, into
+        the rings ``serve.active_slots`` and ``serve.kv_live_positions``;
+        a slot's live positions are the ones this pass attends to, its
+        own write included: ``prompt + generated``.  ``pages_used``
+        walks every slot, so it is counted only where a trace will show
+        it."""
+        active = live = 0
+        for req in self.slots:
+            if req is not None:
+                active += 1
+                live += req.prompt_len + len(req.tokens)
+        t = time.perf_counter()
+        self._sample("serve.active_slots", active, t)
+        self._sample("serve.kv_live_positions", live, t)
+        counts = {
+            "active": active, "queue": len(self.queue),
+            "kv_live_positions": live,
+            "kv_gathered_positions": self.max_slots * self.max_seq_len,
+        }
+        if _spans.watched():
+            counts["pages_used"] = self._host_pages_used()
+        return counts
 
     def warmup(self) -> None:
         """Compile all three programs (prefill, decode tick, release)
@@ -1726,9 +1784,21 @@ class ServeEngine:
             self._tl("serve_admit", rid=req.rid, slot=slot)
         self._adopt_batch(batch)
         prefill = self._prefill_at(start)
+        # what the pass does and what it could have done: the prompt
+        # positions it writes against the positions its padded scan runs
+        counts = {
+            "rows": len(batch), "start": start,
+            "prompt_tokens": int(lens.sum() - starts.sum()),
+            "scanned_positions": B * (self.max_prompt_len - start),
+        }
+        # (the two that the benchmark's prefill_fill_pct reader sums)
         t0 = time.perf_counter()
-        with _spans.span("serve.prefill", cat="serve",
-                         batch=len(batch), start=start):
+        for key in ("prompt_tokens", "scanned_positions"):
+            self._sample(f"serve.prefill.{key}", counts[key], t0)
+        with self._span(
+            "serve.prefill", **counts,
+            rids=" ".join(str(req.rid) for _, req, _ in batch),
+        ):
             self.pool, first, ok = prefill(
                 self.params, self.pool, jnp.asarray(prompts),
                 jnp.asarray(lens), jnp.asarray(starts),
@@ -1746,8 +1816,7 @@ class ServeEngine:
             # `first` is the committed stream).  Greedy: the key is
             # never consumed, so the engine's key stream — and with it
             # the spec-off bitwise twin — is untouched.
-            with _spans.span("serve.draft_prefill", cat="serve",
-                             batch=len(batch)):
+            with self._span("serve.draft_prefill", rows=len(batch)):
                 self.draft_pool, _draft_first, ok_d = self._draft_prefill(
                     self.draft_params, self.draft_pool,
                     jnp.asarray(prompts),
@@ -1780,62 +1849,63 @@ class ServeEngine:
             / self.max_prompt_len
             if self.clock == "virtual" else wall
         )
-        for row, (slot, req, m) in enumerate(batch):
-            req.admitted_t = now
-            req.prefill_start_t = t_pre
-            req.prefill_s = prefill_cost
-            self.slots[slot] = req
-            self._slot_last_rid[slot] = req.rid
-            # _adopted_pages[slot] was billed by _adopt_batch (S204:
-            # same method as the device refcount bump)
-            self._cached_pages[slot] = []
-            # mirror of the admission bill: full worst case under spec
-            # (the drafter pool's share-less need), discounted otherwise
-            self._reserved[slot] = self._pages_needed(req) - (
-                0 if self.spec_k else m.n_ref
-            )
-            self.admitted += 1
+        with self._span("serve.emit"):
+            for row, (slot, req, m) in enumerate(batch):
+                req.admitted_t = now
+                req.prefill_start_t = t_pre
+                req.prefill_s = prefill_cost
+                self.slots[slot] = req
+                self._slot_last_rid[slot] = req.rid
+                # _adopted_pages[slot] was billed by _adopt_batch (S204:
+                # same method as the device refcount bump)
+                self._cached_pages[slot] = []
+                # mirror of the admission bill: full worst case under spec
+                # (the drafter pool's share-less need), discounted otherwise
+                self._reserved[slot] = self._pages_needed(req) - (
+                    0 if self.spec_k else m.n_ref
+                )
+                self.admitted += 1
+                if self.prefix is not None:
+                    self.prefix.lookups += 1
+                    if m.matched > 0:
+                        self.prefix.hits += 1
+                        self.prefix.hit_tokens += m.matched
+                # saved = the scan positions actually skipped (the aligned
+                # floor), not the matched length — the [start, matched) gap
+                # is replayed, so billing it as saved would overcount
+                self.prefill_tokens_saved += start
+                self.prefill_flops_saved += start * self._flops_per_token
+                # the drafter owes this first committed token its KV; a
+                # request that completes at this very token is released by
+                # the flush, which clears the pending list with the slot
+                self._pending[slot] = [int(first[row])]
+                req.first_token_t = now
+                ttft = now - req.arrival_t
+                self.ttft_s.append(ttft)
+                # TTFT == queue_wait + prefill + first_decode by
+                # construction: the residual definition makes the virtual
+                # sum exact (pinned) and the wall sum exact up to float
+                # re-association
+                queue_wait = t_pre - req.arrival_t
+                first_decode = now - t_pre - prefill_cost
+                self.ttft_decomp.append((queue_wait, prefill_cost,
+                                         first_decode))
+                self._tl(
+                    "serve_prefill", rid=req.rid, slot=slot, start=start,
+                    prefix_hit_tokens=int(m.matched),
+                    wall_s=round(wall, 6),
+                )
+                self._tl(
+                    "serve_first_token", rid=req.rid,
+                    ttft_s=round(ttft, 6),
+                    queue_wait_s=round(queue_wait, 6),
+                    prefill_s=round(prefill_cost, 6),
+                    first_decode_s=round(first_decode, 6),
+                )
+                self._emit_token(slot, req, int(first[row]), now)
             if self.prefix is not None:
-                self.prefix.lookups += 1
-                if m.matched > 0:
-                    self.prefix.hits += 1
-                    self.prefix.hit_tokens += m.matched
-            # saved = the scan positions actually skipped (the aligned
-            # floor), not the matched length — the [start, matched) gap
-            # is replayed, so billing it as saved would overcount
-            self.prefill_tokens_saved += start
-            self.prefill_flops_saved += start * self._flops_per_token
-            # the drafter owes this first committed token its KV; a
-            # request that completes at this very token is released by
-            # the flush, which clears the pending list with the slot
-            self._pending[slot] = [int(first[row])]
-            req.first_token_t = now
-            ttft = now - req.arrival_t
-            self.ttft_s.append(ttft)
-            # TTFT == queue_wait + prefill + first_decode by
-            # construction: the residual definition makes the virtual
-            # sum exact (pinned) and the wall sum exact up to float
-            # re-association
-            queue_wait = t_pre - req.arrival_t
-            first_decode = now - t_pre - prefill_cost
-            self.ttft_decomp.append((queue_wait, prefill_cost,
-                                     first_decode))
-            self._tl(
-                "serve_prefill", rid=req.rid, slot=slot, start=start,
-                prefix_hit_tokens=int(m.matched),
-                wall_s=round(wall, 6),
-            )
-            self._tl(
-                "serve_first_token", rid=req.rid,
-                ttft_s=round(ttft, 6),
-                queue_wait_s=round(queue_wait, 6),
-                prefill_s=round(prefill_cost, 6),
-                first_decode_s=round(first_decode, 6),
-            )
-            self._emit_token(slot, req, int(first[row]), now)
-        if self.prefix is not None:
-            self._insert_prefixes(batch)
-        self._track_pages()
+                self._insert_prefixes(batch)
+            self._track_pages()
         flight.record(
             kind="serve_prefill", step=self._prefills, wall_s=round(wall, 6),
             admitted=len(batch), queue=len(self.queue),
@@ -1872,11 +1942,9 @@ class ServeEngine:
         toks = jnp.asarray(
             np.asarray(self._slot_last_tok, np.int32)
         )
+        counts = self._tick_counts()
         t0 = time.perf_counter()
-        with _spans.span(
-            "serve.decode_tick", cat="serve",
-            active=sum(r is not None for r in self.slots),
-        ):
+        with self._span("serve.decode_tick", **counts):
             self.pool, new_tok, ok = self._tick(
                 self.params, self.pool, toks, self._split_key()
             )
@@ -1888,17 +1956,17 @@ class ServeEngine:
         self._ticks += 1
         self._advance(self.tick_s)
         now = self.now()
-        for slot, req in enumerate(self.slots):
-            if req is not None:
-                self._emit_token(slot, req, int(new_tok[slot]), now)
-        self._track_pages()
+        with self._span("serve.emit"):
+            for slot, req in enumerate(self.slots):
+                if req is not None:
+                    self._emit_token(slot, req, int(new_tok[slot]), now)
+            self._track_pages()
         if self._ticks % 8 == 0 or self._ticks <= 2:
+            # active and queue: what the tick STARTED with, as its span
             flight.record(
                 kind="serve_tick", step=self._ticks,
-                wall_s=round(wall, 6),
-                active=sum(r is not None for r in self.slots),
-                queue=len(self.queue),
-                pages_used=self._host_pages_used(),
+                wall_s=round(wall, 6), active=counts["active"],
+                queue=counts["queue"], pages_used=self._host_pages_used(),
             )
 
     def _run_spec_round(self) -> None:
@@ -1939,8 +2007,9 @@ class ServeEngine:
         draft_fn = self._draft_k1 if steps == k + 1 else self._draft_k
 
         jlim = jnp.asarray(limits)
+        counts = self._tick_counts()
         t0 = time.perf_counter()
-        with _spans.span("serve.draft", cat="serve", steps=steps):
+        with self._span("serve.draft", steps=steps, **counts):
             self.draft_pool, drafts_dev, ok_d = draft_fn(
                 self.draft_params, self.draft_pool,
                 jnp.asarray(ctx), jnp.asarray(n_ctx), jlim,
@@ -1953,7 +2022,7 @@ class ServeEngine:
                          )[:, None], drafts_dev],
             axis=1,
         )
-        with _spans.span("serve.verify", cat="serve"):
+        with self._span("serve.verify"):
             self.pool, greedy_dev, ok_v = self._verify(
                 self.params, self.pool, toks, jlim,
             )
@@ -1979,67 +2048,66 @@ class ServeEngine:
         )
         now = self.now()
 
-        new_lens = np.zeros((S,), np.int32)
-        mask = np.zeros((S,), bool)
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            mask[slot] = True
-            self.draft_tokens_proposed += k
-            # accepted prefix: draft i is the target's own choice iff
-            # it equals greedy[i] (the argmax after consuming the
-            # previous position)
-            a = 0
-            while a < k and drafts[slot, a] == greedy[slot, a]:
-                a += 1
-            self.spec_accept_counts[a] = (
-                self.spec_accept_counts.get(a, 0) + 1
-            )
-            # committed token t0 sits at position p0; the round's
-            # emissions extend the written frontier one position each
-            p0 = req.prompt_len + len(req.tokens) - 1
-            emitted = 0
-            for j in range(a + 1):
-                self._emit_token(slot, req, int(greedy[slot, j]), now)
-                emitted += 1
-                if self.slots[slot] is None:
-                    break  # max_new / EOS — inside the draft window
-            # the first min(a, emitted) emissions are draft-origin
-            self.draft_tokens_accepted += min(a, emitted)
-            self._tl(
-                "serve_spec_round", rid=req.rid,
-                round=self._spec_rounds, accepted=a, rejected=k - a,
-                emitted=emitted,
-            )
-            new_lens[slot] = p0 + emitted
-            if self.slots[slot] is not None:
-                if emitted == k + 1:
-                    # full accept: the drafter never appended its own
-                    # final draft, and the bonus token is new to it
-                    self._pending[slot] = [
-                        int(drafts[slot, k - 1]), int(greedy[slot, k]),
-                    ]
-                else:
-                    self._pending[slot] = [int(greedy[slot, emitted - 1])]
-        # roll BOTH pools back to the committed frontier: rejected
-        # positions' fresh pages return to the free set (refcount
-        # decrement — the same discipline as release), stale values
-        # inside kept pages are overwritten before they become readable
-        jl = jnp.asarray(new_lens)
-        jm = jnp.asarray(mask)
-        self.pool = _truncate(self.pool, jl, jm)
-        self.draft_pool = _truncate(self.draft_pool, jl, jm)
-        self._track_pages()
+        with self._span("serve.emit"):
+            new_lens = np.zeros((S,), np.int32)
+            mask = np.zeros((S,), bool)
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                mask[slot] = True
+                self.draft_tokens_proposed += k
+                # accepted prefix: draft i is the target's own choice iff
+                # it equals greedy[i] (the argmax after consuming the
+                # previous position)
+                a = 0
+                while a < k and drafts[slot, a] == greedy[slot, a]:
+                    a += 1
+                self.spec_accept_counts[a] = (
+                    self.spec_accept_counts.get(a, 0) + 1
+                )
+                # committed token t0 sits at position p0; the round's
+                # emissions extend the written frontier one position each
+                p0 = req.prompt_len + len(req.tokens) - 1
+                emitted = 0
+                for j in range(a + 1):
+                    self._emit_token(slot, req, int(greedy[slot, j]), now)
+                    emitted += 1
+                    if self.slots[slot] is None:
+                        break  # max_new / EOS — inside the draft window
+                # the first min(a, emitted) emissions are draft-origin
+                self.draft_tokens_accepted += min(a, emitted)
+                self._tl(
+                    "serve_spec_round", rid=req.rid,
+                    round=self._spec_rounds, accepted=a, rejected=k - a,
+                    emitted=emitted,
+                )
+                new_lens[slot] = p0 + emitted
+                if self.slots[slot] is not None:
+                    if emitted == k + 1:
+                        # full accept: the drafter never appended its own
+                        # final draft, and the bonus token is new to it
+                        self._pending[slot] = [
+                            int(drafts[slot, k - 1]), int(greedy[slot, k]),
+                        ]
+                    else:
+                        self._pending[slot] = [int(greedy[slot, emitted - 1])]
+            # roll BOTH pools back to the committed frontier: rejected
+            # positions' fresh pages return to the free set (refcount
+            # decrement — the same discipline as release), stale values
+            # inside kept pages are overwritten before they become readable
+            jl = jnp.asarray(new_lens)
+            jm = jnp.asarray(mask)
+            self.pool = _truncate(self.pool, jl, jm)
+            self.draft_pool = _truncate(self.draft_pool, jl, jm)
+            self._track_pages()
         if self._spec_rounds % 8 == 0 or self._spec_rounds <= 2:
             flight.record(
                 kind="serve_spec", step=self._spec_rounds,
-                wall_s=round(wall, 6),
-                active=int(mask.sum()),
+                wall_s=round(wall, 6), active=counts["active"],
                 draft_steps=steps,
                 accepted=self.draft_tokens_accepted,
                 proposed=self.draft_tokens_proposed,
-                queue=len(self.queue),
-                pages_used=self._host_pages_used(),
+                queue=counts["queue"], pages_used=self._host_pages_used(),
             )
 
     def _slot_fresh_pages(self, slot: int, written: int) -> int:
@@ -2079,19 +2147,20 @@ class ServeEngine:
     def _flush_releases(self) -> None:
         if not any(self._release_mask):
             return
-        mask = jnp.asarray(np.asarray(self._release_mask))
-        self.pool = self._release(self.pool, mask)
-        if self.spec_k:
-            # the drafter's mirror slot returns its pages in the same
-            # flush (the jitted wrapper respecializes per pool shapes)
-            self.draft_pool = self._release(self.draft_pool, mask)
-        for slot, flushed in enumerate(self._release_mask):
-            if flushed:  # the slot stops pinning its shared pages
-                self._adopted_pages[slot] = []
-                self._cached_pages[slot] = []
-                self._pending[slot] = []
-        self._release_mask = [False] * self.max_slots
-        self._pending_pages = [0] * self.max_slots
+        with self._span("serve.release", slots=sum(self._release_mask)):
+            mask = jnp.asarray(np.asarray(self._release_mask))
+            self.pool = self._release(self.pool, mask)
+            if self.spec_k:
+                # the drafter's mirror slot returns its pages in the same
+                # flush (the jitted wrapper respecializes per pool shapes)
+                self.draft_pool = self._release(self.draft_pool, mask)
+            for slot, flushed in enumerate(self._release_mask):
+                if flushed:  # the slot stops pinning its shared pages
+                    self._adopted_pages[slot] = []
+                    self._cached_pages[slot] = []
+                    self._pending[slot] = []
+            self._release_mask = [False] * self.max_slots
+            self._pending_pages = [0] * self.max_slots
 
     # ---- elastic handoff (PR 14) ---------------------------------------
 
@@ -2124,29 +2193,31 @@ class ServeEngine:
         """One scheduler iteration: flush releases, admit + prefill,
         then one packed decode tick.  Returns True when any program
         ran (False = fully idle)."""
-        ran = False
-        self._flush_releases()
-        self.queue_depths.append(len(self.queue))
-        batch = self._admittable()
-        if batch:
-            self._run_prefill(batch)
-            ran = True
-        # a request that completed DURING prefill (max_new=1 or an eos
-        # first token) must not ride through the decode tick with its
-        # device slot still active — it would write KV for a dead
-        # sequence and could lazily allocate a page the admission
-        # accounting and the host peak mirror never see
-        self._flush_releases()
-        if any(r is not None for r in self.slots):
-            if self.spec_k:
-                self._run_spec_round()
-            else:
-                self._run_decode_tick()
-            ran = True
-        self.token_log.append((self.now(), self.generated_tokens))
-        self._mem_sample()
-        if self._sanitize:  # graft-race: live S204 mirror assertion
-            _sanitizer.check_serve_mirror(self)
+        with self._span("serve.step"):
+            ran = False
+            self._flush_releases()
+            self.queue_depths.append(len(self.queue))
+            with self._span("serve.admit", queue=len(self.queue)):
+                batch = self._admittable()
+            if batch:
+                self._run_prefill(batch)
+                ran = True
+            # a request that completed DURING prefill (max_new=1 or an
+            # eos first token) must not ride through the decode tick with
+            # its device slot still active — it would write KV for a dead
+            # sequence and could lazily allocate a page the admission
+            # accounting and the host peak mirror never see
+            self._flush_releases()
+            if any(r is not None for r in self.slots):
+                if self.spec_k:
+                    self._run_spec_round()
+                else:
+                    self._run_decode_tick()
+                ran = True
+            self.token_log.append((self.now(), self.generated_tokens))
+            self._mem_sample()
+            if self._sanitize:  # graft-race: live S204 mirror assertion
+                _sanitizer.check_serve_mirror(self)
         return ran
 
     # ---- graft-mem (PR 17) ---------------------------------------------

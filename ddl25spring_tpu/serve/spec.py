@@ -212,12 +212,15 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
             )
             return (x, kp, vp), None
 
-        (x, kp, vp), _ = lax.scan(
-            layer, (x, pool["k"], pool["v"]),
-            (params["blocks"], jnp.arange(cfg.n_layers)),
-        )
-        logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
-        g = logits.argmax(-1).astype(jnp.int32)
+        with jax.named_scope("blocks"):
+            (x, kp, vp), _ = lax.scan(
+                layer, (x, pool["k"], pool["v"]),
+                (params["blocks"], jnp.arange(cfg.n_layers)),
+            )
+        with jax.named_scope("head"):
+            logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
+        with jax.named_scope("sample"):
+            g = logits.argmax(-1).astype(jnp.int32)
         absmax = jnp.max(jnp.where(active, jnp.max(
             jnp.abs(logits), axis=-1), 0.0))
         return {**pool, "k": kp, "v": vp}, g, absmax, ok

@@ -9,7 +9,7 @@ instruments those hooks cannot see:
 - :func:`trace` — a ``jax.profiler`` trace context producing a TensorBoard/
   Perfetto-loadable profile of XLA execution (MXU utilization, HBM traffic,
   collective time — the real versions of the reference's wall-clock guesses);
-- :func:`annotate` — named host-side regions that show up inside the trace;
+  named host-side regions inside it are :func:`ddl25spring_tpu.obs.spans.span`;
 - :class:`StepTimer` — steady-state steps/sec with correct async-dispatch
   handling (blocks on the result, discards warmup/compile).
 """
@@ -39,11 +39,6 @@ def trace(log_dir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region (context manager) visible in profiler traces."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class StepTimer:

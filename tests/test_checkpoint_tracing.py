@@ -11,11 +11,12 @@ import optax
 import pytest
 
 from ddl25spring_tpu.models.mnist_cnn import MnistCnn
+from ddl25spring_tpu.obs.spans import span
 from ddl25spring_tpu.ops.losses import nll_loss
 from ddl25spring_tpu.parallel.dp import make_dp_train_step
 from ddl25spring_tpu.utils.checkpoint import Checkpointer
 from ddl25spring_tpu.utils.mesh import make_mesh, replicated
-from ddl25spring_tpu.utils.tracing import StepTimer, annotate
+from ddl25spring_tpu.utils.tracing import StepTimer
 
 
 @pytest.fixture()
@@ -156,7 +157,7 @@ def test_step_timer_discards_warmup():
     t = StepTimer(warmup=1)
     x = jnp.ones((8, 8))
     for _ in range(4):
-        with annotate("matmul"):
+        with span("matmul"):
             x = x @ x.T
         t.tick(x)
     assert len(t.times) == 2  # 3 intervals, 1 warmup discarded

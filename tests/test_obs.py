@@ -88,6 +88,138 @@ def test_spans_threadsafe_and_disabled_is_noop():
     assert len(obs.get_recorder()) == before
 
 
+# ---------------------------------------------------------------- rings
+
+
+@pytest.fixture
+def ring_cap(monkeypatch):
+    """Set how many samples a ring made from here on keeps."""
+    import importlib
+
+    # the module, not the instance that ``obs`` re-exports under its name
+    mod = importlib.import_module("ddl25spring_tpu.obs.counters")
+    return lambda cap: monkeypatch.setattr(mod, "RING_CAP", cap)
+
+
+def test_ring_keeps_samples_stamped_and_in_order(ring_cap):
+    ring_cap(8)
+    c = obs.CounterSet()
+    for i in range(5):
+        c.sample("serve.active_slots", 10 * i, t=100.0 + i)
+    assert c.window("serve.active_slots", 101.0, 103.0) == [
+        (101.0, 10.0), (102.0, 20.0), (103.0, 30.0)
+    ]
+    assert c.oldest_t("serve.active_slots") == 100.0
+    # a span is written when it CLOSES, under the time it opened: a parent
+    # follows its children in the ring and a window is still cut by stamp
+    c.sample("x", 1.0, t=5.0)
+    c.sample("x", 9.0, t=2.0)
+    assert c.window("x", 0.0, 10.0) == [(5.0, 1.0), (2.0, 9.0)]
+    # no stamp given: now, on perf_counter
+    t0 = time.perf_counter()
+    c.sample("now", 1.0)
+    assert t0 <= c.oldest_t("now") <= time.perf_counter()
+
+
+def test_ring_wraps_to_the_newest_and_says_how_far_back_it_reaches(ring_cap):
+    ring_cap(4)
+    c = obs.CounterSet()
+    for i in range(10):
+        c.sample("tick", float(i), t=float(i))
+    assert c.window("tick", 0.0, 100.0) == [(6.0, 6.0), (7.0, 7.0), (8.0, 8.0), (9.0, 9.0)]
+    # a window that opens before oldest_t has lost samples: the reader's
+    # cue to return nothing rather than a wrong number
+    assert c.wrapped("tick") and c.oldest_t("tick") == 6.0
+    # exactly full is not wrapped: the whole series is there, so a series
+    # that begins inside a window is told from one that lost its head
+    d = obs.CounterSet()
+    for i in range(4):
+        d.sample("tick", float(i), t=float(i))
+    assert not d.wrapped("tick") and not d.wrapped("never")
+    assert d.oldest_t("tick") == 0.0 and len(d.window("tick", 0.0, 9.0)) == 4
+
+
+def test_ring_of_a_name_never_sampled_is_empty_not_an_error():
+    c = obs.CounterSet()
+    assert c.window("nothing", 0.0, 1.0) == [] and c.oldest_t("nothing") is None
+    c.sample("something", 1.0, t=0.5)
+    c.reset()
+    assert c.oldest_t("something") is None
+    assert "something" not in json.dumps(c.snapshot())  # rings are not exported
+
+
+def test_ring_is_threadsafe(ring_cap):
+    ring_cap(1 << 12)
+    c = obs.CounterSet()
+
+    def worker(k):
+        for i in range(500):
+            c.sample("shared", float(k), t=float(i))
+
+    ts = [threading.Thread(target=worker, args=(k,)) for k in range(8)]
+    [t.start() for t in ts]
+    [t.join() for t in ts]
+    got = c.window("shared", 0.0, 1e9)
+    assert len(got) == 4000
+    assert sorted(v for _, v in got) == sorted(float(k) for k in range(8) for _ in range(500))
+
+
+def test_span_samples_its_ring_with_telemetry_off_and_records_nothing():
+    before = len(obs.get_recorder())
+    t0 = time.perf_counter()
+    with obs.span("serve.step", cat="serve", rows=3, rids="1 2 3"):
+        with obs.span("serve.admit"):
+            time.sleep(0.002)
+    t1 = time.perf_counter()
+    assert len(obs.get_recorder()) == before  # Chrome JSON: gated by the flag
+    (ts, dur), = obs.counters.window("serve.step", t0, t1)
+    (tc, dc), = obs.counters.window("serve.admit", t0, t1)
+    assert t0 <= ts <= tc and tc + dc <= ts + dur <= t1 and dc >= 0.002
+    obs.instant("still_gated")
+    assert len(obs.get_recorder()) == before
+
+
+def test_span_records_chrome_json_only_with_telemetry_on():
+    old = obs.set_recorder(obs.SpanRecorder())
+    try:
+        t0 = time.perf_counter()
+        with obs.scoped(True):
+            with obs.span("serve.prefill", cat="serve", rows=2):
+                pass
+        with obs.span("serve.prefill", cat="serve", rows=1):
+            pass
+        events = [e for e in obs.get_recorder().to_chrome_trace()["traceEvents"]
+                  if e["ph"] == "X"]
+    finally:
+        obs.set_recorder(old)
+    assert [(e["name"], e["cat"], e["args"]) for e in events] == [
+        ("serve.prefill", "serve", {"rows": 2})
+    ]
+    # the ring has both, whatever the flag said
+    assert len(obs.counters.window("serve.prefill", t0, time.perf_counter())) == 2
+
+
+def test_span_lands_in_a_profiler_trace_with_its_stats(tmp_path):
+    """No flag, no recorder: any open profiler session sees the span."""
+    import glob
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("serve.decode_tick", cat="serve", active=2, queue=0):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [
+        dict(e.stats)
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name == "serve.decode_tick"
+    ]
+    assert found == [{"active": 2, "queue": 0}]
+
+
 # --------------------------------------------------------------- logger
 
 

@@ -16,6 +16,7 @@ described-device executable cannot be read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,36 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, direction):
     assert "tpu_custom_call" in lowered.as_text()
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("direction,kernels", [
+    ("fwd", ["flash_fwd"]),
+    ("bwd", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+])
+def test_flash_kernels_keep_their_names_for_v5e(one_chip, direction, kernels):
+    """Each ``pallas_call`` is named: the name is the custom call's
+    ``kernel_name`` and a component of its ``op_name``, which is how a
+    trace's events are told apart (``benchmark/tools/trace_scopes.py``);
+    unnamed they all read ``tpu_custom_call``.  Lowered, not compiled."""
+    from ddl25spring_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((2, 2048, 8, 128), jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    text = jax.jit(fwd if direction == "fwd" else bwd).lower(x, x, x).as_text(
+        debug_info=True
+    )
+    assert text.count("tpu_custom_call") >= len(kernels)
+    for name in kernels:
+        assert f'kernel_name = "{name}"' in text
+        assert re.search(rf"[(/]{name}\)*/pallas_call", text), name
 
 
 def test_flash_attention_with_lse_compiles_for_v5e(one_chip):
@@ -196,7 +227,10 @@ def test_llama_ref_train_step_compiles_with_the_kernel(topo, as_on_tpu):
     tokens = jax.ShapeDtypeStruct((24, cfg.ctx_size), jnp.int32, sharding=rep)
     step = make_pipeline_train_step(cfg, tx, mesh, 3)
     lowered = step.lower(staged, opt_state, tokens)
-    assert "tpu_custom_call" in lowered.as_text()
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f'kernel_name = "{name}"' in text
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < V5E_HBM_BYTES
 
