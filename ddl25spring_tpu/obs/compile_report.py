@@ -41,24 +41,23 @@ COMPILE_REPORT_BASENAME = "compile_report.json"
 # zero1/zero2's overlap twins therefore graduated from on-demand to
 # default.  PR 10 adds the two serving programs (serve-decode /
 # serve-prefill: the paged-KV TP inference steps, pinned all-reduce-only
-# like tp but forward-only); PR 11 adds the prefix cache's start-offset
-# prefill variant (serve-prefill-cached), whose SHORTER scan — fewer
-# all-reduces than serve-prefill's — is the compile-time proof of the
-# prefill FLOPs a radix hit skips.  PR 12 adds the two partition-rule-
-# table strategies (dp-rules / zero3-rules: the strategy is a mesh +
+# like tp but forward-only; PR 11's start-offset variant
+# serve-prefill-cached left with PR 27, whose one-pass prefill is the
+# same program whatever a radix hit skips).  PR 12 adds the two
+# partition-rule-table strategies (dp-rules / zero3-rules: the strategy is a mesh +
 # regex rule table + issue discipline, parallel/rules.py), pinned
 # bitwise-identical to their bespoke twins and coverage-proven by the
 # sharding-flow verifier (analysis/shard_flow.py, H011-H013).  PR 13
 # adds the speculative-decoding pair (serve-draft / serve-verify: the
 # tiny-LLaMA drafter's k-token scan over its own paged pool and the
-# target's width-(k+1) verify pass, serve/spec.py).  All twenty-one
+# target's width-(k+1) verify pass, serve/spec.py).  All twenty-three
 # share the tests' lower-once compile cache, so tier-1 pays each
 # compile exactly once.
 DEFAULT_STRATEGIES = (
     "dp", "dp-overlap", "dp-rules", "zero1", "zero1-overlap", "zero2",
     "zero2-overlap", "zero3", "zero3-prefetch", "zero3-overlap",
     "zero3-rules", "pipeline", "het_pipeline", "tp", "sp", "ep",
-    "serve-decode", "serve-prefill", "serve-prefill-cached",
+    "serve-decode", "serve-prefill",
     "serve-draft", "serve-verify",
     "serve-decode-tp", "serve-prefill-tp", "serve-decode-zero3stream",
 )

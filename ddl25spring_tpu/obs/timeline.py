@@ -75,7 +75,7 @@ EVENT_KINDS: dict[str, tuple[str, ...]] = {
     "serve_submit": ("rid", "prompt_len", "max_new"),
     "serve_reject": ("rid", "reason"),
     "serve_admit": ("rid", "slot"),
-    "serve_prefill": ("rid", "slot", "start", "prefix_hit_tokens"),
+    "serve_prefill": ("rid", "slot", "width", "prefix_hit_tokens"),
     "serve_first_token": ("rid", "ttft_s"),
     "serve_spec_round": ("rid", "round", "accepted", "rejected"),
     "serve_done": ("rid", "tokens"),

@@ -892,15 +892,6 @@ STRATEGIES: dict[str, dict[str, Any]] = {
         "axes": ("model",), "default_mesh": (2,),
         "kwargs": {"program": "prefill"},
     },
-    # the radix prefix cache's start-offset prefill variant (PR 11):
-    # the scan shortens to max_prompt_len - start positions, so the
-    # all-reduce count — and with it the prefill FLOPs a cached prefix
-    # skips — is a compile-time fact this signature pin holds
-    "serve-prefill-cached": {
-        "module": "ddl25spring_tpu.serve.engine",
-        "axes": ("model",), "default_mesh": (2,),
-        "kwargs": {"program": "prefill", "start": 4},
-    },
     # the TP-sharded serving trio (PR 18): the same decode/prefill
     # programs under the tightened per-chip claim — 64 KiB peak-HBM
     # budgets that only hold because the pool's head dim and the

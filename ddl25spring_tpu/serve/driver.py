@@ -841,10 +841,9 @@ def run_serve_bench(
         params, cfg, knobs, clock="wall", temperature=temperature,
         sentinel=sentinel, trace_label="ramp",
     )
-    # compile OFF the clock: TTFT measures serving, not XLA.  With the
-    # prefix cache on this includes the sharing ops and EVERY
-    # start-offset prefill variant (scan starts are page-quantized, so
-    # the universe is bounded and warmup covers it all)
+    # compile OFF the clock: TTFT measures serving, not XLA.  This
+    # covers every prefill width and, with the prefix cache on, the
+    # sharing ops
     with spans.span("serve.warmup", cat="serve"):
         eng.warmup()
     with spans.span("serve.ramp", cat="serve", requests=len(trace)):

@@ -4,9 +4,10 @@ ROADMAP item 3's serving path: ``models/decode.py`` gives the framework
 a *correct* cached decode loop, this module makes it *serve* —
 
 - **prefill/decode disaggregation**: two separately compiled
-  static-shape programs.  ``prefill`` scans a padded prompt batch
-  through the cached step, writing KV pages and emitting each request's
-  first sampled token; ``decode`` packs every active slot into ONE
+  static-shape programs.  ``prefill`` runs every position of a padded
+  prompt batch in one pass (as wide as the batch's longest prompt
+  needs), writing KV pages and emitting each request's first sampled
+  token; ``decode`` packs every active slot into ONE
   ``[max_slots]`` tick, each tick appending one token per live sequence
   (inactive slots ride along masked — the static-shape tax).
 - **continuous batching**: a sequence that hits EOS / its length stop
@@ -75,46 +76,58 @@ REJECT_DRAINING = "draining"  # elastic scale-down: replica admits nothing
 
 
 def _rope_rows(x, cos, sin):
-    """RoPE for a single-token batch whose POSITION varies per row:
-    ``x [B, 1, H, hd]``, ``cos/sin [B, hd/2]``.  Same arithmetic as
-    :func:`~ddl25spring_tpu.models.llama.apply_rope` (which aligns cos
-    with the sequence axis — here the position lives on the batch axis
-    instead), so fp32 values match the dense decode bitwise."""
+    """RoPE where every row has its OWN positions: ``x [B, T, H, hd]``,
+    ``cos/sin [B, T, hd/2]``.  Same arithmetic as
+    :func:`~ddl25spring_tpu.models.llama.apply_rope` (which shares one
+    position vector over the batch), so fp32 values match the dense
+    decode bitwise."""
     x1, x2 = x[..., 0::2], x[..., 1::2]
-    c = cos[:, None, None, :]
-    s = sin[:, None, None, :]
+    c = cos[:, :, None, :]
+    s = sin[:, :, None, :]
     out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _rope_at(pos, head_dim: int):
+    """``(cos, sin)``, each ``[B, T, hd/2]``, of absolute positions
+    ``pos [B, T]``."""
+    cos, sin = llama.rope_angles(
+        1, head_dim, pos=pos.reshape(-1).astype(jnp.float32)
+    )
+    return cos.reshape(*pos.shape, -1), sin.reshape(*pos.shape, -1)
+
+
 def _paged_block(p, x, kp, vp, layer, rows, pages, offs, pos, cos, sin,
                  cfg: LlamaConfig, tp_axis: str | None):
-    """One transformer block on a single-token slice ``x [B, 1, D]``
-    against the PAGE POOL — the paged twin of
-    :func:`ddl25spring_tpu.models.decode._block_decode`, op for op
-    (same einsums, same fp32 softmax, same ``-1e30`` mask fill), so the
-    fp32 equivalence pin holds bitwise.  ``rows`` is the clamped page
-    table ``[B, P]`` of the sequences in this batch; ``pages``/``offs``
-    the write coordinates of position ``pos`` (trash-routed where
-    masked).  Its parts are scoped ``attn`` / ``page_write`` /
+    """One transformer block on ``T`` positions a row, ``x [B, T, D]`` at
+    absolute positions ``pos [B, T]``, against the PAGE POOL — the paged
+    twin of :func:`ddl25spring_tpu.models.decode._block_decode`, op for
+    op (same einsums, same fp32 softmax, same ``-1e30`` mask fill).
+    ``rows [B, P]`` is the clamped page table of the batch's sequences
+    (any leading run of entries that covers every live position);
+    ``pages``/``offs [B, T]`` are the write coordinates of each position
+    (trash-routed where masked).  All ``T`` keys and values are written
+    first, then the row's page view is gathered, so a query at ``pos``
+    sees what earlier passes left in the pages, this pass's positions up
+    to its own, and nothing later.  The decode tick, the drafter and the
+    verify pass are the ``T = 1`` case; prefill runs a whole prompt
+    batch.  Its parts are scoped ``attn`` / ``page_write`` /
     ``page_gather`` / ``mlp`` (``jax.named_scope``: names in the
     operations' metadata, no operation changes)."""
     dtype = jnp.dtype(cfg.dtype)
-    B = x.shape[0]
+    B, T = x.shape[:2]
     hd = cfg.head_dim
 
     with jax.named_scope("attn"):
         h = llama.rms_norm(x, p["ln1"])
-        q = (h @ p["wq"].astype(dtype)).reshape(B, 1, -1, hd)
-        k = (h @ p["wk"].astype(dtype)).reshape(B, 1, -1, hd)
-        v = (h @ p["wv"].astype(dtype)).reshape(B, 1, -1, hd)
+        q = (h @ p["wq"].astype(dtype)).reshape(B, T, -1, hd)
+        k = (h @ p["wk"].astype(dtype)).reshape(B, T, -1, hd)
+        v = (h @ p["wv"].astype(dtype)).reshape(B, T, -1, hd)
         q = _rope_rows(q, cos, sin)
         k = _rope_rows(k, cos, sin)
 
     with jax.named_scope("page_write"):
-        kp, vp = kv_pages.append_layer_kv(
-            kp, vp, layer, pages, offs, k[:, 0], v[:, 0]
-        )
+        kp, vp = kv_pages.append_layer_kv(kp, vp, layer, pages, offs, k, v)
     with jax.named_scope("page_gather"):
         ks = kp[rows, layer]  # [B, P, page_len, H, hd]
         vs = vp[rows, layer]
@@ -125,11 +138,11 @@ def _paged_block(p, x, kp, vp, layer, rows, pages, offs, pos, cos, sin,
     with jax.named_scope("attn"):
         s = jnp.einsum("bqhd,bmhd->bhqm", q, ks).astype(jnp.float32)
         s = s / jnp.sqrt(jnp.float32(hd))
-        live = jnp.arange(P * page_len)[None, :] <= pos[:, None]
-        s = jnp.where(live[:, None, None, :], s, -1e30)
+        live = jnp.arange(P * page_len)[None, None, :] <= pos[:, :, None]
+        s = jnp.where(live[:, None, :, :], s, -1e30)
         probs = jax.nn.softmax(s, axis=-1).astype(dtype)
         attn = jnp.einsum("bhqm,bmhd->bqhd", probs, vs)
-        attn_out = attn.reshape(B, 1, -1) @ p["wo"].astype(dtype)
+        attn_out = attn.reshape(B, T, -1) @ p["wo"].astype(dtype)
         if tp_axis is not None:
             attn_out = lax.psum(attn_out, tp_axis)
         x = x + attn_out
@@ -142,6 +155,33 @@ def _paged_block(p, x, kp, vp, layer, rows, pages, offs, pos, cos, sin,
         if tp_axis is not None:
             ffn_out = lax.psum(ffn_out, tp_axis)
         return x + ffn_out, kp, vp
+
+
+def _block_stack(params, x, kp, vp, rows, pages, offs, pos,
+                 cfg: LlamaConfig, tp_axis: str | None, layer_stack=None):
+    """Every block of the model over ``x [B, T, D]`` at positions
+    ``pos [B, T]`` (see :func:`_paged_block`): the resident-weight layer
+    scan, or ``layer_stack``'s own walk (:func:`make_decode_tick`)."""
+    cos, sin = _rope_at(pos, cfg.head_dim)
+
+    def run_layer(bp, li, x, kp, vp):
+        return _paged_block(
+            bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin, cfg,
+            tp_axis,
+        )
+
+    if layer_stack is not None:
+        return layer_stack(params, run_layer, x, kp, vp)
+
+    def layer(carry, inp):
+        return run_layer(*inp, *carry), None
+
+    with jax.named_scope("blocks"):
+        carry, _ = lax.scan(
+            layer, (x, kp, vp),
+            (params["blocks"], jnp.arange(cfg.n_layers)),
+        )
+    return carry
 
 
 def make_decode_tick(
@@ -194,35 +234,10 @@ def make_decode_tick(
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)  # [S, P]
 
         x = llama.embed(params, tokens[:, None], cfg)
-        cos, sin = llama.rope_angles(
-            1, cfg.head_dim, pos=pos.astype(jnp.float32)
+        x, kp, vp = _block_stack(
+            params, x, pool["k"], pool["v"], rows, pages[:, None],
+            offs[:, None], pos[:, None], cfg, tp_axis, layer_stack,
         )
-
-        if layer_stack is None:
-            def layer(carry, inp):
-                x, kp, vp = carry
-                bp, li = inp
-                x, kp, vp = _paged_block(
-                    bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin,
-                    cfg, tp_axis,
-                )
-                return (x, kp, vp), None
-
-            with jax.named_scope("blocks"):
-                (x, kp, vp), _ = lax.scan(
-                    layer, (x, pool["k"], pool["v"]),
-                    (params["blocks"], jnp.arange(cfg.n_layers)),
-                )
-        else:
-            def run_layer(bp, li, x, kp, vp):
-                return _paged_block(
-                    bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin,
-                    cfg, tp_axis,
-                )
-
-            x, kp, vp = layer_stack(
-                params, run_layer, x, pool["k"], pool["v"]
-            )
         with jax.named_scope("head"):
             logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
         with jax.named_scope("sample"):
@@ -252,6 +267,19 @@ def make_decode_tick(
     return tick
 
 
+def prefill_widths(max_prompt_len: int) -> tuple[int, ...]:
+    """The widths a prefill pass is padded to: quarters of
+    ``max_prompt_len`` (64, 128, 192, 256 at 256).  A pass rides the
+    smallest that holds its batch's longest unmatched suffix, so the one
+    jitted prefill specialises into at most four programs, all of them
+    run once by :meth:`ServeEngine.warmup`."""
+    step = -(-max_prompt_len // 4)
+    return tuple(
+        min(w, max_prompt_len)
+        for w in range(step, max_prompt_len + step, step)
+    )
+
+
 def make_prefill(
     cfg: LlamaConfig,
     *,
@@ -265,102 +293,76 @@ def make_prefill(
     strategy: str = "serve-prefill",
 ):
     """Build the prefill program body: write a padded prompt batch into
-    the pool and sample each request's FIRST generated token.
+    the pool in ONE pass over all its positions and sample each
+    request's FIRST generated token.
 
     ``prefill(params, pool, prompts, lens, starts, slot_ids, key) ->
-    (pool, first_tokens, ok)`` — ``prompts [B, max_prompt_len]`` int32
-    (pad beyond ``lens``), ``slot_ids [B]`` the target slots (``-1`` =
-    padding row, which writes only to the trash page).  The prompt
-    positions run through the SAME cached single-token step as decode,
-    scanned over ``max_prompt_len`` (weights are the bandwidth bound at
-    these shapes; a fused wide-prompt pass is a future optimization the
-    compile-signature pin would catch drifting).  On exit the target
-    slots are active with ``seq_len = lens`` — exactly the state the
-    next decode tick expects.
+    (pool, first_tokens, ok)`` — ``slot_ids [B]`` are the target slots
+    (``-1`` = padding row, which writes only to the trash page), ``lens
+    [B]`` the prompts' lengths (at most ``max_prompt_len``, the capacity
+    pages are reserved and gathered for), ``starts [B]`` how many
+    leading positions of each row already sit in pages
+    ``kv_pages.adopt_prefix`` seated in its table (a radix hit; 0 cold).
+    ``prompts [B, W]`` int32 holds each row's UNMATCHED suffix:
+    ``prompts[b, j]`` is the token at position ``starts[b] + j``, and
+    the row writes while that position is below ``lens[b]``.  The width
+    ``W`` is the shape's (one compiled program a width: the engine pads
+    to :func:`prefill_widths`), so a hit saves work by riding a narrower
+    pass.  All ``B x W`` positions run through :func:`_paged_block` at
+    once — the block the decode tick runs with one position a row —
+    after ONE all-or-nothing page reservation; the logits are taken at
+    ``lens - 1`` only.  On exit the target slots are active with
+    ``seq_len = lens`` — exactly the state the next decode tick expects.
 
-    ``start`` is the prefix cache's STATIC start offset: the scan runs
-    positions ``[start, max_prompt_len)`` only — the skipped iterations
-    are the prefill FLOPs a radix hit saves, and the offset being a
-    compile-time constant keeps every position/RoPE angle absolute and
-    therefore bitwise-identical to the cold program's (one compiled
-    variant per distinct offset, cached; the engine quantizes offsets
-    to PAGE multiples — ``ServeEngine._scan_start`` — so the variant
-    universe is bounded and warmup covers it all).  ``starts [B]``
-    carries each row's own matched length (``>= start``): rows never
-    write positions below their own ``starts`` — those positions'
-    KV already sit in the pages ``kv_pages.adopt_prefix`` seated in the
-    row's table, and the attention gather reads them like any other
-    page.  A row whose ``starts`` exceeds ``start`` replays the gap's
-    compute bit-exactly (same tokens, same positions) with its writes
-    trash-routed, so correctness never depends on the grouping — it is
-    how a partial-page match rides a page-aligned variant."""
+    ``start`` is accepted as 0 only (the compile rehearsal of the
+    benchmark still passes it)."""
     if cfg.n_experts > 0:
         raise NotImplementedError("serve/ decodes dense-FFN configs only")
-    if not 0 <= start < max_prompt_len:
+    if start != 0:
         raise ValueError(
-            f"start={start} must sit in [0, max_prompt_len="
-            f"{max_prompt_len})"
+            f"start={start}: start offsets are the rows' `starts` now"
         )
     s_on, s_policy = sentinels.resolve(sentinel)
 
     def prefill(params, pool, prompts, lens, starts, slot_ids, key):
-        B = prompts.shape[0]
+        B, W = prompts.shape
         n_pages = pool["free"].shape[0]
+        n_slots, P = pool["page_table"].shape
         page_len = pool["k"].shape[2]
+        # table entries a prompt can reach: what is reserved and gathered
+        E = min(P, -(-max_prompt_len // page_len))
         valid_row = slot_ids >= 0
         pool = kv_pages.activate_slots(pool, slot_ids, valid_row)
 
-        def body(carry, i):
-            pool, last_logits, ok_all = carry
-            tok = prompts[:, i]
-            pos = jnp.full((B,), i, jnp.int32)
-            writing = valid_row & (i >= starts) & (i < lens)
-            need = writing & (i % page_len == 0)
-            with jax.named_scope("page_write"):
-                pool, ok = kv_pages.reserve_pages(pool, slot_ids, pos, need)
-                pages, offs = kv_pages.write_page_ids(
-                    pool, slot_ids, pos, writing
-                )
-            rows = jnp.clip(
-                pool["page_table"][
-                    jnp.clip(slot_ids, 0, pool["page_table"].shape[0] - 1)
-                ],
-                0, n_pages - 1,
-            )  # [B, P]
-
-            x = llama.embed(params, tok[:, None], cfg)
-            cos, sin = llama.rope_angles(
-                1, cfg.head_dim, pos=pos.astype(jnp.float32)
+        pos = starts[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
+        writing = valid_row[:, None] & (pos < lens[:, None])
+        slots = jnp.broadcast_to(slot_ids[:, None], (B, W))
+        with jax.named_scope("page_write"):
+            # every table entry whose FIRST position this pass writes
+            # opens a page; flattened entry-major, which is the order a
+            # position-by-position walk would allocate in
+            opens = jnp.arange(E, dtype=jnp.int32)[:, None] * page_len
+            need = (valid_row[None, :] & (opens >= starts[None, :])
+                    & (opens < lens[None, :]))  # [E, B]
+            pool, ok = kv_pages.reserve_pages(
+                pool, jnp.broadcast_to(slot_ids[None, :], (E, B)).reshape(-1),
+                jnp.broadcast_to(opens, (E, B)).reshape(-1), need.reshape(-1),
             )
+            pages, offs = kv_pages.write_page_ids(pool, slots, pos, writing)
+        rows = jnp.clip(
+            pool["page_table"][jnp.clip(slot_ids, 0, n_slots - 1), :E],
+            0, n_pages - 1,
+        )  # [B, E]
 
-            def layer(carry, inp):
-                x, kp, vp = carry
-                bp, li = inp
-                x, kp, vp = _paged_block(
-                    bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin,
-                    cfg, tp_axis,
-                )
-                return (x, kp, vp), None
-
-            with jax.named_scope("blocks"):
-                (x, kp, vp), _ = lax.scan(
-                    layer, (x, pool["k"], pool["v"]),
-                    (params["blocks"], jnp.arange(cfg.n_layers)),
-                )
-            with jax.named_scope("head"):
-                logits = llama.unembed(params, x, cfg)[:, 0]
-                last_logits = jnp.where(
-                    (i == lens - 1)[:, None], logits, last_logits
-                )
-            pool = {**pool, "k": kp, "v": vp}
-            return (pool, last_logits, ok_all & ok), None
-
-        (pool, last_logits, ok), _ = lax.scan(
-            body,
-            (pool, jnp.zeros((B, cfg.vocab_size), jnp.float32),
-             jnp.bool_(True)),
-            jnp.arange(start, max_prompt_len),
+        x = llama.embed(params, prompts, cfg)
+        x, kp, vp = _block_stack(
+            params, x, pool["k"], pool["v"], rows, pages, offs, pos, cfg,
+            tp_axis,
         )
+        with jax.named_scope("head"):
+            last = jnp.clip(lens - 1 - starts, 0, W - 1)
+            x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
+            last_logits = llama.unembed(params, x_last, cfg)[:, 0]
         with jax.named_scope("sample"):
             if temperature == 0.0:
                 first = last_logits.argmax(-1).astype(jnp.int32)
@@ -368,11 +370,9 @@ def make_prefill(
                 first = decode_mod.sample_logits(
                     last_logits, key, temperature, top_k, top_p
                 )
-        sent = jnp.where(
-            valid_row, slot_ids, pool["seq_len"].shape[0]
-        )
+        sent = jnp.where(valid_row, slot_ids, n_slots)
         pool = {
-            **pool,
+            **pool, "k": kp, "v": vp,
             "seq_len": pool["seq_len"].at[sent].set(lens, mode="drop"),
         }
         first, pool = sentinels.guard(
@@ -411,9 +411,10 @@ _truncate = jax.jit(kv_pages.truncate_to)
 
 # One compiled (tick, prefill, release) triple per build key: the ramp
 # engine and both A/B engines of a `bench.py --serve` run (and every
-# same-config test engine) reuse XLA programs instead of paying the
-# compile bill per ServeEngine.  Keyed on everything that shapes the
-# BUILT program — cfg (frozen dataclass), prompt width, sampling, the
+# same-config test engine, and a drafter of that config for its
+# prefill) reuse XLA programs instead of paying the compile bill per
+# ServeEngine.  Keyed on everything that shapes the BUILT program — cfg
+# (frozen dataclass), prompt capacity, sampling, the
 # RESOLVED sentinel gate+policy (env is read at build time, so an env
 # flip lands in the key), and donation.
 _PROGRAM_CACHE: dict[tuple, tuple] = {}
@@ -442,47 +443,22 @@ def _compiled_programs(
         pool_kw = {"donate_argnums": (1,)} if donate else {}
         _PROGRAM_CACHE[key] = (
             jax.jit(tick, **pool_kw),
-            _prefill_variant(
-                cfg, max_prompt_len=max_prompt_len, start=0,
-                temperature=temperature, sentinel=sentinel, donate=donate,
-            ),
+            # one jitted function: it specialises by the prompts' width
+            jax.jit(make_prefill(
+                cfg, max_prompt_len=max_prompt_len,
+                temperature=temperature, sentinel=sentinel,
+            ), **pool_kw),
             jax.jit(_release),
         )
     return _PROGRAM_CACHE[key]
 
 
-# prefix-cached prefill variants: one compiled program per STATIC start
-# offset (the skipped scan iterations are the saved FLOPs; a dynamic
-# offset would leave the scan length — and the bill — unchanged).
-# Cached separately from the tick/release pair so a new offset never
-# recompiles those.
-_PREFILL_CACHE: dict[tuple, Any] = {}
-
-
-def _prefill_variant(
-    cfg: LlamaConfig, *, max_prompt_len: int, start: int,
-    temperature: float, sentinel: bool | None, donate: bool,
-):
-    key = (
-        cfg, max_prompt_len, start, temperature,
-        sentinels.resolve(sentinel), donate,
-    )
-    if key not in _PREFILL_CACHE:
-        pre = make_prefill(
-            cfg, max_prompt_len=max_prompt_len, start=start,
-            temperature=temperature, sentinel=sentinel,
-        )
-        pool_kw = {"donate_argnums": (1,)} if donate else {}
-        _PREFILL_CACHE[key] = jax.jit(pre, **pool_kw)
-    return _PREFILL_CACHE[key]
-
-
 # speculative-decoding programs (PR 13): one compiled (draft-k,
 # draft-k+1, verify) triple per (target cfg, draft cfg, k, sentinel,
 # donate) — every same-config engine (the spec A/B's two arms, the
-# test engines) shares the XLA programs.  The drafter's prefill rides
-# _PREFILL_CACHE (keyed by the DRAFT cfg, start 0), and rollback rides
-# the module-level _truncate wrapper.
+# test engines) shares the XLA programs.  The drafter's prefill is
+# _compiled_programs' at the DRAFT cfg, and rollback rides the
+# module-level _truncate wrapper.
 _SPEC_CACHE: dict[tuple, dict] = {}
 
 
@@ -662,58 +638,41 @@ def _tp_jit(body, mesh, *, model_axis: str, n_extra: int, p_specs,
 # discipline as _PROGRAM_CACHE; the mesh object participates so two
 # engines on different device subsets never share an executable
 _TP_PROGRAM_CACHE: dict[tuple, tuple] = {}
-_TP_PREFILL_CACHE: dict[tuple, Any] = {}
 _TP_SPEC_CACHE: dict[tuple, dict] = {}
 
 
-def _tp_prefill_variant(
-    cfg: LlamaConfig, mesh, *, max_prompt_len: int, start: int,
-    temperature: float, sentinel: bool | None, donate: bool,
-    weight_stream: bool = False, model_axis: str = "model",
-):
-    key = (
-        cfg, mesh, max_prompt_len, start, temperature,
-        sentinels.resolve(sentinel), donate, weight_stream, model_axis,
+def _tp_prefill_body(cfg: LlamaConfig, model_axis: str, t: int, *,
+                     max_prompt_len: int, temperature: float,
+                     sentinel: bool | None, weight_stream: bool):
+    """The prefill body of a ``t``-way TP build; under weight streaming,
+    inside a shell that gathers the block stack first."""
+    body = make_prefill(
+        cfg, max_prompt_len=max_prompt_len, temperature=temperature,
+        tp_axis=model_axis if t > 1 else None, sentinel=sentinel,
     )
-    if key not in _TP_PREFILL_CACHE:
-        t = int(mesh.shape[model_axis])
-        tp_axis = model_axis if t > 1 else None
-        body = make_prefill(
-            cfg, max_prompt_len=max_prompt_len, start=start,
-            temperature=temperature, tp_axis=tp_axis, sentinel=sentinel,
+    if not weight_stream:
+        return body
+    # one pass reads every layer once, but the layer scan wants the
+    # stack whole: streamed prefill gathers ALL blocks up front
+    # (transient — dropped at program exit)
+    from ddl25spring_tpu.parallel import zero
+
+    template = jax.eval_shape(
+        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    )
+    plan = zero.stream_block_plan(template["blocks"], t)
+
+    def streamed(params, pool, *rest):
+        blocks = zero.stream_gather_blocks(
+            plan, params["blocks"], model_axis, t
         )
-        if weight_stream:
-            # the prompt scan re-reads every layer once per position:
-            # streamed prefill gathers the WHOLE block stack up front
-            # (transient — dropped at program exit) instead of paying
-            # n_layers x positions per-layer gather rounds
-            from ddl25spring_tpu.parallel import zero
+        full = {
+            **{k: v for k, v in params.items() if k != "blocks"},
+            "blocks": _tp_slice_block(blocks, model_axis, t, stacked=True),
+        }
+        return body(full, pool, *rest)
 
-            template = jax.eval_shape(
-                lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-            )
-            plan = zero.stream_block_plan(template["blocks"], t)
-            inner = body
-
-            def body(params, pool, *rest):  # noqa: F811 — streamed shell
-                blocks = zero.stream_gather_blocks(
-                    plan, params["blocks"], model_axis, t
-                )
-                full = {
-                    **{k: v for k, v in params.items() if k != "blocks"},
-                    "blocks": _tp_slice_block(
-                        blocks, model_axis, t, stacked=True
-                    ),
-                }
-                return inner(full, pool, *rest)
-
-        _TP_PREFILL_CACHE[key] = _tp_jit(
-            body, mesh, model_axis=model_axis,
-            n_extra=5,
-            p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
-            donate=donate,
-        )
-    return _TP_PREFILL_CACHE[key]
+    return streamed
 
 
 def _tp_compiled_programs(
@@ -742,11 +701,15 @@ def _tp_compiled_programs(
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
             ),
-            _tp_prefill_variant(
-                cfg, mesh, max_prompt_len=max_prompt_len, start=0,
-                temperature=temperature, sentinel=sentinel,
-                donate=donate, weight_stream=weight_stream,
-                model_axis=model_axis,
+            _tp_jit(
+                _tp_prefill_body(
+                    cfg, model_axis, t, max_prompt_len=max_prompt_len,
+                    temperature=temperature, sentinel=sentinel,
+                    weight_stream=weight_stream,
+                ),
+                mesh, model_axis=model_axis, n_extra=5,
+                p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
+                donate=donate,
             ),
             # release touches only replicated accounting state; plain
             # jit respects the committed input shardings (the k/v head
@@ -1023,10 +986,6 @@ class ServeEngine:
             "" if trace_label in (None, "serve") else f"@{trace_label}"
         )
         self._key = jax.random.PRNGKey(seed)
-        # kept for the lazily-compiled start-offset prefill variants
-        self._temperature = temperature
-        self._sentinel = sentinel
-        self._donate = donate
 
         # TP-sharded serving (PR 18): tp > 1 runs every compiled
         # program under a 1-D ``model`` mesh — params row-parallel, the
@@ -1084,23 +1043,28 @@ class ServeEngine:
             cfg, n_pages=n_pages, page_len=page_len, max_slots=max_slots,
             pages_per_seq=self.pages_per_seq,
         ))
-        if self.tp > 1:
-            self._tick, self._prefill, self._release = (
-                _tp_compiled_programs(
+
+        def programs(cfg, temperature):
+            """(tick, prefill, release) of ``cfg`` under this engine's
+            placement — the drafter asks again for its own prefill."""
+            if self.tp > 1:
+                return _tp_compiled_programs(
                     cfg, self.mesh, max_prompt_len=max_prompt_len,
                     temperature=temperature, sentinel=sentinel,
                     donate=donate, weight_stream=self.weight_stream,
                     model_axis=self._model_axis,
                 )
-            )
-        else:
-            self._tick, self._prefill, self._release = _compiled_programs(
+            return _compiled_programs(
                 cfg, max_prompt_len=max_prompt_len,
                 temperature=temperature, sentinel=sentinel, donate=donate,
             )
+
+        self._tick, self._prefill, self._release = programs(
+            cfg, temperature
+        )
         # radix prefix cache (opt-in): host index over cached prompt
         # pages; device sharing runs through kv_pages.adopt_prefix /
-        # ref_pages / unref_pages and the per-offset prefill variants
+        # ref_pages / unref_pages, and prefill takes each row's start
         self.prefix: PrefixCache | None = (
             PrefixCache(page_len) if prefix_cache else None
         )
@@ -1169,17 +1133,7 @@ class ServeEngine:
             self._draft_k = progs["draft_k"]
             self._draft_k1 = progs["draft_k1"]
             self._verify = progs["verify"]
-            if self.tp > 1:
-                self._draft_prefill = _tp_prefill_variant(
-                    draft_cfg, self.mesh, max_prompt_len=max_prompt_len,
-                    start=0, temperature=0.0, sentinel=sentinel,
-                    donate=donate, model_axis=self._model_axis,
-                )
-            else:
-                self._draft_prefill = _prefill_variant(
-                    draft_cfg, max_prompt_len=max_prompt_len, start=0,
-                    temperature=0.0, sentinel=sentinel, donate=donate,
-                )
+            self._draft_prefill = programs(draft_cfg, 0.0)[1]
             # greedy programs never consume randomness; the drafter
             # prefill still takes a key positionally
             self._zero_key = jax.random.PRNGKey(0)
@@ -1290,26 +1244,6 @@ class ServeEngine:
             for k, v in pool.items()
         }
 
-    def _prefill_at(self, start: int):
-        """The compiled prefill program for a STATIC start offset,
-        routed to the TP build under tp > 1 (same variant-cache
-        discipline either way)."""
-        if start == 0:
-            return self._prefill
-        if self.tp > 1:
-            return _tp_prefill_variant(
-                self.cfg, self.mesh, max_prompt_len=self.max_prompt_len,
-                start=start, temperature=self._temperature,
-                sentinel=self._sentinel, donate=self._donate,
-                weight_stream=self.weight_stream,
-                model_axis=self._model_axis,
-            )
-        return _prefill_variant(
-            self.cfg, max_prompt_len=self.max_prompt_len, start=start,
-            temperature=self._temperature, sentinel=self._sentinel,
-            donate=self._donate,
-        )
-
     # ---- time ----------------------------------------------------------
 
     def now(self) -> float:
@@ -1365,8 +1299,9 @@ class ServeEngine:
         return counts
 
     def warmup(self) -> None:
-        """Compile all three programs (prefill, decode tick, release)
-        before the clock starts, then reset every piece of host state
+        """Compile all three programs (prefill at every width of
+        :func:`prefill_widths`, decode tick, release) before the clock
+        starts, then reset every piece of host state
         and telemetry: a serving bench must not bill XLA compile time
         as the first requests' TTFT.  The jitted wrappers persist, so
         the warmed compiles are reused; the pool is rebuilt fresh.
@@ -1454,12 +1389,23 @@ class ServeEngine:
                 ),
                 jnp.full((self.prefill_batch,), -1, jnp.int32),
             )
-            # every start-offset variant a radix hit can ride: scan
-            # starts are quantized to page multiples (_scan_start), so
-            # this is the WHOLE universe — nothing compiles mid-run
-            self.warm_prefill_starts(
-                range(self.page_len, self.max_prompt_len, self.page_len)
+        # run every prefill width once (all-padding batch: each write
+        # trash-routes, the pool keeps its state), so that no pass
+        # compiles on the clock
+        B = self.prefill_batch
+        zeros = jnp.zeros((B,), jnp.int32)
+        pad = (zeros, zeros, jnp.full((B,), -1, jnp.int32),
+               jax.random.PRNGKey(0))
+        for width in prefill_widths(self.max_prompt_len):
+            prompts = jnp.zeros((B, width), jnp.int32)
+            self.pool, _first, _ok = self._prefill(
+                self.params, self.pool, prompts, *pad
             )
+            if self.spec_k:
+                self.draft_pool, _first, _ok = self._draft_prefill(
+                    self.draft_params, self.draft_pool, prompts, *pad
+                )
+        jax.block_until_ready(self.pool["seq_len"])
         self._vtime = 0.0
         self._ticks = self._prefills = 0
         self._spec_rounds = self._draft_steps = 0
@@ -1478,41 +1424,6 @@ class ServeEngine:
         self.memscope.reset()
         self._slot_last_rid = [None] * self.max_slots
         self.mem_leak = None
-        self._t0 = time.perf_counter()
-
-    def warm_prefill_starts(self, starts) -> None:
-        """Compile start-offset prefill variants OFF the clock — the
-        same contract as :meth:`warmup`, for the programs a radix hit
-        will reach for.  Without this the FIRST cache hit at each new
-        offset pays XLA compile on the wall clock (observed: ramp TTFT
-        p95 3.9 ms -> 1.2 s on the smoke when the shared-prefix trace's
-        first hit compiled mid-run).  Each variant runs one all-padding
-        batch against a scratch pool: every write trash-routes, no
-        engine state is touched.  warmup() calls this with every page
-        multiple below ``max_prompt_len`` — the whole universe, since
-        ``_scan_start`` quantizes live offsets to page multiples."""
-        for start in sorted({int(s) for s in starts}):
-            if not 0 < start < self.max_prompt_len:
-                continue  # 0 is the base program warmup() already ran
-            fn = self._prefill_at(start)
-            scratch = self._place_pool(kv_pages.init_page_pool(
-                self.cfg, n_pages=self.n_pages, page_len=self.page_len,
-                max_slots=self.max_slots,
-                pages_per_seq=self.pages_per_seq,
-            ))
-            B = self.prefill_batch
-            fn(
-                self.params, scratch,
-                jnp.zeros((B, self.max_prompt_len), jnp.int32),
-                jnp.zeros((B,), jnp.int32),
-                jnp.full((B,), start, jnp.int32),
-                jnp.full((B,), -1, jnp.int32),
-                jax.random.PRNGKey(0),
-            )
-        # re-zero the wall clock like warmup() does: the compiles above
-        # ran AFTER warmup reset _t0, and an open-loop run() against a
-        # stale origin sees every early arrival as already overdue —
-        # their TTFT would bill the warm time the method exists to hide
         self._t0 = time.perf_counter()
 
     def _advance(self, dt: float) -> None:
@@ -1626,25 +1537,14 @@ class ServeEngine:
             return Match()
         return self.prefix.match(req.prompt)
 
-    def _scan_start(self, m: Match) -> int:
-        """The compiled-variant offset a match rides: the page-aligned
-        floor of its matched length.  Quantizing here bounds the
-        variant universe to page multiples (all warmed off the clock)
-        at the cost of replaying at most ``page_len - 1`` matched
-        positions per request — their writes stay masked by the
-        per-row ``starts``, so the replay is bit-exact by construction."""
-        return (m.matched // self.page_len) * self.page_len
-
     def _admittable(self) -> list[tuple[int, Request, Match]]:
         """(slot, request, prefix-match) triples the scheduler can
         admit right now: bounded by free slots, the prefill batch
         width, and the pool's uncommitted pages (worst-case accounting
         counts only the SUFFIX pages of a matched request — the
-        adopted prefix is already resident).  Batches are homogeneous
-        in their PAGE-ALIGNED matched floor (``_scan_start``) so the
-        whole batch rides one static start-offset prefill variant;
-        when the free set is short, LRU eviction of unpinned cached
-        pages runs before backpressure."""
+        adopted prefix is already resident).  When the free set is
+        short, LRU eviction of unpinned cached pages runs before
+        backpressure."""
         if self.draining:
             return []  # elastic scale-down: finish live work, admit none
         if self.admission == "static" and any(
@@ -1658,8 +1558,6 @@ class ServeEngine:
         while (self.queue and free
                and len(out) < self.prefill_batch):
             m = self._match(self.queue[0])
-            if out and self._scan_start(m) != self._scan_start(out[0][2]):
-                break  # next batch: different static start offset
             # with speculation on, the prefix discount is forfeit at
             # the ADMISSION bill (the adoption itself — and the prefill
             # compute it saves — still happens): the drafter pool has
@@ -1754,24 +1652,28 @@ class ServeEngine:
             pages[: len(claimed)] = claimed
             self.pool = _ref(self.pool, jnp.asarray(pages))
 
+    def _width_for(self, longest: int) -> int:
+        """The ladder's smallest width that holds ``longest`` positions."""
+        return next(
+            w for w in prefill_widths(self.max_prompt_len) if w >= longest
+        )
+
     def _run_prefill(self, batch: list[tuple[int, Request, Match]]) -> None:
         from ddl25spring_tpu.obs import flight
 
         B = self.prefill_batch
-        # the scan starts at the PAGE-ALIGNED floor of the batch's
-        # matched length (batches are floor-homogeneous): rows replay
-        # the [start, matched) gap bit-exactly with writes masked, so
-        # only page-multiple offsets ever exist as compiled variants —
-        # all of them warmed by warmup(), none compiled mid-run (an
-        # accidental partial-prefix hit on random traffic would
-        # otherwise compile an arbitrary-offset program on the clock)
-        start = self._scan_start(batch[0][2])
-        prompts = np.zeros((B, self.max_prompt_len), np.int32)
+        # each row carries its UNMATCHED suffix from column 0, and the
+        # pass is as wide as the ladder's smallest width that holds the
+        # batch's longest: a radix hit rides a narrower (cheaper) pass
+        width = self._width_for(
+            max(req.prompt_len - m.matched for _, req, m in batch)
+        )
+        prompts = np.zeros((B, width), np.int32)
         lens = np.zeros((B,), np.int32)
         starts = np.zeros((B,), np.int32)
         slot_ids = np.full((B,), -1, np.int32)
         for row, (slot, req, m) in enumerate(batch):
-            prompts[row, : req.prompt_len] = req.prompt
+            prompts[row, : req.prompt_len - m.matched] = req.prompt[m.matched:]
             lens[row] = req.prompt_len
             starts[row] = m.matched
             slot_ids[row] = slot
@@ -1783,13 +1685,12 @@ class ServeEngine:
         for slot, req, m in batch:
             self._tl("serve_admit", rid=req.rid, slot=slot)
         self._adopt_batch(batch)
-        prefill = self._prefill_at(start)
         # what the pass does and what it could have done: the prompt
-        # positions it writes against the positions its padded scan runs
+        # positions it writes against the positions it computes
         counts = {
-            "rows": len(batch), "start": start,
+            "rows": len(batch), "width": width,
             "prompt_tokens": int(lens.sum() - starts.sum()),
-            "scanned_positions": B * (self.max_prompt_len - start),
+            "scanned_positions": B * width,
         }
         # (the two that the benchmark's prefill_fill_pct reader sums)
         t0 = time.perf_counter()
@@ -1799,7 +1700,7 @@ class ServeEngine:
             "serve.prefill", **counts,
             rids=" ".join(str(req.rid) for _, req, _ in batch),
         ):
-            self.pool, first, ok = prefill(
+            self.pool, first, ok = self._prefill(
                 self.params, self.pool, jnp.asarray(prompts),
                 jnp.asarray(lens), jnp.asarray(starts),
                 jnp.asarray(slot_ids),
@@ -1809,17 +1710,22 @@ class ServeEngine:
         if not bool(ok):
             self.pool_ok_failures += 1
         if self.spec_k:
-            # the drafter prefills its OWN pool over the same batch —
-            # always the full prompt scan (the radix cache shares
+            # the drafter prefills its OWN pool over the same batch,
+            # whole prompts from position 0 (the radix cache shares
             # target pages only, so a matched prefix saves no drafter
             # work); its sampled token is discarded (the target's
             # `first` is the committed stream).  Greedy: the key is
             # never consumed, so the engine's key stream — and with it
             # the spec-off bitwise twin — is untouched.
-            with self._span("serve.draft_prefill", rows=len(batch)):
+            d_width = self._width_for(lens.max())
+            whole = np.zeros((B, d_width), np.int32)
+            for row, (_slot, req, _m) in enumerate(batch):
+                whole[row, : req.prompt_len] = req.prompt
+            with self._span("serve.draft_prefill", rows=len(batch),
+                            width=d_width):
                 self.draft_pool, _draft_first, ok_d = self._draft_prefill(
                     self.draft_params, self.draft_pool,
-                    jnp.asarray(prompts),
+                    jnp.asarray(whole),
                     jnp.asarray(lens), jnp.zeros((B,), jnp.int32),
                     jnp.asarray(slot_ids), self._zero_key,
                 )
@@ -1827,28 +1733,26 @@ class ServeEngine:
                 self.pool_ok_failures += 1
         wall = time.perf_counter() - t0
         self._prefills += 1
-        # the virtual clock charges prefill for the scan it actually
-        # ran: a start-offset variant costs proportionally less — the
-        # deterministic half of the cached-vs-cold A/B (the wall clock
-        # measures the same saving, noisily)
-        self._advance(
-            self.tick_s * (self.max_prompt_len - start)
-            / self.max_prompt_len
-        )
+        # the virtual clock charges a pass by its width (a full-width
+        # pass one tick): a batch of radix hits rides a narrower pass
+        # and costs proportionally less — the deterministic half of the
+        # cached-vs-cold A/B (the wall clock measures the same saving,
+        # noisily)
+        charge = self.tick_s * width / self.max_prompt_len
+        self._advance(charge)
         if self.spec_k:
-            # the drafter's full-prompt scan, at its FLOP ratio
-            self._advance(self.tick_s * self.spec_flop_ratio)
+            # the drafter's full-prompt pass, at its FLOP ratio
+            self._advance(
+                self.tick_s * self.spec_flop_ratio
+                * d_width / self.max_prompt_len
+            )
         now = self.now()
         # what THIS prefill pass cost on the engine clock — the middle
-        # term of the TTFT decomposition.  Virtual: the target scan's
+        # term of the TTFT decomposition.  Virtual: the target pass's
         # deterministic charge (the drafter's charge lands in the
         # first-decode residual).  Wall: the measured device wall of
         # the pass (host overhead lands in the residual).
-        prefill_cost = (
-            self.tick_s * (self.max_prompt_len - start)
-            / self.max_prompt_len
-            if self.clock == "virtual" else wall
-        )
+        prefill_cost = charge if self.clock == "virtual" else wall
         with self._span("serve.emit"):
             for row, (slot, req, m) in enumerate(batch):
                 req.admitted_t = now
@@ -1870,11 +1774,9 @@ class ServeEngine:
                     if m.matched > 0:
                         self.prefix.hits += 1
                         self.prefix.hit_tokens += m.matched
-                # saved = the scan positions actually skipped (the aligned
-                # floor), not the matched length — the [start, matched) gap
-                # is replayed, so billing it as saved would overcount
-                self.prefill_tokens_saved += start
-                self.prefill_flops_saved += start * self._flops_per_token
+                # saved = the matched positions: the pass computes none
+                self.prefill_tokens_saved += m.matched
+                self.prefill_flops_saved += m.matched * self._flops_per_token
                 # the drafter owes this first committed token its KV; a
                 # request that completes at this very token is released by
                 # the flush, which clears the pending list with the slot
@@ -1891,7 +1793,7 @@ class ServeEngine:
                 self.ttft_decomp.append((queue_wait, prefill_cost,
                                          first_decode))
                 self._tl(
-                    "serve_prefill", rid=req.rid, slot=slot, start=start,
+                    "serve_prefill", rid=req.rid, slot=slot, width=width,
                     prefix_hit_tokens=int(m.matched),
                     wall_s=round(wall, 6),
                 )
@@ -1908,8 +1810,7 @@ class ServeEngine:
             self._track_pages()
         flight.record(
             kind="serve_prefill", step=self._prefills, wall_s=round(wall, 6),
-            admitted=len(batch), queue=len(self.queue),
-            **({"prefix_start": start} if start else {}),
+            admitted=len(batch), queue=len(self.queue), width=width,
         )
 
     def _emit_token(self, slot: int, req: Request, tok: int,
@@ -2567,7 +2468,6 @@ def make_tp_serve_program(
     pages_per_seq: int = 4,
     max_slots: int = 4,
     max_prompt_len: int = 8,
-    start: int = 0,
     model_axis: str = "model",
     temperature: float = 0.0,
     sentinel: bool | None = False,
@@ -2593,8 +2493,8 @@ def make_tp_serve_program(
     params for the ZeRO-3 ``[L, n, k]`` row layout
     (:func:`ddl25spring_tpu.parallel.zero.zero_stream_llama_params`):
     decode gathers one layer per position (double-buffered), prefill
-    reconstructs the stack transiently — the ``serve-decode-
-    zero3stream`` registry entry."""
+    reconstructs the stack transiently, once a pass — the
+    ``serve-decode-zero3stream`` registry entry."""
     from jax.sharding import NamedSharding
 
     if program not in ("decode", "prefill", "draft", "verify"):
@@ -2624,18 +2524,13 @@ def make_tp_serve_program(
     }
     tp_axis = model_axis if t > 1 else None
 
-    if program == "decode":
-        fn = _tp_compiled_programs(
+    if program in ("decode", "prefill"):
+        tick, prefill, _release_fn = _tp_compiled_programs(
             cfg, mesh, max_prompt_len=max_prompt_len,
             temperature=temperature, sentinel=sentinel, donate=False,
             weight_stream=weight_stream, model_axis=model_axis,
-        )[0]
-    elif program == "prefill":
-        fn = _tp_prefill_variant(
-            cfg, mesh, max_prompt_len=max_prompt_len, start=start,
-            temperature=temperature, sentinel=sentinel, donate=False,
-            weight_stream=weight_stream, model_axis=model_axis,
         )
+        fn = tick if program == "decode" else prefill
     else:
         # the speculative pair rides the same sharded pool contract;
         # late import — spec.py needs this module's block body
@@ -2661,22 +2556,19 @@ def make_tp_serve_program(
 
 
 def describe(mesh, program: str = "decode", model_axis: str = "model",
-             start: int = 0, per_chip: bool = False,
-             weight_stream: bool = False):
+             per_chip: bool = False, weight_stream: bool = False):
     """Compile-analytics/graft-lint hook for the serving programs
     (:data:`ddl25spring_tpu.obs.xla_analytics.STRATEGIES` entries
-    ``serve-decode`` / ``serve-prefill`` / ``serve-prefill-cached`` and
-    the PR-18 trio ``serve-decode-tp`` / ``serve-prefill-tp`` /
+    ``serve-decode`` / ``serve-prefill`` and the PR-18 trio
+    ``serve-decode-tp`` / ``serve-prefill-tp`` /
     ``serve-decode-zero3stream``): the TP-sharded decode tick / prefill
-    lowered exactly as the engine builds them.  ``start > 0`` pins the
-    prefix cache's start-offset prefill variant — the scan shortens to
-    ``max_prompt_len - start`` positions, so its collective count (and
-    the FLOPs the radix hit saves) is a compile-time fact the signature
-    gate can hold.
+    lowered exactly as the engine builds them, the prefill at its
+    widest (``max_prompt_len``: the cold pass).
 
     The load-bearing signature: TP serving traffic is the row-parallel
-    **all-reduce ONLY** — 2 psums per block per token position, every
-    group strictly over the model axis; permutes / all-gathers /
+    **all-reduce ONLY** — 2 psums per block per pass (a decode tick; a
+    prefill of any width), every group strictly over the model axis;
+    permutes / all-gathers /
     reduce-scatters / all-to-alls are forbidden outright (serve keeps
     embed/unembed replicated — ``shard_vocab=False`` — so not even the
     logits assembly gather exists).  Peak-HBM budgets ride along like
@@ -2724,18 +2616,18 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
     fn, pool, _specs = make_tp_serve_program(
         cfg, mesh, program, page_len=page_len,
         pages_per_seq=pages_per_seq, max_slots=max_slots,
-        max_prompt_len=max_prompt_len, start=start,
+        max_prompt_len=max_prompt_len,
         model_axis=model_axis, sentinel=False,
         weight_stream=weight_stream,
     )
+    # one pass, of any width: 2 row-parallel psums per block
+    ar_count = 2 * cfg.n_layers
     if program == "decode":
         args = (
             params, pool,
             jnp.ones((max_slots,), jnp.int32),
             jax.random.PRNGKey(1),
         )
-        # one token position: 2 row-parallel psums per block
-        ar_count = 2 * cfg.n_layers
         ar_positions = max_slots
         lowered = "decode_step"
     else:
@@ -2743,14 +2635,11 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
             params, pool,
             jnp.ones((prefill_batch, max_prompt_len), jnp.int32),
             jnp.full((prefill_batch,), max_prompt_len, jnp.int32),
-            jnp.full((prefill_batch,), start, jnp.int32),
+            jnp.zeros((prefill_batch,), jnp.int32),
             jnp.arange(prefill_batch, dtype=jnp.int32),
             jax.random.PRNGKey(1),
         )
-        # every SCANNED prompt position runs the block stack — the
-        # start-offset variant's shorter count IS the saved prefill
-        ar_count = 2 * cfg.n_layers * (max_prompt_len - start)
-        ar_positions = prefill_batch
+        ar_positions = prefill_batch * max_prompt_len
         lowered = "prefill_step"
 
     expected: dict[str, Any] = {
@@ -2815,8 +2704,7 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
         # compiled serve program to (see KV_POOL_HEAD_DIM)
         "kv_sharded_dim": KV_POOL_HEAD_DIM,
         **({"max_prompt_len": max_prompt_len,
-            "prefill_batch": prefill_batch,
-            "start": start}
+            "prefill_batch": prefill_batch}
            if program == "prefill" else {}),
     }
     if per_chip or weight_stream:

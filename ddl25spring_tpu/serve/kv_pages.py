@@ -146,7 +146,8 @@ def reserve_pages(pool: Pool, slots: jax.Array, pos: jax.Array,
     """Batched first-fit allocation: every row ``i`` with ``need[i]``
     set gets the next free page, entered into ``page_table[slots[i]]``
     at the entry position ``pos[i]`` calls for (``pos // page_len`` —
-    passed explicitly because prefill allocates at positions its slots'
+    passed explicitly because prefill allocates, in ONE call over every
+    (table entry, row) its pass opens, at positions its slots'
     ``seq_len`` does not reach until the prompt is fully written).
 
     Returns ``(pool, ok)`` — ``ok`` is False when the pool cannot cover
@@ -190,9 +191,10 @@ def reserve_pages(pool: Pool, slots: jax.Array, pos: jax.Array,
 
 def write_page_ids(pool: Pool, slots: jax.Array, pos: jax.Array,
                    valid: jax.Array):
-    """``(pages, offsets)`` for writing position ``pos`` of each slot:
-    invalid rows (inactive slot, padded prefill row, position past the
-    table) are routed to the trash page."""
+    """``(pages, offsets)`` for writing position ``pos`` of each slot
+    (all three arguments of one shape: a vector, or ``[B, T]`` for a
+    prefill pass): invalid entries (inactive slot, padded prefill row or
+    position, position past the table) are routed to the trash page."""
     n_pages = pool["free"].shape[0]
     P = pool["page_table"].shape[1]
     page_len = pool["k"].shape[2]
@@ -204,10 +206,10 @@ def write_page_ids(pool: Pool, slots: jax.Array, pos: jax.Array,
 
 
 def append_layer_kv(k_pages, v_pages, layer, pages, offs, k, v):
-    """Scatter one layer's single-token k/v ``[B, H, hd]`` into the pool
-    at ``(pages[b], layer, offs[b])``.  Trash-routed rows may collide;
-    the trash page is never read, so the nondeterministic overwrite
-    order there is irrelevant."""
+    """Scatter one layer's k/v ``[B, T, H, hd]`` into the pool at
+    ``(pages[b, t], layer, offs[b, t])``.  Trash-routed positions may
+    collide; the trash page is never read, so the nondeterministic
+    overwrite order there is irrelevant."""
     return (
         k_pages.at[pages, layer, offs].set(k),
         v_pages.at[pages, layer, offs].set(v),

@@ -186,7 +186,7 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
     the write mask — keeping this body single is what makes 'draft and
     verify agree with the tick' a structural fact instead of a
     three-way copy to hand-maintain."""
-    from ddl25spring_tpu.serve.engine import _paged_block
+    from ddl25spring_tpu.serve.engine import _block_stack
 
     def step(params, pool, tok, pos, writing, active):
         page_len = pool["k"].shape[2]
@@ -199,24 +199,10 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)
 
         x = llama.embed(params, tok[:, None], cfg)
-        cos, sin = llama.rope_angles(
-            1, cfg.head_dim, pos=pos.astype(jnp.float32)
+        x, kp, vp = _block_stack(
+            params, x, pool["k"], pool["v"], rows, pages[:, None],
+            offs[:, None], pos[:, None], cfg, tp_axis,
         )
-
-        def layer(carry, inp):
-            x, kp, vp = carry
-            bp, li = inp
-            x, kp, vp = _paged_block(
-                bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin,
-                cfg, tp_axis,
-            )
-            return (x, kp, vp), None
-
-        with jax.named_scope("blocks"):
-            (x, kp, vp), _ = lax.scan(
-                layer, (x, pool["k"], pool["v"]),
-                (params["blocks"], jnp.arange(cfg.n_layers)),
-            )
         with jax.named_scope("head"):
             logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
         with jax.named_scope("sample"):

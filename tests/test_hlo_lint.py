@@ -7,7 +7,7 @@ Two contracts pinned here:
    proving the rule detects what it claims, plus a near-miss showing it
    stays quiet when the hazard is absent.
 2. **Every strategy is clean** — every registered strategy (all
-   twenty-one, the rule-table and speculative-serving variants
+   twenty-three, the rule-table and speculative-serving variants
    included) compiles with ZERO
    unwaived findings on this jax, the same
    way PR 2 pinned their collective signatures.  A refactor that

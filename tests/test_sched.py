@@ -433,11 +433,12 @@ def test_every_registered_strategy_carries_a_sched_report():
     from ddl25spring_tpu.obs.compile_report import DEFAULT_STRATEGIES
 
     assert set(DEFAULT_STRATEGIES) == set(xa.STRATEGIES)
-    # 14 training + 2 serving (PR 10) + the cached-prefill variant
-    # (PR 11) + the 2 partition-rule-table strategies (PR 12) + the
-    # speculative draft/verify pair (PR 13) + the TP serving trio
-    # (PR 18: tp decode/prefill + zero3 weight streaming)
-    assert len(DEFAULT_STRATEGIES) == 24
+    # 16 training + 2 serving (PR 10; PR 11's cached-prefill variant
+    # left with PR 27: the one-pass prefill is one program) + the 2
+    # partition-rule-table strategies (PR 12) + the speculative
+    # draft/verify pair (PR 13) + the TP serving trio (PR 18: tp
+    # decode/prefill + zero3 weight streaming)
+    assert len(DEFAULT_STRATEGIES) == 23
     for name in DEFAULT_STRATEGIES:
         r = cached_strategy_report(name)
         s = r.get("sched")

@@ -470,11 +470,9 @@ def test_ramp_and_spike_profiles_shape_the_rate():
 
 @pytest.mark.parametrize("name,ar_count", [
     ("serve-decode", 2 * 2),          # 2 psums/block x 2 layers
-    ("serve-prefill", 2 * 2 * 8),     # x max_prompt_len scan
-    # the start-offset variant scans max_prompt_len - start = 4
-    # positions: HALF serve-prefill's collectives — the compile-time
-    # proof of the prefill work a radix prefix hit skips
-    ("serve-prefill-cached", 2 * 2 * 4),
+    # a prefill is ONE pass over all its positions: the same 2 psums a
+    # block whatever its width (the serial scan ran them x 8 positions)
+    ("serve-prefill", 2 * 2),
 ])
 def test_serve_signature_pins(strategy_report, name, ar_count):
     """TP serving traffic is the row-parallel all-reduce ONLY: exact

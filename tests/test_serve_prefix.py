@@ -312,9 +312,9 @@ def test_prefix_cached_decode_matches_dense_across_cow_boundary(params):
         (PREFIX + [41, 42], 4),   # second hit (same COW source again)
     ]
     eng = make_engine(params)
-    # warming the start-offset variants (the driver's off-the-clock
-    # compile path) must not touch engine or pool state
-    eng.warm_prefill_starts((4, len(PREFIX), 0, 99))
+    # warming every prefill width (the off-the-clock compile path)
+    # must leave engine and pool state untouched
+    eng.warmup()
     assert bool(np.asarray(jax.device_get(eng.pool["free"])).all())
     assert eng.admitted == 0 and eng._prefills == 0
     got = serve_tokens(eng, reqs)
@@ -323,10 +323,10 @@ def test_prefix_cached_decode_matches_dense_across_cow_boundary(params):
     s = eng.prefix.stats()
     assert s["hits"] == 2 and s["lookups"] == 3
     assert s["hit_tokens"] == 2 * len(PREFIX)  # matched: page + partial
-    # SAVED counts only the skipped scan positions — the page-aligned
-    # floor (4 of the 6 matched tokens; the partial-page gap replays
-    # with writes masked so the variant universe stays page-quantized)
-    assert eng.prefill_tokens_saved == 2 * 4
+    # SAVED counts every matched position: rows start at their own
+    # matched length, the COW'd partial page included, so the pass
+    # computes none of the 6
+    assert eng.prefill_tokens_saved == 2 * len(PREFIX)
     assert eng.prefill_flops_saved > 0
     assert eng.pool_ok_failures == 0
     assert_pool_invariants(eng)

@@ -16,7 +16,7 @@ import pytest
 
 from ddl25spring_tpu import obs
 from ddl25spring_tpu.models import llama
-from ddl25spring_tpu.serve.engine import ServeEngine
+from ddl25spring_tpu.serve.engine import ServeEngine, prefill_widths
 from ddl25spring_tpu.utils.config import LlamaConfig
 
 CFG = LlamaConfig(
@@ -196,12 +196,16 @@ def test_prefill_counts_are_the_admitted_prompts_less_matched_prefixes(params):
     rows = [a["rows"] for a in stats]
     assert sum(rows) == eng.admitted == len(prompts)
     assert sum(tokens) == sum(map(len, prompts)) - eng.prefix.hit_tokens
-    # a pass scans prefill_batch x (max_prompt_len - start) positions, so
-    # the skipped ones are what the engine books as saved, row for row
-    full = eng.prefill_batch * eng.max_prompt_len
-    assert all(0 < s <= full for s in scanned)
-    saved_rows = sum(r * (full - s) // eng.prefill_batch for r, s in zip(rows, scanned))
-    assert saved_rows == eng.prefill_tokens_saved > 0
+    # a pass computes prefill_batch x width positions, at the smallest
+    # width of the ladder that holds its longest unmatched suffix
+    widths = prefill_widths(eng.max_prompt_len)
+    assert [a["width"] for a in stats] == [s // eng.prefill_batch for s in scanned]
+    assert all(a["width"] in widths for a in stats)
+    # cold [6+2] rides the full width; the hits' suffixes (4, 4, 1 after
+    # the cached page of 4) ride half of it, the cold [8, 8, 8] with them
+    assert scanned == [2 * 8, 2 * 4, 2 * 4]
+    # and what a hit skips is its matched positions, row for row
+    assert eng.prefill_tokens_saved == eng.prefix.hit_tokens > 0
 
 
 def test_a_labelled_engine_keys_its_names_and_an_unlabelled_one_does_not(params):

@@ -306,7 +306,8 @@ def test_tp_mem_budget_divides_per_chip_only(params):
     # per-chip variants: same program as serve-decode/serve-prefill,
     # tighter screws — 64 KiB budget + byte-exact all-reduce payload
     ("serve-decode-tp", 2 * 2, 1024, {"all-reduce"}),
-    ("serve-prefill-tp", 2 * 2 * 8, 4096, {"all-reduce"}),
+    # one pass: 4 all-reduces of [2 x 8 positions, dmodel 16] fp32
+    ("serve-prefill-tp", 2 * 2, 4096, {"all-reduce"}),
     # streaming decode adds EXACTLY n_layers x n_buckets = 2 gathers
     ("serve-decode-zero3stream", 2 * 2, 1024, {"all-reduce", "all-gather"}),
 ])

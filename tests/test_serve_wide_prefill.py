@@ -1,0 +1,342 @@
+"""The wide prefill pass (PR 27): every position of a prompt batch in one
+pass, at a width taken from the batch.
+
+What it is held to is the one-token step it replaced as the prefill's
+body (``serve/spec.py``'s ``_position_step``: the decode tick's block at
+explicit positions), run position by position on the same pool, on the
+15M preset in float32:
+
+- the pass writes the same pages: tables, refcounts and free set equal,
+  every K/V row equal within ``KV_RTOL`` / ``KV_ATOL`` (a matmul over T
+  rows sums in another order than T matmuls over one);
+- the first tokens and the greedy stream that follows are identical;
+- the dense decode path (``models/decode.decode_step``), which shares no
+  block with the pass, leaves the same K and V and the same first tokens;
+- the width is the ladder's smallest that holds the batch's longest
+  unmatched suffix, a batch of radix hits rides a narrower pass than its
+  cold twin, and nothing compiles after ``warmup()``;
+- a pool too small fails the pass whole.
+"""
+
+from __future__ import annotations
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ddl25spring_tpu import obs
+from ddl25spring_tpu.models import llama
+from ddl25spring_tpu.serve import kv_pages
+from ddl25spring_tpu.serve.engine import (
+    ServeEngine,
+    make_decode_tick,
+    make_prefill,
+    prefill_widths,
+)
+from ddl25spring_tpu.serve.spec import _position_step
+from ddl25spring_tpu.utils.config import LlamaConfig
+
+CFG = LlamaConfig(dtype="float32")  # the 15M preset
+PAGE_LEN, PAGES_PER_SEQ, SLOTS, N_PAGES = 16, 8, 4, 24
+MAX_PROMPT = 64
+# float32 holds 2^-24 = 6e-8 a rounding; K and V reach 1.6 after six
+# blocks of sums over 288 and 1,152 terms taken in another order, and
+# differ by at most 1.3e-6 here: eight times of room
+KV_RTOL, KV_ATOL = 1e-4, 1e-5
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture(scope="module")
+def params():
+    return llama.init_llama_params(jax.random.PRNGKey(0), CFG)
+
+
+def fresh_pool(n_pages=N_PAGES):
+    return kv_pages.init_page_pool(
+        CFG, n_pages=n_pages, page_len=PAGE_LEN, max_slots=SLOTS,
+        pages_per_seq=PAGES_PER_SEQ,
+    )
+
+
+def tokens_of(seed, n):
+    return np.random.default_rng(seed).integers(1, CFG.vocab_size, n).tolist()
+
+
+@pytest.fixture(scope="module")
+def step():
+    return jax.jit(_position_step(CFG, None))
+
+
+def serial(step, params, pool, prompts, lo, hi, valid):
+    """The one-token step over positions ``[lo[s], hi[s])`` of each slot,
+    position by position; returns the pool and each slot's argmax after
+    its LAST position."""
+    last = np.zeros((SLOTS,), np.int32)
+    for i in range(int(max(hi))):
+        tok = np.asarray(
+            [p[i] if i < len(p) else 0 for p in prompts], np.int32
+        )
+        writing = valid & (i >= lo) & (i < hi)
+        pool, g, _absmax, ok = step(
+            params, pool, jnp.asarray(tok), jnp.full((SLOTS,), i, jnp.int32),
+            jnp.asarray(writing), pool["active"],
+        )
+        assert bool(ok)
+        last = np.where(i == hi - 1, np.asarray(g), last)
+    return pool, last
+
+
+# rows are slots here (slot_ids = 0..3): three prompts of different
+# lengths and starts across page boundaries, and a padding row
+CASES = {
+    # two cold rows and one that starts past a full adopted page
+    "cold_and_page_hit": ([37, 50, 21, 0], [0, 16, 0, 0]),
+    # a start inside a page (the COW'd partial page of a radix hit), one
+    # at the last position but one, and a one-token prompt
+    "partial_page_starts": ([64, 33, 1, 0], [5, 31, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_wide_pass_equals_the_one_token_step(params, step, case):
+    lens, starts = (np.asarray(a, np.int32) for a in CASES[case])
+    prompts = [tokens_of(7 + s, n) for s, n in enumerate(lens)]
+    valid = lens > 0
+    slot_ids = np.where(valid, np.arange(SLOTS), -1).astype(np.int32)
+
+    # what a hit finds: its first `starts` positions already in pages of
+    # its table (the one-token step wrote them; adoption seats the same)
+    pool = kv_pages.activate_slots(
+        fresh_pool(), jnp.asarray(slot_ids), jnp.asarray(valid)
+    )
+    pool, _ = serial(step, params, pool, prompts, np.zeros_like(starts),
+                     starts, valid)
+
+    ref, ref_first = serial(step, params, pool, prompts, starts, lens, valid)
+    ref = {**ref, "seq_len": jnp.asarray(lens)}
+
+    width = next(w for w in prefill_widths(MAX_PROMPT)
+                 if w >= (lens - starts).max())
+    packed = np.zeros((SLOTS, width), np.int32)
+    for s, p in enumerate(prompts):
+        packed[s, : lens[s] - starts[s]] = p[starts[s]:]
+    prefill = jax.jit(make_prefill(CFG, max_prompt_len=MAX_PROMPT,
+                                   sentinel=False))
+    got, first, ok = prefill(
+        params, pool, jnp.asarray(packed), jnp.asarray(lens),
+        jnp.asarray(starts), jnp.asarray(slot_ids), jax.random.PRNGKey(0),
+    )
+    assert bool(ok)
+
+    # the same pages in the same tables (the one reservation allocates
+    # in the order the position-by-position walk did) ...
+    for key in ("page_table", "refcount", "free", "seq_len", "active"):
+        np.testing.assert_array_equal(got[key], ref[key], err_msg=key)
+    # ... holding the same K and V (the trash page, last, is never read)
+    for key in ("k", "v"):
+        np.testing.assert_allclose(
+            got[key][:-1], ref[key][:-1], rtol=KV_RTOL, atol=KV_ATOL,
+            err_msg=key,
+        )
+    written = int(np.ceil(lens[valid] / PAGE_LEN).sum())
+    assert int((~np.asarray(got["free"])).sum()) == written
+    # the first tokens, and the greedy stream after them
+    np.testing.assert_array_equal(np.asarray(first)[valid], ref_first[valid])
+    tick = jax.jit(make_decode_tick(CFG, sentinel=False))
+    a = b = jnp.asarray(np.where(valid, ref_first, 0).astype(np.int32))
+    for _ in range(6):
+        got, a, ok_a = tick(params, got, a, jax.random.PRNGKey(0))
+        ref, b, ok_b = tick(params, ref, b, jax.random.PRNGKey(0))
+        assert bool(ok_a) and bool(ok_b)
+        np.testing.assert_array_equal(np.asarray(a)[valid],
+                                      np.asarray(b)[valid])
+
+
+@pytest.mark.parametrize("lens", [
+    (37, 64, 21, 0),   # the full width, and a padding row
+    (1, 16, 17, 48),   # one token; a page exactly full; one position past it
+])
+def test_wide_pass_writes_the_dense_paths_keys_and_values(params, lens):
+    """The one-token step shares ``_paged_block`` with the pass, so a
+    fault in the block would pass the comparison above.  The dense
+    decode path (``models/decode.decode_step``: its own block over a
+    contiguous ``[L, B, max_len, H, hd]`` cache) shares none of it: a
+    cold batch's pages hold its K and V, and the first tokens are its
+    argmax."""
+    from ddl25spring_tpu.models.decode import decode_step, init_kv_cache
+
+    lens = np.asarray(lens, np.int32)
+    prompts = [tokens_of(20 + s, n) for s, n in enumerate(lens)]
+    valid = lens > 0
+    slot_ids = np.where(valid, np.arange(SLOTS), -1).astype(np.int32)
+    packed = np.zeros((SLOTS, MAX_PROMPT), np.int32)
+    for s, p in enumerate(prompts):
+        packed[s, : lens[s]] = p
+
+    dense = jax.jit(lambda p, c, t, i: decode_step(p, c, t, i, CFG))
+    cache = init_kv_cache(CFG, SLOTS, MAX_PROMPT)
+    want_first = np.zeros((SLOTS,), np.int32)
+    for i in range(MAX_PROMPT):
+        logits, cache = dense(params, cache, jnp.asarray(packed[:, i]),
+                              jnp.int32(i))
+        want_first = np.where(i == lens - 1, np.argmax(logits, -1), want_first)
+
+    prefill = jax.jit(make_prefill(CFG, max_prompt_len=MAX_PROMPT,
+                                   sentinel=False))
+    pool = kv_pages.activate_slots(
+        fresh_pool(), jnp.asarray(slot_ids), jnp.asarray(valid)
+    )
+    pool, first, ok = prefill(
+        params, pool, jnp.asarray(packed), jnp.asarray(lens),
+        jnp.zeros((SLOTS,), jnp.int32), jnp.asarray(slot_ids),
+        jax.random.PRNGKey(0),
+    )
+    assert bool(ok)
+    np.testing.assert_array_equal(np.asarray(first)[valid], want_first[valid])
+    table = np.asarray(pool["page_table"])
+    for key, want in zip(("k", "v"), cache):
+        pages = np.asarray(pool[key])  # [n_pages + 1, L, page_len, H, hd]
+        for s in np.flatnonzero(valid):
+            n = int(lens[s])
+            got = np.concatenate(
+                [pages[p] for p in table[s, : -(-n // PAGE_LEN)]], axis=1
+            )[:, :n]
+            np.testing.assert_allclose(
+                got, np.asarray(want)[:, s, :n], rtol=KV_RTOL, atol=KV_ATOL,
+                err_msg=f"{key} of slot {s}",
+            )
+
+
+def test_a_pool_too_small_fails_the_pass_whole(params):
+    """Two prompts of 3 pages each against 5 free pages: ``ok`` is false
+    and NOTHING is reserved (``reserve_pages`` is all-or-nothing and the
+    pass calls it once)."""
+    lens = np.asarray([40, 40, 0, 0], np.int32)
+    slot_ids = np.asarray([0, 1, -1, -1], np.int32)
+    packed = np.zeros((SLOTS, MAX_PROMPT), np.int32)
+    packed[:2, :40] = [tokens_of(1, 40), tokens_of(2, 40)]
+    prefill = jax.jit(make_prefill(CFG, max_prompt_len=MAX_PROMPT,
+                                   sentinel=False))
+    args = (jnp.asarray(packed), jnp.asarray(lens),
+            jnp.zeros((SLOTS,), jnp.int32), jnp.asarray(slot_ids),
+            jax.random.PRNGKey(0))
+    pool, _first, ok = prefill(params, fresh_pool(n_pages=5), *args)
+    assert not bool(ok)
+    assert bool(np.asarray(pool["free"]).all())
+    assert int(np.asarray(pool["refcount"]).sum()) == 0
+    assert (np.asarray(pool["page_table"]) == -1).all()
+    # one page more and the same pass fits
+    pool, _first, ok = prefill(params, fresh_pool(n_pages=6), *args)
+    assert bool(ok) and int((~np.asarray(pool["free"])).sum()) == 6
+
+
+# --------------------------------------------------------- the ladder
+
+
+@pytest.mark.parametrize("max_prompt_len,widths", [
+    (256, (64, 128, 192, 256)),
+    (64, (16, 32, 48, 64)),
+    (8, (2, 4, 6, 8)),
+    (7, (2, 4, 6, 7)),
+    (3, (1, 2, 3)),
+    (1, (1,)),
+])
+def test_the_ladder_is_quarters_of_the_longest_prompt(max_prompt_len, widths):
+    assert prefill_widths(max_prompt_len) == widths
+
+
+@pytest.fixture(autouse=True)
+def _clean_rings():
+    obs.counters.reset()
+    yield
+    obs.counters.reset()
+
+
+def make_engine(params, **kw):
+    kw.setdefault("page_len", PAGE_LEN)
+    kw.setdefault("n_pages", 32)
+    kw.setdefault("max_slots", SLOTS)
+    kw.setdefault("pages_per_seq", PAGES_PER_SEQ)
+    kw.setdefault("prefill_batch", 2)
+    kw.setdefault("max_prompt_len", MAX_PROMPT)
+    kw.setdefault("clock", "virtual")
+    kw.setdefault("trace_label", None)
+    return ServeEngine(params, CFG, **kw)
+
+
+def serve(eng, prompts, max_new=2):
+    """One batch: submit, then step until drained."""
+    reqs = [eng.make_request(p, max_new) for p in prompts]
+    for r in reqs:
+        assert eng.submit(r) is None
+    while eng.queue or any(s is not None for s in eng.slots):
+        eng.step()
+    eng.step()  # the flush
+    return [r.tokens for r in reqs]
+
+
+def scanned():
+    return [int(v) for _, v in obs.counters.window(
+        "serve.prefill.scanned_positions", 0.0, time.perf_counter()
+    )]
+
+
+@pytest.mark.parametrize("longest,width", [
+    (1, 16), (16, 16), (17, 32), (32, 32), (33, 48), (49, 64), (64, 64),
+])
+def test_a_pass_rides_the_smallest_width_that_holds_it(params, longest, width):
+    eng = make_engine(params)
+    seen = []
+    inner = eng._prefill
+    eng._prefill = lambda p, pool, prompts, *a: (
+        seen.append(prompts.shape), inner(p, pool, prompts, *a))[1]
+    serve(eng, [tokens_of(3, longest), tokens_of(4, min(longest, 9))])
+    assert seen == [(eng.prefill_batch, width)]
+    assert scanned() == [eng.prefill_batch * width]
+
+
+def test_radix_hits_ride_a_narrower_pass_than_their_cold_twin(params):
+    shared = tokens_of(5, 40)  # two full pages of 16 are cacheable
+    batch = [shared + tokens_of(6, 6), shared + tokens_of(7, 9)]
+    cold = make_engine(params)
+    hot = make_engine(params, prefix_cache=True)
+    serve(hot, [shared + tokens_of(8, 3)])  # seeds the cache
+    obs.counters.reset()
+    want = serve(cold, batch)
+    assert scanned() == [2 * 64]  # 49 positions: the full width
+    obs.counters.reset()
+    assert serve(hot, batch) == want  # the same greedy streams
+    assert scanned() == [2 * 32]  # 32 matched: suffixes of 14 and 17
+    assert hot.prefix.hits == 2
+    assert hot.prefill_tokens_saved == hot.prefix.hit_tokens == 2 * 32
+    assert hot.pool_ok_failures == cold.pool_ok_failures == 0
+
+
+def test_nothing_compiles_after_warmup(params):
+    """Every width, a radix hit with a COW'd partial page and the decode
+    tick run on programs ``warmup()`` already compiled."""
+    eng = make_engine(params, prefix_cache=True)
+    eng.warmup()
+    compiled = []
+
+    def listener(event, _seconds, **_):
+        if event == COMPILE_EVENT:
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        shared = tokens_of(9, 21)  # a full page and a partial one
+        for n, width in zip((3, 30, 40, 60), prefill_widths(MAX_PROMPT)):
+            serve(eng, [tokens_of(10 + n, n)])
+        serve(eng, [shared])
+        serve(eng, [shared + tokens_of(11, 4)])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert eng.prefix.hits == 1 and eng._prefills == 6
+    assert sorted(set(scanned())) == [
+        eng.prefill_batch * w for w in prefill_widths(MAX_PROMPT)
+    ]
+    assert compiled == []

@@ -491,13 +491,13 @@ def test_zero_family_entry_layouts_satisfy_the_reshard_contract():
 
 def test_serve_programs_agree_on_the_kv_pool_split():
     """The cross-program half on the real serve programs: prefill,
-    decode, the cached-prefill variant, AND the PR-18 trio (per-chip
+    decode AND the PR-18 trio (per-chip
     budget entries + the ZeRO-3 streaming decode) shard every pool
     buffer identically, k/v on the engine's declared head dim."""
     reports = {
         n: _report(n)
         for n in (
-            "serve-decode", "serve-prefill", "serve-prefill-cached",
+            "serve-decode", "serve-prefill",
             "serve-decode-tp", "serve-prefill-tp",
             "serve-decode-zero3stream",
         )

@@ -191,7 +191,7 @@ def test_prefill_program_compiles_for_v5e(ref_serve, one_chip):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     prefill = jax.jit(
-        make_prefill(cfg, max_prompt_len=Lp, start=0, temperature=0.0,
+        make_prefill(cfg, max_prompt_len=Lp, temperature=0.0,
                      sentinel=False),
         donate_argnums=(1,),
     )
