@@ -5,18 +5,34 @@
 
 ``--workload X`` opens ``workloads/X.json``; that file names the
 configuration (``configs/<config>.json``) and the runner
-(``runners/<runner>.py``).  After the run, every ``metrics/*.json`` whose
+(``runners/<runner>.py``), and the configuration names its model family
+(``families/<family>.py``).  After the run, every ``metrics/*.json`` whose
 ``runner`` matches (and whose optional ``workloads`` list holds the cell)
 is read by the reader it names (``readers/<reader>.py``): the
 ``end_to_end`` ones with ``--trace 0``, the ``per_layer`` ones with
-``--trace 1``.  A later PR adds a cell, a configuration, a metric, a reader
-or a runner kind by adding files; it edits none that is here.
+``--trace 1``.  A later PR adds a cell, a configuration, a model family, a
+metric, a reader or a runner kind by adding files; it edits none that is
+here.
+
+Everything the harness knows about a model comes from the family file,
+which the runner gets as ``ctx["family"]``.  A family file has
+``build(config, n_layers=, use_flash=)`` (the program's configuration
+object, refusing what the program cannot state), ``init_params(cfg, seed)``
+and ``init_staged_params(cfg, seed, stages)`` (seeded weights on the
+device), ``vocab(cfg)`` and ``seq_len(cfg)`` (what the traffic draws),
+``check_served(cfg, params, done, pad_to=)``, ``reference_loss(cfg, params,
+tokens)`` and ``check_train_loss(system, reference)`` (the plain
+reference's checks, which decide ``correct``), ``train_flops_per_token(cfg)``
+and ``flash_calls(cfg, batch)`` (the counts the peak readers divide by).  A
+configuration with no ``"family"`` is an error; there is no default.
 
 The last line of stdout is the one JSON object of the contract
-(``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, when
-traced, ``breakdown``).  Earlier lines are notes for a reader, each one
-JSON object too.  No TPU, or fewer chips than the cell asks for: exit code
-2 and no result line.  ``BENCH_RUN`` in the environment is ignored.
+(``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, when
+traced ``breakdown``, and last ``compared``: each number that decided
+``correct`` beside its limit, which are also the last lines on stderr).
+Earlier lines are notes for a reader, each one JSON object too.  No TPU, or
+fewer chips than the cell asks for: exit code 2 and no result line.
+``BENCH_RUN`` in the environment is ignored.
 """
 
 from __future__ import annotations
@@ -63,7 +79,7 @@ def load_module(bench_dir: str, kind: str, name: str):
     """``<bench_dir>/<kind>/<name>.py`` as a module, found by name."""
     path = os.path.join(bench_dir, kind, f"{name}.py")
     if not os.path.isfile(path):
-        raise FileNotFoundError(f"no {kind[:-1]} {name!r}: {path} is missing")
+        raise FileNotFoundError(f"no {kind}/{name}.py: {path} is missing")
     spec = importlib.util.spec_from_file_location(f"benchmark_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -74,6 +90,18 @@ def load_cell(bench_dir: str, workload: str) -> tuple[dict, dict]:
     cell = load_json(os.path.join(bench_dir, "workloads", f"{workload}.json"))
     config = load_json(os.path.join(bench_dir, "configs", f"{cell['config']}.json"))
     return cell, config
+
+
+def load_family(bench_dir: str, config: dict):
+    """The model family a configuration names, found by name like a runner
+    or a reader.  There is no default: a configuration that names none
+    would otherwise run as whatever model the harness happened to know."""
+    if "family" not in config:
+        raise KeyError(
+            f"configuration {config.get('name')!r} names no model family: add "
+            '"family": "<name>" for families/<name>.py; there is no default'
+        )
+    return load_module(bench_dir, "families", config["family"])
 
 
 def metric_specs(bench_dir: str, runner: str, workload: str, kind: str) -> list[dict]:
@@ -192,16 +220,20 @@ def device_line(devices, chips: int, record: dict) -> dict:
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              bench_dir: str = BENCH_DIR, allow_cpu: bool = False,
-             keep_trace: str | None = None) -> dict:
+             peaks: dict | None = None, keep_trace: str | None = None) -> dict:
     """Run one cell and return the contract's result object.
 
     ``allow_cpu`` exists for the benchmark's own tests, which drive the
     runners at tiny sizes on the CPU through this entry; the command line
     cannot set it, so nothing a CPU measures is ever printed as a result.
+    With it the peak readers find no peaks and stay silent, unless the test
+    hands in ``peaks`` of its own to see their arithmetic.
     """
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     cell, config = load_cell(bench_dir, workload)
     chips = int(cell["chips"])
+    runner = load_module(bench_dir, "runners", cell["runner"])
+    family = load_family(bench_dir, config)
 
     import jax
 
@@ -221,15 +253,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     from benchmark import flops
 
-    peaks = None if allow_cpu else flops.load_peaks(
-        devices[0].device_kind, os.path.join(bench_dir, "peaks.json")
-    )
+    if not allow_cpu:
+        peaks = flops.load_peaks(
+            devices[0].device_kind, os.path.join(bench_dir, "peaks.json")
+        )
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
     tracer = Tracer(jax, trace, trace_dir)
-    runner = load_module(bench_dir, "runners", cell["runner"])
     try:
         record = runner.run({
-            "cell": cell, "config": config, "seed": int(seed),
+            "cell": cell, "config": config, "family": family, "seed": int(seed),
             "seconds": float(seconds), "trace": bool(trace),
             "devices": devices[:chips], "chips": chips,
             "t_process": T_PROCESS, "tracer": tracer,
@@ -274,6 +306,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             "device_ops": record["trace"]["device_ops"],
             "idle_gaps": record["trace"]["idle_gaps"],
         }
+    # last in the line, and the last lines on stderr: what decided `correct`
+    result["compared"] = record["compared"]
+    for name, c in record["compared"].items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     return result
 
 

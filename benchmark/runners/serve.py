@@ -1,5 +1,7 @@
 """The ``serve`` runner: the paged server's engine, built as
-``serve.driver`` builds it, at the widths of a configuration file.
+``serve.driver`` builds it, around the model of the configuration's family
+file (``ctx["family"]``: configuration object, seeded weights, range of
+token ids, the served tokens' check; ``families/``).
 
 ``serve_model`` and ``run_serve_bench`` take three preset names and no
 config (PERF.md, Open questions), so this calls what they call, one level
@@ -28,7 +30,7 @@ from __future__ import annotations
 
 import time
 
-from benchmark import model, reference, stats, traffic
+from benchmark import stats, traffic
 
 GRACE_S = 10.0     # after the close: wait this long for first tokens
 DRAIN_S = 60.0     # then let what is in flight finish, for the leak check
@@ -65,28 +67,25 @@ def _row(req, reason, t_open: float, t_close: float) -> dict:
 
 
 def run(ctx: dict) -> dict:
-    import jax
-
-    from ddl25spring_tpu.models import llama
     from ddl25spring_tpu.serve import driver
 
-    cell, config, tracer = ctx["cell"], ctx["config"], ctx["tracer"]
+    t_run = time.perf_counter()
+    cell, family, tracer = ctx["cell"], ctx["family"], ctx["tracer"]
     spec = cell["traffic"]
-    cfg = model.llama_config(config, use_flash=False)
-    params = jax.jit(lambda key: llama.init_llama_params(key, cfg))(
-        jax.random.PRNGKey(ctx["seed"])
-    )
+    cfg = family.build(ctx["config"], use_flash=False)
+    params = family.init_params(cfg, ctx["seed"])
     knobs = {**driver.engine_knobs(), **cell["engine"]}
     eng = driver._build_engine(
         params, cfg, knobs, clock="wall", temperature=0.0, trace_label=None
     )
-    t0 = time.perf_counter()
+    t_warm = time.perf_counter()
     eng.warmup()
-    compile_s = time.perf_counter() - t0
+    t_warmed = time.perf_counter()
+    compile_s = t_warmed - t_warm
     if ctx["trace"]:
         _wrap(eng, tracer)
 
-    stream = traffic.requests(spec, cfg.vocab_size, ctx["seed"])
+    stream = traffic.requests(spec, family.vocab(cfg), ctx["seed"])
     sent: list[tuple] = []  # (Request, rejection reason or None)
 
     def send(arrival_t=None):
@@ -171,9 +170,8 @@ def run(ctx: dict) -> dict:
     # ------------------------------------------------------ correctness
     leak = eng.mem_leak_check()
     done = [r for r in eng.done if r.arrival_t >= t_open][:CHECKED]
-    served = reference.check_served(
-        params, [(r.prompt, r.tokens) for r in done],
-        num_heads=cfg.num_heads, pad_to=eng.max_seq_len,
+    served = family.check_served(
+        cfg, params, [(r.prompt, r.tokens) for r in done], pad_to=eng.max_seq_len
     )
     mine = [r for r in rows if r["sent_in_window"]]
     failed = sum(
@@ -196,7 +194,18 @@ def run(ctx: dict) -> dict:
         ),
         "attempted": len(mine),
         "failed": failed,
+        "compared": {
+            "worst_margin": {"value": served["worst_margin"], "limit": served["eps"]},
+            "tokens_checked": {"value": served["tokens_checked"], "limit": ">= 1"},
+            "leaked_pages": {"value": leak["leaked_pages"], "limit": 0},
+            "pool_ok_failures": {"value": eng.pool_ok_failures, "limit": 0},
+            "undrained": {"value": int(not eng.drained), "limit": 0},
+        },
         "notes": {
+            # where set-up went: imports and device init, weights and
+            # engine, warm-up, ramp-in
+            "setup_parts_s": [t_run - ctx["t_process"], t_warm - t_run,
+                              compile_s, t_open_host - t_warmed],
             "ttft_ms": {"n": len(ttft), "p50": stats.median(ttft)},
             "tpot_ms": {"n": len(tpot), "p50": stats.median(tpot)},
             "served_check": served, "leaked_pages": leak["leaked_pages"],

@@ -1,9 +1,11 @@
 """The ``train`` runner: the pipeline trainer's step, as the launcher
-``lab/s01_b2_dp_pp.py`` builds it, at the widths of a configuration file.
+``lab/s01_b2_dp_pp.py`` builds it, around the model of the configuration's
+family file (``ctx["family"]``: configuration object, seeded weights split
+by stage, the loss's check, the counts of FLOPs and bytes; ``families/``).
 
 The launcher takes no width (PERF.md, Open questions), so this calls what
-it calls, one level down: ``llama.init_llama_params`` ->
-``llama.split_blocks_for_stages`` -> ``pipeline.shard_staged_params`` ->
+it calls, one level down: the family's staged weights ->
+``pipeline.shard_staged_params`` ->
 ``pipeline.make_pipeline_train_step(cfg, tx, mesh, M, data_axis=...)`` with
 ``optax.adam(8e-4)``, the launcher's optimizer.
 
@@ -20,46 +22,49 @@ import time
 
 import numpy as np
 
-from benchmark import model, reference, traffic
+from benchmark import traffic
 
 TRACE_STEPS = 4
 
 
-def init_state(cfg, tx, mesh, stages: int, seed: int):
-    """Seeded weights, made on the device in one jitted call and split for
-    the stages, then placed as the launcher places them, and the
-    optimizer's state beside them.  ``tx.init`` runs eagerly on purpose:
-    its ``zeros_like`` keeps each parameter's placement, where a jitted
-    init (and ``device_put`` inside one) left the whole state replicated on
-    every chip, which did not fit four chips (PR 25)."""
-    import jax
+def placement(config: dict, chips: int) -> tuple[int, int, int]:
+    """``(data, stage, n_layers)`` of a training configuration on ``chips``
+    chips: the mesh of its ``placement`` entry, and that entry's layers a
+    stage (else the configuration's) times the stages."""
+    place = config["placement"][str(chips)]
+    stages = int(place["stage"])
+    per_stage = place.get("layers_per_stage", config["run"]["layers_per_stage"])
+    return int(place["data"]), stages, stages * int(per_stage)
 
-    from ddl25spring_tpu.models import llama
+
+def init_state(family, cfg, tx, mesh, stages: int, seed: int):
+    """The family's seeded weights, split for the stages, placed as the
+    launcher places them, and the optimizer's state beside them.
+    ``tx.init`` runs eagerly on purpose: its ``zeros_like`` keeps each
+    parameter's placement, where a jitted init (and ``device_put`` inside
+    one) left the whole state replicated on every chip, which did not fit
+    four chips (PR 25)."""
     from ddl25spring_tpu.parallel.pipeline import shard_staged_params
 
-    params = jax.jit(lambda key: llama.split_blocks_for_stages(
-        llama.init_llama_params(key, cfg), stages
-    ))(jax.random.PRNGKey(seed))
-    staged = shard_staged_params(params, mesh)
+    staged = shard_staged_params(family.init_staged_params(cfg, seed, stages), mesh)
     return staged, tx.init(staged)
 
 
 def run(ctx: dict) -> dict:
-    import jax
     import jax.numpy as jnp
     import optax
 
     from ddl25spring_tpu.parallel.pipeline import make_pipeline_train_step
     from ddl25spring_tpu.utils.mesh import make_mesh
 
+    t_run = time.perf_counter()
     cell, config, tracer = ctx["cell"], ctx["config"], ctx["tracer"]
-    devices = ctx["devices"]
+    family, devices = ctx["family"], ctx["devices"]
     on_tpu = devices[0].platform == "tpu"
-    dp, stages, n_layers = model.train_placement(config, ctx["chips"])
-    cfg = model.llama_config(
-        config, n_layers=n_layers,
-        use_flash=bool(config["run"].get("use_flash")) and on_tpu,
-    )
+    dp, stages, n_layers = placement(config, ctx["chips"])
+    use_flash = bool(config["run"].get("use_flash")) and on_tpu
+    cfg = family.build(config, n_layers=n_layers, use_flash=use_flash)
+    seq_len = family.seq_len(cfg)
     step_spec = cell["traffic"]
     batch, M = int(step_spec["sequences_per_step"]), int(step_spec["microbatches"])
     block = batch // (M * dp)  # sequences of one microbatch on one replica
@@ -69,13 +74,13 @@ def run(ctx: dict) -> dict:
     mesh = make_mesh(devices, data=dp, stage=stages)
     tx = optax.adam(float(config["run"]["learning_rate"]))
 
-    staged, opt_state = init_state(cfg, tx, mesh, stages, ctx["seed"])
+    staged, opt_state = init_state(family, cfg, tx, mesh, stages, ctx["seed"])
     step = make_pipeline_train_step(
         cfg, tx, mesh, M, data_axis="data" if dp > 1 else None,
         schedule=step_spec.get("schedule", "gpipe"),
     )
     batches = traffic.train_batches(
-        step_spec, cfg.vocab_size, batch, cfg.ctx_size, ctx["seed"]
+        step_spec, family.vocab(cfg), batch, seq_len, ctx["seed"]
     )
 
     # correctness, outside the window, in the two warm-up steps: before
@@ -86,27 +91,22 @@ def run(ctx: dict) -> dict:
     # compiles (or reads the cache); the second takes the first's donated
     # outputs, as every later step will.
     n_check = min(block, 2)
+    t_state = time.perf_counter()
     first = next(batches)
     checks, call_s = [], []
     for i in range(2):
         seqs = first[i * n_check:(i + 1) * n_check]
-        ref_loss = float(reference.loss(
-            reference.flat_blocks(staged), jnp.asarray(seqs), num_heads=cfg.num_heads
-        ))
+        ref_loss = family.reference_loss(cfg, staged, jnp.asarray(seqs))
         t0 = time.perf_counter()
         staged, opt_state, loss = step(
             staged, opt_state, jnp.asarray(np.tile(seqs, (batch // n_check, 1)))
         )
         system_loss = float(loss)
         call_s.append(time.perf_counter() - t0)
-        checks.append({
-            "system_loss": system_loss, "reference_loss": ref_loss,
-            "rel": abs(system_loss - ref_loss) / abs(ref_loss),
-        })
+        checks.append(family.check_train_loss(system_loss, ref_loss))
     check = {
-        "ok": all(c["rel"] <= reference.TRAIN_LOSS_RTOL for c in checks),
-        "sequences": 2 * n_check, "rtol": reference.TRAIN_LOSS_RTOL,
-        "steps": checks,
+        "ok": all(c["ok"] for c in checks),
+        "sequences": 2 * n_check, "rtol": checks[0]["rtol"], "steps": checks,
     }
 
     def one_step(tokens):
@@ -137,27 +137,37 @@ def run(ctx: dict) -> dict:
     losses = [float(x) for x in losses]
     finite = [math.isfinite(x) for x in losses]
     tail = losses[-10:] if len(losses) > 10 else losses[-1:]
-    learned = len(losses) < 2 or sum(tail) / len(tail) < losses[0]
+    rise = sum(tail) / len(tail) - losses[0]
+    learned = len(losses) < 2 or rise < 0
     starts = [t_open] + ends[:-1]
+    flash = family.flash_calls(cfg, block)
     return {
         "setup_s": t_open - ctx["t_process"],
         "t_open_host": t_open, "t_close_host": t_close,
         "window_s": t_close - t_open,
         "step_s": [e - s for s, e in zip(starts, ends)],
-        "tokens_per_step": batch * cfg.ctx_size,
-        "tokens": len(ends) * batch * cfg.ctx_size,
+        "tokens_per_step": batch * seq_len,
+        "tokens": len(ends) * batch * seq_len,
         "compile_s": call_s[0],
-        "model": {"dmodel": cfg.dmodel, "ffn_dim": cfg.ffn_dim,
-                  "n_layers": cfg.n_layers, "vocab": cfg.vocab_size,
-                  "ctx": cfg.ctx_size, "heads": cfg.num_heads,
-                  "head_dim": cfg.head_dim},
-        "flash": {"batch_per_call": block, "calls_per_step": M * n_layers * dp,
-                  "used": bool(cfg.use_flash)},
+        # the family's counts: what the ALGORITHM needs, for the readers
+        "flops_per_token": family.train_flops_per_token(cfg),
+        "flash": {"used": use_flash, "calls_per_step": M * dp * flash["calls"],
+                  "forward": flash["forward"], "backward": flash["backward"]},
         "trace_steps": TRACE_STEPS,
         "correct": check["ok"] and all(finite) and learned,
         "attempted": len(losses),
         "failed": finite.count(False),
+        "compared": {
+            **{f"loss_rel_step{i + 1}": {"value": c["rel"], "limit": c["rtol"]}
+               for i, c in enumerate(checks)},
+            "nonfinite_losses": {"value": finite.count(False), "limit": 0},
+            "loss_rise": {"value": rise if len(losses) > 1 else None, "limit": "< 0"},
+        },
         "notes": {"check": check, "steps": len(losses),
+                  # where set-up went: imports and device init, state and
+                  # step builder, the two checked warm-up steps
+                  "setup_parts_s": [t_run - ctx["t_process"], t_state - t_run,
+                                    t_open - t_state],
                   "first_call_s": call_s[0], "second_call_s": call_s[1],
                   "first_loss": losses[0], "last_loss": losses[-1],
                   "learned": learned, "mesh": {"data": dp, "stage": stages},

@@ -31,7 +31,7 @@ def bench_dir(tmp_path):
     """A copy of the benchmark's data directories with the tiny test
     configuration and cells ADDED as files: what a later PR does."""
     root = tmp_path / "benchmark"
-    for sub in ("metrics", "readers", "runners", "configs", "workloads"):
+    for sub in ("metrics", "readers", "runners", "families", "configs", "workloads"):
         shutil.copytree(os.path.join(BENCH, sub), root / sub)
     shutil.copy(os.path.join(BENCH, "peaks.json"), root / "peaks.json")
     for sub in ("configs", "workloads"):

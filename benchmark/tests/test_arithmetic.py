@@ -8,20 +8,33 @@ from benchmark import flops, stats, traffic
 from benchmark.run import load_module, BENCH_DIR
 
 
+LLAMA = load_module(BENCH_DIR, "families", "llama")
+
+
 def test_train_flops_of_the_15m_config_by_hand():
+    from ddl25spring_tpu.utils.config import LlamaConfig
+
     # LlamaConfig(): dmodel 288, ffn 1152, 6 layers, vocab 4096, ctx 256
     # a layer: 4 * 288^2 = 331,776 and 3 * 288 * 1152 = 995,328 -> 1,327,104
     # six layers 7,962,624; unembed 288 * 4096 = 1,179,648; together 9,142,272
-    assert flops.matmul_params(288, 1152, 6, 4096) == 9_142_272
+    assert LLAMA.matmul_params(288, 1152, 6, 4096) == 9_142_272
     # 6 x that = 54,853,632; attention 6 * 6 * 256 * 288 = 2,654,208
-    assert flops.train_flops_per_token(288, 1152, 6, 4096, 256) == 57_507_840
+    assert LLAMA.flops_per_token(288, 1152, 6, 4096, 256) == 57_507_840
+    # and as the train runner asks for it: from the configuration object
+    assert LLAMA.train_flops_per_token(LlamaConfig()) == 57_507_840
 
 
 def test_flash_flops_and_bytes_by_hand():
+    from ddl25spring_tpu.utils.config import LlamaConfig
+
     # one head, ctx 4, head_dim 2: QK^T and PV are 2*4*4*2 = 64 each, 128
     # together, halved by the mask: 64; q, k, v, o of 8 bf16 elements: 64 B
-    assert flops.flash_flops_bytes(1, 4, 1, 2, backward=False) == (64.0, 64.0)
-    assert flops.flash_flops_bytes(1, 4, 1, 2, backward=True) == (160.0, 128.0)
+    assert LLAMA.flash_flops_bytes(1, 4, 1, 2, backward=False) == (64.0, 64.0)
+    assert LLAMA.flash_flops_bytes(1, 4, 1, 2, backward=True) == (160.0, 128.0)
+    # as the train runner asks for it: one call a layer, both directions
+    cfg = LlamaConfig(dmodel=2, num_heads=1, n_layers=3, ctx_size=4)
+    assert LLAMA.flash_calls(cfg, 1) == {
+        "calls": 3, "forward": (64.0, 64.0), "backward": (160.0, 128.0)}
     peaks = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
     assert flops.roofline_seconds(64.0, 64.0, peaks) == (6.4, "memory")
     assert flops.roofline_seconds(6400.0, 64.0, peaks) == (64.0, "compute")

@@ -2,6 +2,7 @@
 their Python entry, cells and metrics added as files only, and the command
 refusing to run without a TPU."""
 
+import hashlib
 import json
 import os
 import subprocess
@@ -43,16 +44,18 @@ def test_training_state_is_placed_by_stage_not_replicated():
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from benchmark import model
     from ddl25spring_tpu.utils.mesh import make_mesh
 
+    bench = os.path.join(ROOT, "benchmark")
     config = run.load_json(os.path.join(HERE, "data", "configs", "tiny-test.json"))
-    dp, stages, n_layers = model.train_placement(config, 4)
+    train = run.load_module(bench, "runners", "train")
+    family = run.load_family(bench, config)
+    dp, stages, n_layers = train.placement(config, 4)
     assert (dp, stages, n_layers) == (2, 2, 2)
-    cfg = model.llama_config(config, n_layers=n_layers)
+    cfg = family.build(config, n_layers=n_layers)
     mesh = make_mesh(jax.devices()[:4], data=dp, stage=stages)
-    train = run.load_module(os.path.join(ROOT, "benchmark"), "runners", "train")
-    staged, opt_state = train.init_state(cfg, optax.adam(1e-3), mesh, stages, 2**31 + 5)
+    staged, opt_state = train.init_state(
+        family, cfg, optax.adam(1e-3), mesh, stages, 2**31 + 5)
     for tree in (staged, opt_state[0].mu, opt_state[0].nu):
         wq = tree["blocks"]["wq"]
         assert wq.sharding.spec == P("stage") and wq.shape[0] == stages
@@ -99,6 +102,102 @@ def test_a_metric_and_its_reader_are_picked_up_as_new_files(bench_dir):
     # its "workloads" list keeps it out of every other cell
     specs = run.metric_specs(bench_dir, "train", "tiny-dppp", "per_layer")
     assert "steps_x2.train" not in [s["name"] for s in specs]
+
+
+OTHER_CONFIG = {  # the second family's keys are its own; "run" and "placement" are the runner's
+    "name": "tiny-other", "source": "none: a toy used only by benchmark/tests on the CPU",
+    "family": "tinydec", "width": 32, "heads": 2, "depth": 2, "tokens": 96, "context": 32,
+    "run": {"layers_per_stage": 2, "learning_rate": 8e-4, "use_flash": False},
+    "placement": {"1": {"data": 1, "stage": 1}},
+}
+OTHER_CELLS = {
+    "serve": {"engine": {"max_slots": 4, "prefill_batch": 2, "max_prompt_len": 16,
+                         "page_len": 4, "pages_per_seq": 8, "n_pages": 40, "max_queue": 16},
+              "traffic": {"loop": "closed", "clients": 4, "pool_size": 16, "pool_seed": 0,
+                          "prompt_len": {"kind": "lognormal", "median": 8, "sigma": 0.5,
+                                         "min": 2, "max": 16},
+                          "max_new": {"kind": "fixed", "value": 6},
+                          "tokens": {"kind": "uniform"}}},
+    "train": {"traffic": {"sequences_per_step": 8, "microbatches": 4, "schedule": "gpipe",
+                          "tokens": {"kind": "zipf", "a": 1.1}}},
+}
+FAKE_PEAKS = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9}  # so the peak readers speak
+
+
+def tree_digest(root):
+    out = {}
+    for d, _, files in os.walk(root):
+        for f in files:
+            with open(os.path.join(d, f), "rb") as fh:
+                out[os.path.join(d, f)] = hashlib.sha256(fh.read()).hexdigest()
+    return out
+
+
+def add_family(bench_dir, family, drop_layers=()):
+    """What a PR that brings an architecture adds, as files alone: a family
+    file, a configuration that names it, and a cell for each runner."""
+    with open(os.path.join(HERE, "data", "families", "tinydec.py")) as f:
+        source = f.read()
+    marker = "DROP_LAYERS: tuple = ()"
+    assert marker in source
+    with open(os.path.join(bench_dir, "families", f"{family}.py"), "x") as f:
+        f.write(source.replace(marker, f"DROP_LAYERS: tuple = {tuple(drop_layers)!r}"))
+    with open(os.path.join(bench_dir, "configs", f"{family}-cfg.json"), "x") as f:
+        json.dump(dict(OTHER_CONFIG, name=f"{family}-cfg", family=family), f)
+    for runner, body in OTHER_CELLS.items():
+        with open(os.path.join(bench_dir, "workloads", f"{family}-{runner}.json"), "x") as f:
+            json.dump({"name": f"{family}-{runner}", "config": f"{family}-cfg",
+                       "runner": runner, "chips": 1, "why": "test only", **body}, f)
+
+
+def test_a_model_family_is_picked_up_as_new_files(bench_dir):
+    """An architecture lands as a family file, a configuration and cells;
+    the runners ``serve`` and ``train`` run it, hold it to ITS reference and
+    report the benchmark's metrics under their own names."""
+    before = tree_digest(bench_dir)
+    add_family(bench_dir, "tinydec")
+    kw = dict(bench_dir=bench_dir, allow_cpu=True)
+    served = run.run_cell("tinydec-serve", 2**31 + 9, 0.7, False, **kw)
+    check_line(served, {"setup_s", "serve_tokens_s_chip", "ttft_p95_ms", "tpot_p95_ms"})
+    assert served["compared"]["worst_margin"]["limit"] == 1e-3  # the family's own
+    assert 0 < served["compared"]["tokens_checked"]["value"]
+    trained = run.run_cell("tinydec-train", 2**31 + 9, 0.5, False, **kw)
+    check_line(trained, {"setup_s", "train_tokens_s_chip"})
+    assert trained["compared"]["loss_rel_step1"]["limit"] == 1e-4
+    traced = run.run_cell("tinydec-train", 7, 0.5, True, peaks=FAKE_PEAKS, **kw)
+    check_line(traced, {"mfu_pct.train", "step_ms.train"})
+    # the reader's count is the family's: 6 x (2 x 16 x 32^2 + 32 x 96) + 6 x 2 x 32 x 32
+    rate = 8 * 32 / (traced["metrics"]["step_ms.train"]["value"] / 1e3)
+    assert traced["metrics"]["mfu_pct.train"]["value"] == pytest.approx(
+        100.0 * rate * 227_328 / 1e9, rel=0.5)  # median step against the window's mean
+    after = tree_digest(bench_dir)
+    assert {p: h for p, h in after.items() if p in before} == before  # nothing edited
+
+
+@pytest.mark.parametrize("runner", ["serve", "train"])
+def test_a_family_whose_reference_drops_a_layer_is_not_correct(bench_dir, runner):
+    """The negative control: the same program against a reference that
+    skips a layer it runs."""
+    add_family(bench_dir, "tinydec-dropped", drop_layers=(1,))
+    r = run.run_cell(f"tinydec-dropped-{runner}", 5, 0.5, False,
+                     bench_dir=bench_dir, allow_cpu=True)
+    assert r["correct"] is False and r["failed"] == 0, r["compared"]
+    first = next(iter(r["compared"].values()))
+    assert first["value"] > first["limit"]
+
+
+def test_a_configuration_names_its_family_or_does_not_run(bench_dir):
+    add_family(bench_dir, "tinydec")
+    cfg_path = os.path.join(bench_dir, "configs", "tinydec-cfg.json")
+    config = run.load_json(cfg_path)
+    with open(cfg_path, "w") as f:
+        json.dump({k: v for k, v in config.items() if k != "family"}, f)
+    with pytest.raises(KeyError, match="names no model family"):
+        run.run_cell("tinydec-serve", 1, 0.3, False, bench_dir=bench_dir, allow_cpu=True)
+    with open(cfg_path, "w") as f:
+        json.dump(dict(config, family="not-there"), f)
+    with pytest.raises(FileNotFoundError, match="families/not-there.py"):
+        run.run_cell("tinydec-train", 1, 0.3, False, bench_dir=bench_dir, allow_cpu=True)
 
 
 def test_command_without_a_tpu_exits_nonzero_and_prints_no_result():

@@ -28,8 +28,7 @@ import numpy as np  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding  # noqa: E402
 
-from benchmark import model  # noqa: E402
-from benchmark.run import BENCH_DIR, load_cell  # noqa: E402
+from benchmark.run import BENCH_DIR, load_cell, load_family, load_module  # noqa: E402
 
 GB = 1e9
 
@@ -52,22 +51,20 @@ def with_sharding(tree, shardings):
     )
 
 
-def train(cell, config, topo) -> None:
+def train(cell, config, family, topo) -> None:
     import optax
 
-    from ddl25spring_tpu.models import llama
     from ddl25spring_tpu.parallel.pipeline import (
         make_pipeline_train_step, staged_param_specs,
     )
 
     chips = int(cell["chips"])
-    dp, stages, n_layers = model.train_placement(config, chips)
-    cfg = model.llama_config(config, n_layers=n_layers, use_flash=True)
+    runner = load_module(BENCH_DIR, "runners", "train")
+    dp, stages, n_layers = runner.placement(config, chips)
+    cfg = family.build(config, n_layers=n_layers, use_flash=True)
     mesh = Mesh(np.array(topo.devices[:chips]).reshape(dp, stages), ("data", "stage"))
     tx = optax.adam(config["run"]["learning_rate"])
-    staged = jax.eval_shape(lambda: llama.split_blocks_for_stages(
-        llama.init_llama_params(jax.random.PRNGKey(0), cfg), stages
-    ))
+    staged = jax.eval_shape(lambda: family.init_staged_params(cfg, 0, stages))
     specs = staged_param_specs("stage", None, None, False, n_experts=0)
     shard = {
         k: (jax.tree.map(lambda _: NamedSharding(mesh, specs["blocks"]), staged["blocks"])
@@ -92,7 +89,7 @@ def train(cell, config, topo) -> None:
     )
     t = cell["traffic"]
     tokens = jax.ShapeDtypeStruct(
-        (t["sequences_per_step"], cfg.ctx_size), jnp.int32, sharding=rep
+        (t["sequences_per_step"], family.seq_len(cfg)), jnp.int32, sharding=rep
     )
     step = make_pipeline_train_step(
         cfg, tx, mesh, t["microbatches"], data_axis="data" if dp > 1 else None,
@@ -108,13 +105,14 @@ def train(cell, config, topo) -> None:
         print(f"  {op}: {hlo.count(' ' + op + '(') + hlo.count(' ' + op + '-start(')}")
 
 
-def serve(cell, config, topo) -> None:
-    from ddl25spring_tpu.models import llama
+def serve(cell, config, family, topo) -> None:
     from ddl25spring_tpu.serve import driver, kv_pages
-    from ddl25spring_tpu.serve.engine import make_decode_tick, make_prefill
+    from ddl25spring_tpu.serve.engine import (
+        make_decode_tick, make_prefill, prefill_widths,
+    )
 
     one = SingleDeviceSharding(topo.devices[0])
-    cfg = model.llama_config(config, use_flash=False)
+    cfg = family.build(config, use_flash=False)
     k = {**driver.engine_knobs(), **cell["engine"]}
 
     def abstract(tree):
@@ -122,9 +120,7 @@ def serve(cell, config, topo) -> None:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree
         )
 
-    params = abstract(jax.eval_shape(
-        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    ))
+    params = abstract(jax.eval_shape(lambda: family.init_params(cfg, 0)))
     pool = abstract(jax.eval_shape(lambda: kv_pages.init_page_pool(
         cfg, n_pages=k["n_pages"], page_len=k["page_len"],
         max_slots=k["max_slots"], pages_per_seq=k["pages_per_seq"],
@@ -140,11 +136,12 @@ def serve(cell, config, topo) -> None:
            tick.lower(params, pool, i32(k["max_slots"]), key).compile())
     B, Lp = k["prefill_batch"], k["max_prompt_len"]
     prefill = jax.jit(
-        make_prefill(cfg, max_prompt_len=Lp, start=0, temperature=0.0, sentinel=False),
+        make_prefill(cfg, max_prompt_len=Lp, temperature=0.0, sentinel=False),
         donate_argnums=(1,),
     )
-    report(f"{cell['name']} prefill",
-           prefill.lower(params, pool, i32(B, Lp), i32(B), i32(B), i32(B), key).compile())
+    for W in prefill_widths(Lp):  # one program a width, as the engine warms them
+        report(f"{cell['name']} prefill at width {W}",
+               prefill.lower(params, pool, i32(B, W), i32(B), i32(B), i32(B), key).compile())
 
 
 def main() -> int:
@@ -153,7 +150,9 @@ def main() -> int:
     # the program asks jax.default_backend() whether to use the kernel; a
     # described chip is not attached, so say "tpu" for the lowering only
     jax.default_backend = lambda: "tpu"
-    {"train": train, "serve": serve}[cell["runner"]](cell, config, topo)
+    {"train": train, "serve": serve}[cell["runner"]](
+        cell, config, load_family(BENCH_DIR, config), topo
+    )
     return 0
 
 

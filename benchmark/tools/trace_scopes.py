@@ -10,7 +10,7 @@ part; and, over all chips, the idle gaps by the deepest program or harness
 span that covers them and the host spans' own totals.
 
 Where the names come from.  The program names its parts with
-``jax.named_scope`` (``models/llama.py``, ``parallel/pipeline.py``,
+``jax.named_scope`` (the decoder under ``models/``, ``parallel/pipeline.py``,
 ``serve/engine.py``) and its kernels with ``pallas_call(name=...)``; both
 end up in each HLO instruction's ``op_name`` metadata, e.g.
 ``jit(step)/transpose(jvp())/shard_map/while/body/closed_call/attn/
