@@ -85,14 +85,16 @@ class SpanRecorder:
         )
 
     @contextmanager
-    def span(self, name: str, cat: str = "host", **args: Any) -> Iterator[None]:
+    def span(self, name: str, cat: str = "host", **args: Any) -> Iterator[Any]:
         """Record the block as one complete ("X") event; also annotate the
-        real profiler timeline when one is active."""
+        real profiler timeline when one is active.  Yields the annotation:
+        ``set_metadata(**more)`` adds what is known only inside the block
+        to both (``args`` is the event's own dict until it closes)."""
         tid = threading.get_ident()
         ts = self._now_us()
-        with TraceAnnotation(name, **args):
+        with TraceAnnotation(name, **args) as ann:
             try:
-                yield
+                yield _Late(ann, args)
             finally:
                 dur = self._now_us() - ts
                 with self._lock:
@@ -152,6 +154,20 @@ class SpanRecorder:
         return path
 
 
+class _Late:
+    """Stats added to an open span: into the profiler's annotation, and
+    into the dict the Chrome event is written from."""
+
+    __slots__ = ("_ann", "_args")
+
+    def __init__(self, ann, args: dict[str, Any]) -> None:
+        self._ann, self._args = ann, args
+
+    def set_metadata(self, **stats: Any) -> None:
+        self._ann.set_metadata(**stats)
+        self._args.update(stats)
+
+
 _default = SpanRecorder()
 
 
@@ -170,18 +186,24 @@ class _Span:
     """What :func:`span` returns: annotation + ring sample always, the
     default recorder's Chrome event when telemetry is enabled."""
 
-    __slots__ = ("name", "cat", "stats", "_inner", "_t0")
+    __slots__ = ("name", "cat", "stats", "_inner", "_open", "_t0")
 
     def __init__(self, name: str, cat: str, stats: dict[str, Any]) -> None:
         self.name, self.cat, self.stats = name, cat, stats
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "_Span":
         if state.enabled():
             self._inner = _default.span(self.name, cat=self.cat, **self.stats)
         else:
             self._inner = TraceAnnotation(self.name, **self.stats)
-        self._inner.__enter__()
+        self._open = self._inner.__enter__()
         self._t0 = time.perf_counter()
+        return self
+
+    def add(self, **stats: Any) -> None:
+        """Stats known only inside the block (what a pass read back from
+        the device): they join the ones the span was opened with."""
+        self._open.set_metadata(**stats)
 
     def __exit__(self, *exc) -> None:
         counters.sample(self.name, time.perf_counter() - self._t0, self._t0)

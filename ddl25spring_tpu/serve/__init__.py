@@ -1,9 +1,15 @@
-"""``ddl25spring_tpu.serve`` — the continuous-batching LLaMA decode
-engine (ROADMAP item 3): paged KV cache (:mod:`.kv_pages`),
-prefill/decode-disaggregated scheduler with admission control
-(:mod:`.engine`), and the seeded synthetic open-loop workload
-(:mod:`.traffic`).  Drive it via ``bench.py --serve``; report with
-``tools/serve_report.py``.
+"""``ddl25spring_tpu.serve`` — the continuous-batching decode engine
+(ROADMAP item 3): a page pool of whatever planes the served model
+declares (:mod:`.kv_pages`), the seam through which a model offers its
+paged block (:mod:`.paged_model`), the prefill/decode-disaggregated
+scheduler with admission control (:mod:`.engine`), and the seeded
+synthetic open-loop workload (:mod:`.traffic`).  Two families are served:
+the dense LLaMA block (``models/llama_paged.py``) and the
+Mistral-Small-4 block with latent pages and routed experts
+(``models/mistral4.py``).  Drive it via ``bench.py --serve``; report with
+``tools/serve_report.py``; the benchmark's cells are ``python3
+benchmark/run.py --workload olmo1b-serve-closed32`` and ``--workload
+mistral4-serve-decode64``.
 
 PEP-562 lazy exports (matching :mod:`ddl25spring_tpu.ft`): importing
 the package must not drag jax in — :mod:`.traffic` is numpy-only and

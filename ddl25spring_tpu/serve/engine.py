@@ -48,6 +48,7 @@ from jax import lax
 
 from ddl25spring_tpu.analysis import host_sanitizer as _sanitizer
 from ddl25spring_tpu.models import decode as decode_mod, llama
+from ddl25spring_tpu.models.llama_paged import KV_POOL_HEAD_DIM
 from ddl25spring_tpu.obs import (
     memscope as _memscope,
     sentinels,
@@ -57,6 +58,7 @@ from ddl25spring_tpu.obs import (
 from ddl25spring_tpu.obs.counters import counters as _counters
 from ddl25spring_tpu.obs.timeline import timeline as _timeline
 from ddl25spring_tpu.serve import kv_pages
+from ddl25spring_tpu.serve.paged_model import PagedModel, paged_model
 from ddl25spring_tpu.serve.prefix import Match, PrefixCache
 from ddl25spring_tpu.utils.config import LlamaConfig
 
@@ -75,113 +77,46 @@ REJECT_DRAINING = "draining"  # elastic scale-down: replica admits nothing
 # ------------------------------------------------------ compiled programs
 
 
-def _rope_rows(x, cos, sin):
-    """RoPE where every row has its OWN positions: ``x [B, T, H, hd]``,
-    ``cos/sin [B, T, hd/2]``.  Same arithmetic as
-    :func:`~ddl25spring_tpu.models.llama.apply_rope` (which shares one
-    position vector over the batch), so fp32 values match the dense
-    decode bitwise."""
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    out = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def _rope_at(pos, head_dim: int):
-    """``(cos, sin)``, each ``[B, T, hd/2]``, of absolute positions
-    ``pos [B, T]``."""
-    cos, sin = llama.rope_angles(
-        1, head_dim, pos=pos.reshape(-1).astype(jnp.float32)
-    )
-    return cos.reshape(*pos.shape, -1), sin.reshape(*pos.shape, -1)
-
-
-def _paged_block(p, x, kp, vp, layer, rows, pages, offs, pos, cos, sin,
-                 cfg: LlamaConfig, tp_axis: str | None):
-    """One transformer block on ``T`` positions a row, ``x [B, T, D]`` at
-    absolute positions ``pos [B, T]``, against the PAGE POOL — the paged
-    twin of :func:`ddl25spring_tpu.models.decode._block_decode`, op for
-    op (same einsums, same fp32 softmax, same ``-1e30`` mask fill).
-    ``rows [B, P]`` is the clamped page table of the batch's sequences
-    (any leading run of entries that covers every live position);
-    ``pages``/``offs [B, T]`` are the write coordinates of each position
-    (trash-routed where masked).  All ``T`` keys and values are written
-    first, then the row's page view is gathered, so a query at ``pos``
-    sees what earlier passes left in the pages, this pass's positions up
-    to its own, and nothing later.  The decode tick, the drafter and the
-    verify pass are the ``T = 1`` case; prefill runs a whole prompt
-    batch.  Its parts are scoped ``attn`` / ``page_write`` /
-    ``page_gather`` / ``mlp`` (``jax.named_scope``: names in the
-    operations' metadata, no operation changes)."""
-    dtype = jnp.dtype(cfg.dtype)
-    B, T = x.shape[:2]
-    hd = cfg.head_dim
-
-    with jax.named_scope("attn"):
-        h = llama.rms_norm(x, p["ln1"])
-        q = (h @ p["wq"].astype(dtype)).reshape(B, T, -1, hd)
-        k = (h @ p["wk"].astype(dtype)).reshape(B, T, -1, hd)
-        v = (h @ p["wv"].astype(dtype)).reshape(B, T, -1, hd)
-        q = _rope_rows(q, cos, sin)
-        k = _rope_rows(k, cos, sin)
-
-    with jax.named_scope("page_write"):
-        kp, vp = kv_pages.append_layer_kv(kp, vp, layer, pages, offs, k, v)
-    with jax.named_scope("page_gather"):
-        ks = kp[rows, layer]  # [B, P, page_len, H, hd]
-        vs = vp[rows, layer]
-        P, page_len = ks.shape[1], ks.shape[2]
-        ks = ks.reshape(B, P * page_len, -1, hd)
-        vs = vs.reshape(B, P * page_len, -1, hd)
-
-    with jax.named_scope("attn"):
-        s = jnp.einsum("bqhd,bmhd->bhqm", q, ks).astype(jnp.float32)
-        s = s / jnp.sqrt(jnp.float32(hd))
-        live = jnp.arange(P * page_len)[None, None, :] <= pos[:, :, None]
-        s = jnp.where(live[:, None, :, :], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(dtype)
-        attn = jnp.einsum("bhqm,bmhd->bqhd", probs, vs)
-        attn_out = attn.reshape(B, T, -1) @ p["wo"].astype(dtype)
-        if tp_axis is not None:
-            attn_out = lax.psum(attn_out, tp_axis)
-        x = x + attn_out
-
-    with jax.named_scope("mlp"):
-        h = llama.rms_norm(x, p["ln2"])
-        gate = jax.nn.silu(h @ p["w_gate"].astype(dtype))
-        up = h @ p["w_up"].astype(dtype)
-        ffn_out = (gate * up) @ p["w_down"].astype(dtype)
-        if tp_axis is not None:
-            ffn_out = lax.psum(ffn_out, tp_axis)
-        return x + ffn_out, kp, vp
-
-
-def _block_stack(params, x, kp, vp, rows, pages, offs, pos,
-                 cfg: LlamaConfig, tp_axis: str | None, layer_stack=None):
-    """Every block of the model over ``x [B, T, D]`` at positions
-    ``pos [B, T]`` (see :func:`_paged_block`): the resident-weight layer
-    scan, or ``layer_stack``'s own walk (:func:`make_decode_tick`)."""
-    cos, sin = _rope_at(pos, cfg.head_dim)
-
-    def run_layer(bp, li, x, kp, vp):
-        return _paged_block(
-            bp, x, kp, vp, li, rows, pages, offs, pos, cos, sin, cfg,
-            tp_axis,
-        )
+def _block_stack(model: PagedModel, params, x, planes, rows, pages, offs,
+                 pos, live, tp_axis: str | None, layer_stack=None):
+    """Every block of ``model`` over ``x [B, T, D]`` at positions ``pos
+    [B, T]`` against the page ``planes`` (the seam of
+    :mod:`.paged_model`): the resident-weight layer scan, or
+    ``layer_stack``'s own walk (:func:`make_decode_tick`).  Returns ``(x,
+    planes, aux)``: ``aux`` is what the model's blocks count of the pass,
+    stacked over layers, or ``None`` for a model that counts nothing."""
+    run_layer = model.layers(params, rows, pages, offs, pos, live, tp_axis)
 
     if layer_stack is not None:
-        return layer_stack(params, run_layer, x, kp, vp)
+        return (*layer_stack(params, run_layer, x, planes), None)
 
     def layer(carry, inp):
-        return run_layer(*inp, *carry), None
+        x, planes, aux = run_layer(*inp, *carry)
+        return (x, planes), aux
 
     with jax.named_scope("blocks"):
-        carry, _ = lax.scan(
-            layer, (x, kp, vp),
-            (params["blocks"], jnp.arange(cfg.n_layers)),
+        (x, planes), aux = lax.scan(
+            layer, (x, planes),
+            (params["blocks"], jnp.arange(model.n_layers)),
         )
-    return carry
+    return x, planes, aux
+
+
+def _pack_pass(tokens, logits, aux, logit_probe: int):
+    """What a pass hands the host, as ONE int32 vector, one fetch:
+    ``tokens [n]``; where the engine keeps a probe of the rows they were
+    sampled from (``logit_probe > 0``), that many evenly strided logits of
+    each row ``logits [n, V]`` as float32 bits; where the model counts
+    any, the pass's counts ``aux [L, c]``.  With neither, the tokens come
+    back as they are (the dense programs' outputs are what they were)."""
+    parts = [tokens]
+    if logit_probe:
+        stride = logits.shape[-1] // logit_probe
+        kept = logits[:, : logit_probe * stride : stride].astype(jnp.float32)
+        parts.append(lax.bitcast_convert_type(kept, jnp.int32).reshape(-1))
+    if aux is not None:
+        parts.append(aux.astype(jnp.int32).reshape(-1))
+    return tokens if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def make_decode_tick(
@@ -194,6 +129,7 @@ def make_decode_tick(
     sentinel: bool | None = None,
     strategy: str = "serve-decode",
     layer_stack=None,
+    logit_probe: int = 0,
 ):
     """Build the decode program body: one token for EVERY active slot.
 
@@ -205,24 +141,27 @@ def make_decode_tick(
     gate+policy of the logits sentinel resolve at BUILD time
     (:func:`ddl25spring_tpu.obs.sentinels.resolve`).
 
+    The block, the page planes, ``embed`` and ``unembed`` are the
+    model's (``cfg.paged_model()``, :mod:`.paged_model`); what a model's
+    blocks count of a pass (``aux``) rides BEHIND the tokens in the same
+    int32 vector (:func:`_pack_pass`), so that the host's one fetch of
+    the sampled tokens brings it along; ``logit_probe`` strided logits of
+    every sampled row ride there too (``ServeEngine(logit_probe=)``).
+
     ``layer_stack`` swaps the default resident-weight layer scan for a
     custom walk over the block stack — ``layer_stack(params, run_layer,
-    x, kp, vp) -> (x, kp, vp)`` with ``run_layer(bp, li, x, kp, vp)``
+    x, planes) -> (x, planes)`` with ``run_layer(bp, li, x, planes)``
     one block's paged step.  The ZeRO-3 weight-streaming decode
     (:func:`_stream_layer_stack`) rides this hook; ``None`` keeps the
     original inline scan, byte-identical to every pre-streaming build
     (pinned in tests/test_serve_tp.py)."""
-    if cfg.n_experts > 0:
-        raise NotImplementedError(
-            "serve/ decodes dense-FFN configs only (MoE decode exists in "
-            "models/decode.py; paging it is future work)"
-        )
+    model = paged_model(cfg)
     s_on, s_policy = sentinels.resolve(sentinel)
 
     def tick(params, pool, tokens, key):
         active = pool["active"]
         pos = pool["seq_len"]  # [S] — position this tick writes
-        page_len = pool["k"].shape[2]
+        page_len = kv_pages.page_len_of(pool)
         n_pages = pool["free"].shape[0]
         S = tokens.shape[0]
         slots = jnp.arange(S, dtype=jnp.int32)
@@ -233,13 +172,14 @@ def make_decode_tick(
             pages, offs = kv_pages.write_page_ids(pool, slots, pos, active)
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)  # [S, P]
 
-        x = llama.embed(params, tokens[:, None], cfg)
-        x, kp, vp = _block_stack(
-            params, x, pool["k"], pool["v"], rows, pages[:, None],
-            offs[:, None], pos[:, None], cfg, tp_axis, layer_stack,
+        x = model.embed(params, tokens[:, None])
+        x, planes, aux = _block_stack(
+            model, params, x, kv_pages.planes(pool), rows, pages[:, None],
+            offs[:, None], pos[:, None], active[:, None], tp_axis,
+            layer_stack,
         )
         with jax.named_scope("head"):
-            logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
+            logits = model.unembed(params, x)[:, 0]  # [S, V] fp32
         with jax.named_scope("sample"):
             if temperature == 0.0:
                 new_tok = logits.argmax(-1).astype(jnp.int32)
@@ -247,10 +187,9 @@ def make_decode_tick(
                 new_tok = decode_mod.sample_logits(
                     logits, key, temperature, top_k, top_p
                 )
-        pool = {
-            **pool, "k": kp, "v": vp,
-            "seq_len": jnp.where(active, pos + 1, pos),
-        }
+        pool = kv_pages.with_planes(
+            pool, planes, seq_len=jnp.where(active, pos + 1, pos)
+        )
         # decode-step sentinel: a non-finite logit on any ACTIVE slot is
         # the serving analogue of a NaN loss (inactive slots carry
         # garbage by construction — masked out of the check)
@@ -262,7 +201,7 @@ def make_decode_tick(
             fallback=(new_tok, pool),
             axis=tp_axis, enabled=s_on, policy=s_policy,
         )
-        return pool, new_tok, ok
+        return pool, _pack_pass(new_tok, logits, aux, logit_probe), ok
 
     return tick
 
@@ -284,13 +223,13 @@ def make_prefill(
     cfg: LlamaConfig,
     *,
     max_prompt_len: int,
-    start: int = 0,
     temperature: float = 0.0,
     top_k: int = 0,
     top_p: float = 1.0,
     tp_axis: str | None = None,
     sentinel: bool | None = None,
     strategy: str = "serve-prefill",
+    logit_probe: int = 0,
 ):
     """Build the prefill program body: write a padded prompt batch into
     the pool in ONE pass over all its positions and sample each
@@ -308,27 +247,22 @@ def make_prefill(
     the row writes while that position is below ``lens[b]``.  The width
     ``W`` is the shape's (one compiled program a width: the engine pads
     to :func:`prefill_widths`), so a hit saves work by riding a narrower
-    pass.  All ``B x W`` positions run through :func:`_paged_block` at
-    once — the block the decode tick runs with one position a row —
+    pass.  All ``B x W`` positions run through the model's paged block
+    at once — the block the decode tick runs with one position a row —
     after ONE all-or-nothing page reservation; the logits are taken at
     ``lens - 1`` only.  On exit the target slots are active with
     ``seq_len = lens`` — exactly the state the next decode tick expects.
-
-    ``start`` is accepted as 0 only (the compile rehearsal of the
-    benchmark still passes it)."""
-    if cfg.n_experts > 0:
-        raise NotImplementedError("serve/ decodes dense-FFN configs only")
-    if start != 0:
-        raise ValueError(
-            f"start={start}: start offsets are the rows' `starts` now"
-        )
+    What the model's blocks count of the pass, and the probe of the rows
+    the first tokens were sampled from, follow the first tokens in the
+    same vector (see :func:`make_decode_tick`)."""
+    model = paged_model(cfg)
     s_on, s_policy = sentinels.resolve(sentinel)
 
     def prefill(params, pool, prompts, lens, starts, slot_ids, key):
         B, W = prompts.shape
         n_pages = pool["free"].shape[0]
         n_slots, P = pool["page_table"].shape
-        page_len = pool["k"].shape[2]
+        page_len = kv_pages.page_len_of(pool)
         # table entries a prompt can reach: what is reserved and gathered
         E = min(P, -(-max_prompt_len // page_len))
         valid_row = slot_ids >= 0
@@ -354,15 +288,15 @@ def make_prefill(
             0, n_pages - 1,
         )  # [B, E]
 
-        x = llama.embed(params, prompts, cfg)
-        x, kp, vp = _block_stack(
-            params, x, pool["k"], pool["v"], rows, pages, offs, pos, cfg,
-            tp_axis,
+        x = model.embed(params, prompts)
+        x, planes, aux = _block_stack(
+            model, params, x, kv_pages.planes(pool), rows, pages, offs,
+            pos, writing, tp_axis,
         )
         with jax.named_scope("head"):
             last = jnp.clip(lens - 1 - starts, 0, W - 1)
             x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)
-            last_logits = llama.unembed(params, x_last, cfg)[:, 0]
+            last_logits = model.unembed(params, x_last)[:, 0]
         with jax.named_scope("sample"):
             if temperature == 0.0:
                 first = last_logits.argmax(-1).astype(jnp.int32)
@@ -371,10 +305,10 @@ def make_prefill(
                     last_logits, key, temperature, top_k, top_p
                 )
         sent = jnp.where(valid_row, slot_ids, n_slots)
-        pool = {
-            **pool, "k": kp, "v": vp,
-            "seq_len": pool["seq_len"].at[sent].set(lens, mode="drop"),
-        }
+        pool = kv_pages.with_planes(
+            pool, planes,
+            seq_len=pool["seq_len"].at[sent].set(lens, mode="drop"),
+        )
         first, pool = sentinels.guard(
             strategy, (first, pool),
             loss=jnp.max(jnp.where(valid_row, jnp.max(
@@ -384,7 +318,7 @@ def make_prefill(
             fallback=(first, pool),
             axis=tp_axis, enabled=s_on, policy=s_policy,
         )
-        return pool, first, ok
+        return pool, _pack_pass(first, last_logits, aux, logit_probe), ok
 
     return prefill
 
@@ -422,15 +356,16 @@ _PROGRAM_CACHE: dict[tuple, tuple] = {}
 
 def _compiled_programs(
     cfg: LlamaConfig, *, max_prompt_len: int, temperature: float,
-    sentinel: bool | None, donate: bool,
+    sentinel: bool | None, donate: bool, logit_probe: int = 0,
 ):
     key = (
         cfg, max_prompt_len, temperature, sentinels.resolve(sentinel),
-        donate,
+        donate, logit_probe,
     )
     if key not in _PROGRAM_CACHE:
         tick = make_decode_tick(
-            cfg, temperature=temperature, sentinel=sentinel
+            cfg, temperature=temperature, sentinel=sentinel,
+            logit_probe=logit_probe,
         )
         # tick/prefill donate their POOL argument (position 1).  release
         # deliberately does NOT donate: aliasing the pool through the
@@ -447,6 +382,7 @@ def _compiled_programs(
             jax.jit(make_prefill(
                 cfg, max_prompt_len=max_prompt_len,
                 temperature=temperature, sentinel=sentinel,
+                logit_probe=logit_probe,
             ), **pool_kw),
             jax.jit(_release),
         )
@@ -502,19 +438,29 @@ def _spec_programs(
 # prefetch), so per-chip param residency is blocks/n + one layer.
 
 
-def _tp_pool_specs(model_axis: str = "model"):
-    """PartitionSpecs for every pool buffer: k/v split exactly
-    :data:`KV_POOL_HEAD_DIM`, all accounting state replicated (the
-    sharing ops stay layout-oblivious — pinned in tests)."""
+def _tp_pool_specs(cfg, model_axis: str = "model"):
+    """PartitionSpecs for every pool buffer of ``cfg``'s model: each
+    plane split on the pool axis its model names (``tp_shard``; the dense
+    block's heads, :data:`KV_POOL_HEAD_DIM`), all accounting state
+    replicated (the sharing ops stay layout-oblivious — pinned in
+    tests)."""
     from jax.sharding import PartitionSpec as P
 
-    kv = P(*(
-        model_axis if d == KV_POOL_HEAD_DIM else None for d in range(5)
-    ))
+    model = paged_model(cfg)
+    if model.tp_shard is None:
+        raise ValueError(
+            f"{type(cfg).__name__}'s paged model offers no tensor-parallel "
+            "pool layout (tp_shard is None): serve it at tp=1"
+        )
     return {
-        "k": kv, "v": kv,
-        "page_table": P(), "seq_len": P(), "active": P(),
-        "free": P(), "refcount": P(),
+        **{
+            name: P(*(
+                model_axis if d == model.tp_shard[name] else None
+                for d in range(3 + len(shape))
+            ))
+            for name, shape in model.planes.items()
+        },
+        **{name: P() for name in kv_pages.ACCOUNTING},
     }
 
 
@@ -577,7 +523,7 @@ def _stream_layer_stack(cfg: LlamaConfig, model_axis: str, n: int):
     plan = zero.stream_block_plan(template["blocks"], n)
     L = cfg.n_layers
 
-    def layer_stack(params, run_layer, x, kp, vp):
+    def layer_stack(params, run_layer, x, planes):
         bufs = zero.stream_layer_bufs(plan, params["blocks"], L)
 
         def gather(i):
@@ -590,28 +536,28 @@ def _stream_layer_stack(cfg: LlamaConfig, model_axis: str, n: int):
         cur = gather(0)
         if L > 1:
             def body(carry, i):
-                x, kp, vp, cur = carry
+                x, planes, cur = carry
                 # issue layer i+1's gather BEFORE layer i's compute
                 nxt = gather(i + 1)
-                x, kp, vp = run_layer(
-                    _tp_slice_block(cur, model_axis, n), i, x, kp, vp
+                x, planes, _aux = run_layer(
+                    _tp_slice_block(cur, model_axis, n), i, x, planes
                 )
-                return (x, kp, vp, nxt), None
+                return (x, planes, nxt), None
 
-            (x, kp, vp, cur), _ = lax.scan(
-                body, (x, kp, vp, cur), jnp.arange(L - 1)
+            (x, planes, cur), _ = lax.scan(
+                body, (x, planes, cur), jnp.arange(L - 1)
             )
         # the last layer is peeled: nothing left to prefetch
-        x, kp, vp = run_layer(
+        x, planes, _aux = run_layer(
             _tp_slice_block(cur, model_axis, n),
-            jnp.int32(L - 1), x, kp, vp,
+            jnp.int32(L - 1), x, planes,
         )
-        return x, kp, vp
+        return x, planes
 
     return layer_stack, plan
 
 
-def _tp_jit(body, mesh, *, model_axis: str, n_extra: int, p_specs,
+def _tp_jit(body, mesh, cfg, *, model_axis: str, n_extra: int, p_specs,
             donate: bool):
     """shard_map + jit one serve program body under the TP pool/param
     layout: pool k/v enter split over ``model_axis`` (so the in-spec
@@ -620,7 +566,7 @@ def _tp_jit(body, mesh, *, model_axis: str, n_extra: int, p_specs,
     donated like the dense programs when asked."""
     from jax.sharding import PartitionSpec as P
 
-    pool_specs = _tp_pool_specs(model_axis)
+    pool_specs = _tp_pool_specs(cfg, model_axis)
     fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, pool_specs) + (P(),) * n_extra,
@@ -696,7 +642,7 @@ def _tp_compiled_programs(
         )
         _TP_PROGRAM_CACHE[key] = (
             _tp_jit(
-                tick_body, mesh, model_axis=model_axis,
+                tick_body, mesh, cfg, model_axis=model_axis,
                 n_extra=2,
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
@@ -707,7 +653,7 @@ def _tp_compiled_programs(
                     temperature=temperature, sentinel=sentinel,
                     weight_stream=weight_stream,
                 ),
-                mesh, model_axis=model_axis, n_extra=5,
+                mesh, cfg, model_axis=model_axis, n_extra=5,
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
             ),
@@ -735,7 +681,7 @@ def _tp_spec_programs(
 
         def build(body, body_cfg, n_extra):
             return _tp_jit(
-                body, mesh, model_axis=model_axis,
+                body, mesh, body_cfg, model_axis=model_axis,
                 n_extra=n_extra,
                 p_specs=_tp_param_specs(body_cfg, model_axis, False),
                 donate=donate,
@@ -771,6 +717,18 @@ def _pct(xs, q):
     return xs[k]
 
 
+class Generated(list):
+    """A request's generated tokens.  Under ``ServeEngine(logit_probe=k)``
+    ``probe[j]`` holds ``k`` evenly strided logits (float32, ids ``0,
+    V // k, 2 V // k, ...``) of the row token ``j`` was sampled from, as
+    the pass that sampled it computed them: what the engine's own
+    compiled programs produced, for whoever holds them to a reference."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.probe: list = []
+
+
 @dataclass
 class Request:
     """One inference request (host side)."""
@@ -789,7 +747,7 @@ class Request:
     prefill_s: float | None = None
     first_token_t: float | None = None
     done_t: float | None = None
-    tokens: list = field(default_factory=list)
+    tokens: Generated = field(default_factory=Generated)
 
     @property
     def prompt_len(self) -> int:
@@ -928,6 +886,7 @@ class ServeEngine:
         tp: int = 1,
         weight_stream: bool = False,
         trace_label: str | None = "serve",
+        logit_probe: int = 0,
     ):
         if admission not in ("continuous", "static"):
             raise ValueError(
@@ -952,7 +911,16 @@ class ServeEngine:
                 "speculative decoding is greedy-only "
                 f"(temperature={temperature} with spec_k={spec_k})"
             )
+        if logit_probe and (spec_k or tp > 1):
+            raise ValueError(
+                "logit_probe is kept by the plain tick and prefill only "
+                f"(spec_k={spec_k}, tp={tp})"
+            )
+        self.logit_probe = logit_probe
         self.cfg = cfg
+        # what the model offers the server: planes, block, embed/unembed
+        # (raises the seam's one error for a model that offers none)
+        self._model = paged_model(cfg)
         self.params = params
         self.page_len = page_len
         self.n_pages = n_pages
@@ -1014,6 +982,7 @@ class ServeEngine:
         if self.tp > 1:
             from ddl25spring_tpu.utils.mesh import make_mesh
 
+            _tp_pool_specs(cfg, self._model_axis)  # refuses a model without
             devs = jax.devices()
             if len(devs) < self.tp:
                 raise ValueError(
@@ -1057,6 +1026,7 @@ class ServeEngine:
             return _compiled_programs(
                 cfg, max_prompt_len=max_prompt_len,
                 temperature=temperature, sentinel=sentinel, donate=donate,
+                logit_probe=logit_probe,
             )
 
         self._tick, self._prefill, self._release = programs(
@@ -1231,14 +1201,16 @@ class ServeEngine:
     # ---- sharding ------------------------------------------------------
 
     def _place_pool(self, pool: dict) -> dict:
-        """Place a freshly-built pool on the engine's mesh (head dim of
-        k/v split over ``model``, accounting replicated) — identity at
-        tp=1, so the single-device path never touches sharding APIs."""
+        """Place a freshly-built pool on the engine's mesh (each plane
+        split as its model says, accounting replicated) — identity at
+        tp=1, so the single-device path never touches sharding APIs.
+        The drafter's pool has the target's planes (an early exit of the
+        same model), so one set of specs places both."""
         if self.mesh is None:
             return pool
         from jax.sharding import NamedSharding
 
-        specs = _tp_pool_specs(self._model_axis)
+        specs = _tp_pool_specs(self.cfg, self._model_axis)
         return {
             k: jax.device_put(v, NamedSharding(self.mesh, specs[k]))
             for k, v in pool.items()
@@ -1270,6 +1242,31 @@ class ServeEngine:
 
     def _sample(self, name: str, value: float, t: float) -> None:
         _counters.sample(name + self._obs_key, value, t)
+
+    def _split_pass(self, span, fetched, n: int, t: float):
+        """Split a pass's fetched vector (:func:`_pack_pass`) into its
+        ``n`` tokens and the probe ``[n, logit_probe]`` of the rows they
+        were sampled from (``None`` where none is kept), which are
+        returned, and what the model's blocks counted of the pass
+        (nothing for a model that counts nothing), which goes through the
+        model's ``pass_stats``: each sampled number into the ring
+        ``serve.<name>`` stamped at the pass's dispatch ``t``, and every
+        number a stat of the pass's span under the name's last part."""
+        k = self.logit_probe
+        tokens, rest = fetched[:n], fetched[n:]
+        probe = rest[: n * k].view(np.float32).reshape(n, k) if k else None
+        counts = rest[n * k:]
+        if counts.size:
+            sampled, stats = self._model.pass_stats(
+                counts.reshape(self._model.n_layers, -1)
+            )
+            for name, value in sampled.items():
+                self._sample(f"serve.{name}", value, t)
+            span.add(**{
+                name.rsplit(".", 1)[-1]: v
+                for name, v in {**stats, **sampled}.items()
+            })
+        return tokens, probe
 
     def _tick_counts(self) -> dict[str, int]:
         """The counts a decode pass starts with, from host state alone
@@ -1699,14 +1696,15 @@ class ServeEngine:
         with self._span(
             "serve.prefill", **counts,
             rids=" ".join(str(req.rid) for _, req, _ in batch),
-        ):
+        ) as span:
             self.pool, first, ok = self._prefill(
                 self.params, self.pool, jnp.asarray(prompts),
                 jnp.asarray(lens), jnp.asarray(starts),
                 jnp.asarray(slot_ids),
                 self._split_key(),
             )
-            first = jax.device_get(first)
+            # one fetch: the sampled tokens and what rides behind them
+            first, probe = self._split_pass(span, jax.device_get(first), B, t0)
         if not bool(ok):
             self.pool_ok_failures += 1
         if self.spec_k:
@@ -1804,7 +1802,8 @@ class ServeEngine:
                     prefill_s=round(prefill_cost, 6),
                     first_decode_s=round(first_decode, 6),
                 )
-                self._emit_token(slot, req, int(first[row]), now)
+                self._emit_token(slot, req, int(first[row]), now,
+                                 None if probe is None else probe[row])
             if self.prefix is not None:
                 self._insert_prefixes(batch)
             self._track_pages()
@@ -1814,8 +1813,10 @@ class ServeEngine:
         )
 
     def _emit_token(self, slot: int, req: Request, tok: int,
-                    now: float) -> None:
+                    now: float, probe=None) -> None:
         req.tokens.append(tok)
+        if probe is not None:
+            req.tokens.probe.append(probe)
         self._slot_last_tok[slot] = tok
         self.generated_tokens += 1
         if (len(req.tokens) >= req.max_new_tokens
@@ -1845,11 +1846,13 @@ class ServeEngine:
         )
         counts = self._tick_counts()
         t0 = time.perf_counter()
-        with self._span("serve.decode_tick", **counts):
+        with self._span("serve.decode_tick", **counts) as span:
             self.pool, new_tok, ok = self._tick(
                 self.params, self.pool, toks, self._split_key()
             )
-            new_tok = jax.device_get(new_tok)
+            new_tok, probe = self._split_pass(
+                span, jax.device_get(new_tok), self.max_slots, t0
+            )
         wall = time.perf_counter() - t0
         if not bool(ok):
             self.pool_ok_failures += 1
@@ -1860,7 +1863,8 @@ class ServeEngine:
         with self._span("serve.emit"):
             for slot, req in enumerate(self.slots):
                 if req is not None:
-                    self._emit_token(slot, req, int(new_tok[slot]), now)
+                    self._emit_token(slot, req, int(new_tok[slot]), now,
+                                     None if probe is None else probe[slot])
             self._track_pages()
         if self._ticks % 8 == 0 or self._ticks <= 2:
             # active and queue: what the tick STARTED with, as its span
@@ -2449,15 +2453,6 @@ class ServeEngine:
 
 # ------------------------------------------------------ registry hook
 
-# The TP page-pool layout contract, as data: the k/v page buffers
-# ``[n_pages+1, L, page_len, H, hd]`` shard exactly ONE dimension — the
-# head dim — over the model axis (each shard caches its local ``H/t``
-# heads).  Prefill writes the pages decode reads, so every compiled
-# serve program must agree on this split; the sharding-flow verifier
-# (analysis/shard_flow.py, rule H013) walks each program pair's
-# entry-parameter shardings against it in `graft_lint --shard-flow`.
-KV_POOL_HEAD_DIM = 3
-
 
 def make_tp_serve_program(
     cfg: LlamaConfig,
@@ -2517,7 +2512,7 @@ def make_tp_serve_program(
     )
     # heads sharded, everything else replicated — the spec keeps the
     # split on KV_POOL_HEAD_DIM of the rank-5 buffer (_tp_pool_specs)
-    pool_specs = _tp_pool_specs(model_axis)
+    pool_specs = _tp_pool_specs(cfg, model_axis)
     pool = {
         k: jax.device_put(v, NamedSharding(mesh, pool_specs[k]))
         for k, v in pool.items()
@@ -2549,7 +2544,7 @@ def make_tp_serve_program(
             )
             n_extra = 2
         fn = _tp_jit(
-            body, mesh, model_axis=model_axis,
+            body, mesh, cfg, model_axis=model_axis,
             n_extra=n_extra, p_specs=p_specs, donate=False,
         )
     return fn, pool, pool_specs

@@ -7,9 +7,14 @@ until the batch drains, which is exactly what kills continuous batching:
 freed capacity never returns to the pool.  This module is the vLLM-style
 alternative, TPU-first (every operation static-shaped under jit):
 
-- **page pool** ``k/v: [n_pages + 1, L, page_len, H, hd]`` — one shared
-  arena of fixed-size pages, all layers of a page row together (one
-  gather per layer serves a sequence's whole context).  The LAST row is
+- **page pool**: one array ``[n_pages + 1, L, page_len, *shape]`` for
+  every PLANE the served model declares (:mod:`.paged_model`: ``k`` and
+  ``v`` of ``(H, hd)`` for the dense block, a latent and a rotary plane
+  for a latent-attention block) — one shared arena of fixed-size pages,
+  all layers of a page row together (one gather per layer serves a
+  sequence's whole context).  A plane is every pool entry that is not
+  one of :data:`ACCOUNTING`; no op here learns what a plane means, and
+  every op that moves page contents walks all of them.  The LAST row is
   a trash page: masked writes (inactive slots, padded prefill rows) land
   there instead of corrupting live pages, so no ``lax.cond`` is ever
   needed on the write path.
@@ -72,42 +77,75 @@ import jax.numpy as jnp
 # shared head-count validation with the dense cache layout — defined in
 # models/ (the layer below) so the dependency points downward only
 from ddl25spring_tpu.models.decode import resolve_heads
-from ddl25spring_tpu.utils.config import LlamaConfig
+from ddl25spring_tpu.serve.paged_model import paged_model
 
 Pool = dict[str, Any]
 
 __all__ = [
     "resolve_heads", "init_page_pool", "pool_geometry", "reserve_pages",
-    "write_page_ids", "append_layer_kv",
+    "write_page_ids", "write_planes", "gather_planes", "planes",
+    "with_planes", "page_len_of", "ACCOUNTING",
     "release_slots", "activate_slots", "used_pages",
     "adopt_prefix", "ref_pages", "unref_pages", "truncate_to",
 ]
 
+# the pool's bookkeeping entries; every other entry is a plane
+ACCOUNTING = ("page_table", "seq_len", "active", "free", "refcount")
+
+
+def planes(pool: Pool) -> Pool:
+    """The page contents of ``pool``: ``{name: [n_pages + 1, L, page_len,
+    ...]}``, whatever the model named them."""
+    return {k: v for k, v in pool.items() if k not in ACCOUNTING}
+
+
+def with_planes(pool: Pool, new_planes: Pool, **accounting) -> Pool:
+    return {**pool, **new_planes, **accounting}
+
+
+def page_len_of(pool: Pool) -> int:
+    """Positions a page holds (a static shape fact of any plane)."""
+    return next(iter(planes(pool).values())).shape[2]
+
 
 def init_page_pool(
-    cfg: LlamaConfig,
+    cfg,
     *,
     n_pages: int,
     page_len: int,
     max_slots: int,
     pages_per_seq: int,
-    num_heads: int | None = None,
+    planes: dict[str, tuple[int, ...]] | None = None,
 ) -> Pool:
-    """Build an empty pool.  ``k``/``v`` carry ``n_pages + 1`` rows —
-    row ``n_pages`` is the trash page masked writes target; it is never
-    entered into a page table and never counted as capacity."""
+    """Build an empty pool for the model ``cfg`` offers (or for explicit
+    ``planes``: ``{name: trailing shape of one position}``, with ``cfg``
+    giving ``n_layers`` and ``dtype``).  Every plane carries ``n_pages +
+    1`` rows — row ``n_pages`` is the trash page masked writes target; it
+    is never entered into a page table and never counted as capacity."""
     if n_pages < 1 or page_len < 1 or max_slots < 1 or pages_per_seq < 1:
         raise ValueError(
             f"n_pages={n_pages}, page_len={page_len}, "
             f"max_slots={max_slots}, pages_per_seq={pages_per_seq}: "
             "every pool dimension must be >= 1"
         )
-    heads = resolve_heads(cfg, num_heads)
-    shape = (n_pages + 1, cfg.n_layers, page_len, heads, cfg.head_dim)
-    dtype = jnp.dtype(cfg.dtype)
+    if planes is None:
+        model = paged_model(cfg)
+        planes, n_layers, dtype = model.planes, model.n_layers, model.dtype
+    else:
+        n_layers, dtype = cfg.n_layers, cfg.dtype
+    clash = sorted(set(planes) & set(ACCOUNTING))
+    if not planes or clash:
+        raise ValueError(
+            f"planes={dict(planes)}: a pool needs at least one plane, and "
+            f"none named like its accounting {ACCOUNTING}"
+        )
     return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
+        **{
+            name: jnp.zeros(
+                (n_pages + 1, n_layers, page_len, *shape), jnp.dtype(dtype)
+            )
+            for name, shape in planes.items()
+        },
         "page_table": jnp.full((max_slots, pages_per_seq), -1, jnp.int32),
         "seq_len": jnp.zeros((max_slots,), jnp.int32),
         "active": jnp.zeros((max_slots,), bool),
@@ -123,7 +161,7 @@ def pool_geometry(pool: Pool) -> dict[str, int]:
     """Static shape facts host code sizes its accounting from."""
     n_pages = int(pool["free"].shape[0])
     max_slots, pages_per_seq = (int(d) for d in pool["page_table"].shape)
-    page_len = int(pool["k"].shape[2])
+    page_len = int(page_len_of(pool))
     return {
         "n_pages": n_pages,
         "page_len": page_len,
@@ -157,7 +195,7 @@ def reserve_pages(pool: Pool, slots: jax.Array, pos: jax.Array,
     free = pool["free"]
     n_pages = free.shape[0]
     P = pool["page_table"].shape[1]
-    page_len = pool["k"].shape[2]
+    page_len = page_len_of(pool)
 
     need = need.astype(bool)
     # free page ids first, ascending (stable argsort over the negated
@@ -197,7 +235,7 @@ def write_page_ids(pool: Pool, slots: jax.Array, pos: jax.Array,
     position, position past the table) are routed to the trash page."""
     n_pages = pool["free"].shape[0]
     P = pool["page_table"].shape[1]
-    page_len = pool["k"].shape[2]
+    page_len = page_len_of(pool)
     entry = pos // page_len
     rows = jnp.clip(slots, 0, pool["page_table"].shape[0] - 1)
     pages = pool["page_table"][rows, jnp.clip(entry, 0, P - 1)]
@@ -205,15 +243,28 @@ def write_page_ids(pool: Pool, slots: jax.Array, pos: jax.Array,
     return jnp.where(good, pages, n_pages), pos % page_len
 
 
-def append_layer_kv(k_pages, v_pages, layer, pages, offs, k, v):
-    """Scatter one layer's k/v ``[B, T, H, hd]`` into the pool at
-    ``(pages[b, t], layer, offs[b, t])``.  Trash-routed positions may
+def write_planes(planes: Pool, layer, pages, offs, values: Pool) -> Pool:
+    """Scatter one layer's ``values[name] [B, T, ...]`` into every plane
+    at ``(pages[b, t], layer, offs[b, t])``.  Trash-routed positions may
     collide; the trash page is never read, so the nondeterministic
     overwrite order there is irrelevant."""
-    return (
-        k_pages.at[pages, layer, offs].set(k),
-        v_pages.at[pages, layer, offs].set(v),
-    )
+    return {
+        name: plane.at[pages, layer, offs].set(values[name])
+        for name, plane in planes.items()
+    }
+
+
+def gather_planes(planes: Pool, layer, rows) -> Pool:
+    """One layer's page view of the sequences whose clamped table rows
+    are ``rows [B, P]``: ``{name: [B, P * page_len, ...]}``, position
+    ``p`` of a sequence at row ``p`` of its view."""
+    out = {}
+    for name, plane in planes.items():
+        view = plane[rows, layer]  # [B, P, page_len, ...]
+        out[name] = view.reshape(
+            view.shape[0], view.shape[1] * view.shape[2], *view.shape[3:]
+        )
+    return out
 
 
 def release_slots(pool: Pool, slot_mask: jax.Array) -> Pool:
@@ -253,7 +304,7 @@ def adopt_prefix(pool: Pool, slots: jax.Array, adopt_pages: jax.Array,
       is read-only by construction),
     - ``cow_src[b] >= 0`` — the matched prefix ends inside this
       partially-filled page: allocate a fresh first-fit page, copy the
-      source page's k/v rows bit for bit, and seat the COPY at the
+      source page's rows of every plane bit for bit, and seat the COPY at the
       row's next table entry (= its count of adopted entries).  The
       adopter's suffix appends land in the copy; the shared original is
       never written.  Two rows COWing the same source each get their
@@ -299,14 +350,17 @@ def adopt_prefix(pool: Pool, slots: jax.Array, adopt_pages: jax.Array,
         jnp.clip(cow_entry, 0, P - 1),
     ].set(fresh, mode="drop")
 
-    # bit-for-bit page copy; masked rows read/write the trash row
+    # bit-for-bit page copy of every plane; masked rows read/write the
+    # trash row
     src = jnp.where(take, cow_src, n_pages)
     dst = jnp.where(take, fresh, n_pages)
-    k = pool["k"].at[dst].set(pool["k"][src], mode="drop")
-    v = pool["v"].at[dst].set(pool["v"][src], mode="drop")
+    copied = {
+        name: plane.at[dst].set(plane[src], mode="drop")
+        for name, plane in planes(pool).items()
+    }
 
     return {
-        **pool, "k": k, "v": v, "free": refcount == 0,
+        **pool, **copied, "free": refcount == 0,
         "refcount": refcount, "page_table": table,
     }, ok
 
@@ -323,7 +377,7 @@ def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array) -> Pool:
     count 0 (a shared page survives, exactly like :func:`release_slots`)
     — and ``seq_len`` clamps to ``min(seq_len, new_len)``.  The page
     holding the frontier is KEPT even when partially rolled back: its
-    tail positions hold stale k/v values, which is safe because every
+    tail positions hold stale values, which is safe because every
     read masks ``position <= pos`` and the write frontier is monotone —
     a stale slot is overwritten (same step it next becomes readable)
     before any attention can gather it.  Masked scatters with the usual
@@ -335,7 +389,7 @@ def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array) -> Pool:
     drafter has nothing to drop)."""
     n_pages = pool["free"].shape[0]
     P = pool["page_table"].shape[1]
-    page_len = pool["k"].shape[2]
+    page_len = page_len_of(pool)
     mask = mask.astype(bool)
     new_lens = jnp.maximum(new_lens, 0)
 

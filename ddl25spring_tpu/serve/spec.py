@@ -39,7 +39,7 @@ The pieces:
 - **verify program** (:func:`make_verify`) — the target model scores
   all ``k+1`` positions (the committed last token + the ``k`` drafts)
   in one program: a width-``(k+1)`` prefill-shaped scan over the paged
-  KV (same ``_paged_block`` body as the decode tick, so fp32 logits are
+  KV (the model's paged block, as the decode tick runs it, so fp32 logits are
   bitwise those of ``k+1`` sequential ticks), writing KV optimistically
   and masking writes past each row's admission limit so the page
   accounting never exceeds the non-speculative worst case.
@@ -73,6 +73,7 @@ from jax import lax
 from ddl25spring_tpu.models import llama
 from ddl25spring_tpu.obs import sentinels
 from ddl25spring_tpu.serve import kv_pages
+from ddl25spring_tpu.serve.paged_model import paged_model
 from ddl25spring_tpu.utils.config import LlamaConfig, replace
 
 Params = dict[str, Any]
@@ -188,8 +189,10 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
     three-way copy to hand-maintain."""
     from ddl25spring_tpu.serve.engine import _block_stack
 
+    model = paged_model(cfg)
+
     def step(params, pool, tok, pos, writing, active):
-        page_len = pool["k"].shape[2]
+        page_len = kv_pages.page_len_of(pool)
         n_pages = pool["free"].shape[0]
         S = pos.shape[0]
         slots = jnp.arange(S, dtype=jnp.int32)
@@ -198,18 +201,18 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
         pages, offs = kv_pages.write_page_ids(pool, slots, pos, writing)
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)
 
-        x = llama.embed(params, tok[:, None], cfg)
-        x, kp, vp = _block_stack(
-            params, x, pool["k"], pool["v"], rows, pages[:, None],
-            offs[:, None], pos[:, None], cfg, tp_axis,
+        x = model.embed(params, tok[:, None])
+        x, planes, _aux = _block_stack(
+            model, params, x, kv_pages.planes(pool), rows, pages[:, None],
+            offs[:, None], pos[:, None], writing[:, None], tp_axis,
         )
         with jax.named_scope("head"):
-            logits = llama.unembed(params, x, cfg)[:, 0]  # [S, V] fp32
+            logits = model.unembed(params, x)[:, 0]  # [S, V] fp32
         with jax.named_scope("sample"):
             g = logits.argmax(-1).astype(jnp.int32)
         absmax = jnp.max(jnp.where(active, jnp.max(
             jnp.abs(logits), axis=-1), 0.0))
-        return {**pool, "k": kp, "v": vp}, g, absmax, ok
+        return kv_pages.with_planes(pool, planes), g, absmax, ok
 
     return step
 
@@ -261,8 +264,6 @@ def make_draft(
         # steps = k serves n_ctx <= 1 rounds; steps = k + 1 is the
         # 2-token catch-up variant — anything else mis-windows drafts
         raise ValueError(f"steps={steps} must be k={k} or k+1")
-    if cfg.n_experts > 0:
-        raise NotImplementedError("serve/ decodes dense-FFN configs only")
     s_on, s_policy = sentinels.resolve(sentinel)
     step = _position_step(cfg, tp_axis)
 
@@ -345,8 +346,6 @@ def make_verify(
     invisible to every later logit."""
     if k < 1:
         raise ValueError(f"k={k} draft tokens must be >= 1")
-    if cfg.n_experts > 0:
-        raise NotImplementedError("serve/ decodes dense-FFN configs only")
     s_on, s_policy = sentinels.resolve(sentinel)
     step = _position_step(cfg, tp_axis)
 

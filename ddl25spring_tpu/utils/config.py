@@ -37,6 +37,13 @@ class LlamaConfig:
     def ffn_dim(self) -> int:
         return 4 * self.dmodel
 
+    def paged_model(self):
+        """What this model offers the paged server
+        (``serve/paged_model.py``): the dense block's, or ``None``."""
+        from ddl25spring_tpu.models.llama_paged import paged_model
+
+        return paged_model(self)
+
 
 @dataclass(frozen=True)
 class PipelineConfig:
