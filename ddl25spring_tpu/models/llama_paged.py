@@ -4,6 +4,14 @@ the paged server (:mod:`ddl25spring_tpu.serve.paged_model`).
 Two planes a position a layer, ``k`` and ``v`` of ``(heads, head_dim)``;
 one block for any ``T`` (a decode tick is ``T = 1``, a prompt batch ``T =
 W``); a tensor-parallel build splits both planes over their head axis.
+
+The block casts every matrix to ``cfg.dtype`` where it uses it
+(``.astype(dtype)``, as the trainer's ``llama.embed`` / ``unembed`` do on
+float32 masters) and multiplies the norm scales in float32.  A server
+never trains, so :func:`resident` rounds the matrices ONCE, when the
+engine is built: the casts at use are then no-ops that the compiler drops,
+every matmul and the embedding gather see the same bits as before, and no
+pass reads four bytes a parameter or converts a whole layer stack.
 """
 
 from __future__ import annotations
@@ -25,6 +33,10 @@ from ddl25spring_tpu.utils.config import LlamaConfig
 # H013) walks each program pair's entry-parameter shardings against it in
 # `graft_lint --shard-flow`.
 KV_POOL_HEAD_DIM = 3
+
+# the block's leaves that :func:`paged_block` casts to ``cfg.dtype`` at
+# their use; ``ln1`` / ``ln2`` are multiplied in float32 (``rms_norm``)
+BLOCK_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
 def _rope_rows(x, cos, sin):
@@ -109,6 +121,28 @@ def paged_block(p, x, planes, layer, rows, pages, offs, pos, cos, sin,
         return x + ffn_out, planes
 
 
+def resident(params, cfg: LlamaConfig):
+    """``params`` as the serving programs read them: ``embed``,
+    ``unembed`` and the block matrices in ``cfg.dtype``, the norm scales
+    (``ln1``, ``ln2``, ``ln_f``) as they are.  One leaf at a time (the
+    transient is one leaf); a leaf already in the type is returned
+    itself, so a resident tree comes back as the same arrays."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def cast(x):
+        return x if x.dtype == dtype else x.astype(dtype)
+
+    return {
+        **params,
+        "embed": cast(params["embed"]),
+        "unembed": cast(params["unembed"]),
+        "blocks": {
+            name: cast(w) if name in BLOCK_MATRICES else w
+            for name, w in params["blocks"].items()
+        },
+    }
+
+
 def paged_model(cfg: LlamaConfig) -> PagedModel | None:
     """``cfg``'s offer to the paged server; ``None`` for the switch-MoE
     FFN (``n_experts > 0``), whose capacity buckets drop tokens and which
@@ -138,4 +172,5 @@ def paged_model(cfg: LlamaConfig) -> PagedModel | None:
         unembed=lambda params, x: llama.unembed(params, x, cfg),
         layers=layers,
         tp_shard={"k": KV_POOL_HEAD_DIM, "v": KV_POOL_HEAD_DIM},
+        resident=lambda params: resident(params, cfg),
     )

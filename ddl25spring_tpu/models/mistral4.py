@@ -49,9 +49,9 @@ block's ``aux``: the held experts' load, a layer (kept by layer, because
 the sum over layers hides how many experts one call reads), beside the
 count of live positions.
 
-Parameters (in ``cfg.dtype``, resident: nothing is cast at use; whoever
-serves the model brings them, as ``benchmark/families/mistral4.py`` draws
-seeded ones):
+Parameters (in ``cfg.dtype``, resident: nothing is cast at use and the
+seam's ``resident`` stays the identity; whoever serves the model brings
+them, as ``benchmark/families/mistral4.py`` draws seeded ones):
 ``embed [V, D]``; ``blocks`` stacked ``[L, ...]`` and scanned (``ln1 wq_a
 q_norm wq_b wkv_a kv_norm wkv_b wo ln2 router ws_gate ws_up ws_down``);
 ``experts`` = ``w_gate, w_up [L, E, D, F]``, ``w_down [L, E, F, D]``, NOT

@@ -792,6 +792,7 @@ def run_serve_bench(
     from ddl25spring_tpu.obs.logger import git_sha
     from ddl25spring_tpu.obs.perfscope import host_fingerprint
     from ddl25spring_tpu.obs.report import SERVE_BASENAME
+    from ddl25spring_tpu.serve.paged_model import paged_model
     from ddl25spring_tpu.serve.traffic import TrafficSpec, synth_trace
 
     t_start = time.perf_counter()
@@ -834,7 +835,11 @@ def run_serve_bench(
         serve_seed=spec.seed, serve_requests=len(trace),
     )
 
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    # resident once, here: every engine of this run (the ramp's, the A/B
+    # arms', the elastic replicas') then takes the same arrays as they are
+    params = paged_model(cfg).resident(
+        llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    )
 
     # --- ramp phase: wall clock, the measured serving numbers ----------
     eng = _build_engine(

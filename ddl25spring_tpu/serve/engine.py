@@ -464,6 +464,15 @@ def _tp_pool_specs(cfg, model_axis: str = "model"):
     }
 
 
+def _resident_template(cfg: LlamaConfig):
+    """The abstract parameter tree the TP programs are lowered with: the
+    leaves as the engine holds them (``PagedModel.resident``), so that a
+    streamed bucket plan counts the bytes that are streamed."""
+    return jax.eval_shape(lambda: paged_model(cfg).resident(
+        llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    ))
+
+
 def _tp_param_specs(cfg: LlamaConfig, model_axis: str,
                     weight_stream: bool):
     """Entry-param specs for the TP programs: Megatron column/row splits
@@ -475,10 +484,7 @@ def _tp_param_specs(cfg: LlamaConfig, model_axis: str,
         return tp_param_specs(model_axis, False, 0)
     from ddl25spring_tpu.parallel import zero
 
-    template = jax.eval_shape(
-        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    )
-    return zero.stream_param_specs(template, model_axis)
+    return zero.stream_param_specs(_resident_template(cfg), model_axis)
 
 
 def _tp_slice_block(p: dict, model_axis: str, t: int, *,
@@ -517,10 +523,7 @@ def _stream_layer_stack(cfg: LlamaConfig, model_axis: str, n: int):
     count times ``n_layers`` is the program's pinned all-gather count."""
     from ddl25spring_tpu.parallel import zero
 
-    template = jax.eval_shape(
-        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    )
-    plan = zero.stream_block_plan(template["blocks"], n)
+    plan = zero.stream_block_plan(_resident_template(cfg)["blocks"], n)
     L = cfg.n_layers
 
     def layer_stack(params, run_layer, x, planes):
@@ -603,10 +606,7 @@ def _tp_prefill_body(cfg: LlamaConfig, model_axis: str, t: int, *,
     # (transient — dropped at program exit)
     from ddl25spring_tpu.parallel import zero
 
-    template = jax.eval_shape(
-        lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-    )
-    plan = zero.stream_block_plan(template["blocks"], t)
+    plan = zero.stream_block_plan(_resident_template(cfg)["blocks"], t)
 
     def streamed(params, pool, *rest):
         blocks = zero.stream_gather_blocks(
@@ -921,7 +921,6 @@ class ServeEngine:
         # what the model offers the server: planes, block, embed/unembed
         # (raises the seam's one error for a model that offers none)
         self._model = paged_model(cfg)
-        self.params = params
         self.page_len = page_len
         self.n_pages = n_pages
         self.max_slots = max_slots
@@ -954,6 +953,12 @@ class ServeEngine:
             "" if trace_label in (None, "serve") else f"@{trace_label}"
         )
         self._key = jax.random.PRNGKey(seed)
+        # the engine holds the parameters as its programs READ them (the
+        # model says which leaves it casts at each use): rounded once
+        # here, before any placement, and no reference to a master of
+        # another type is kept.  A tree that arrives resident (another
+        # engine's, a model served in its own type) is taken as it is
+        params = self.params = self._make_resident(self._model, params)
 
         # TP-sharded serving (PR 18): tp > 1 runs every compiled
         # program under a 1-D ``model`` mesh — params row-parallel, the
@@ -1057,8 +1062,13 @@ class ServeEngine:
                 raise ValueError(
                     "explicit draft_params need their draft_cfg"
                 )
+            else:
+                draft_params = self._make_resident(
+                    paged_model(draft_cfg), draft_params, "serve.draft_resident"
+                )
             # the drafter derives from (and shards like) the target:
-            # early_exit_drafter slices the UNSHARDED params, then tp>1
+            # early_exit_drafter slices the unsharded RESIDENT params (so
+            # speculation holds no second copy in another type), then tp>1
             # places the result in the same Megatron layout — its pool
             # shards the head dim under the identical H013 contract
             if self.tp > 1:
@@ -2160,6 +2170,47 @@ class ServeEngine:
                 pass
         return int(np.prod(shape)) * jnp.dtype(x.dtype).itemsize
 
+    def _make_resident(self, model: PagedModel, params: Params,
+                       name: str = "serve.resident") -> Params:
+        """``model.resident(params)`` under the span ``name``, whose
+        stats say whether anything was cast: ``leaves_cast`` (0 for a
+        tree that arrives resident) and the bytes before and after."""
+        def nbytes(tree) -> int:
+            return sum(
+                self._leaf_bytes(x, False) for x in jax.tree.leaves(tree)
+            )
+
+        with self._span(name) as span:
+            out = model.resident(params)
+            span.add(
+                leaves_cast=sum(
+                    a is not b for a, b in zip(
+                        jax.tree.leaves(params), jax.tree.leaves(out)
+                    )
+                ),
+                bytes_masters=nbytes(params), bytes_resident=nbytes(out),
+            )
+        return out
+
+    def memory_bill(self, per_chip: bool = True) -> dict[str, Any]:
+        """:meth:`mem_budget_bytes` by part: ``weights`` (the drafter's
+        too under spec) as ``{dtype: bytes}`` of what the engine holds,
+        the resident tree and no master, ``pool`` and ``total``."""
+        weights: dict[str, int] = {}
+        for t in [self.params] + ([self.draft_params] if self.spec_k else []):
+            for x in jax.tree.leaves(t):
+                name = jnp.dtype(x.dtype).name
+                weights[name] = (
+                    weights.get(name, 0) + self._leaf_bytes(x, per_chip)
+                )
+        pool = sum(
+            self._leaf_bytes(x, per_chip)
+            for t in [self.pool] + ([self.draft_pool] if self.spec_k else [])
+            for x in jax.tree.leaves(t)
+        )
+        return {"weights": weights, "pool": pool,
+                "total": sum(weights.values()) + pool}
+
     def mem_budget_bytes(self, per_chip: bool = True) -> int:
         """The engine's static memory bill: params + page pool (+ the
         drafter's params and pool under spec) — exact, from shapes,
@@ -2177,17 +2228,7 @@ class ServeEngine:
         working set is transient, not resident — it shows up in the
         compile-time peak-HBM budget the ``serve-decode-zero3stream``
         describe() pins, not here."""
-        def tree_bytes(t) -> int:
-            return sum(
-                self._leaf_bytes(x, per_chip)
-                for x in jax.tree.leaves(t)
-            )
-
-        total = tree_bytes(self.params) + tree_bytes(self.pool)
-        if self.spec_k:
-            total += tree_bytes(self.draft_params)
-            total += tree_bytes(self.draft_pool)
-        return total
+        return self.memory_bill(per_chip)["total"]
 
     def mem_pool_snapshot(self) -> dict[str, Any]:
         """Device-mask pool telemetry (occupancy, cache-vs-table page
@@ -2322,6 +2363,7 @@ class ServeEngine:
 
         pct = _pct
         wall = self.now()
+        bill = self.memory_bill()
         try:  # the chips the pool actually lives on (1 off-mesh)
             n_chips = max(1, len(self.pool["seq_len"].devices()))
         except Exception:  # noqa: BLE001 — older array APIs
@@ -2378,20 +2420,8 @@ class ServeEngine:
             # obs_report Serving section and --check-tp gates read
             "tp": self.tp,
             "weight_stream": self.weight_stream,
-            "pool_bytes_per_chip": sum(
-                self._leaf_bytes(x, True)
-                for t in ([self.pool] + (
-                    [self.draft_pool] if self.spec_k else []
-                ))
-                for x in jax.tree.leaves(t)
-            ),
-            "param_bytes_per_chip": sum(
-                self._leaf_bytes(x, True)
-                for t in ([self.params] + (
-                    [self.draft_params] if self.spec_k else []
-                ))
-                for x in jax.tree.leaves(t)
-            ),
+            "pool_bytes_per_chip": bill["pool"],
+            "param_bytes_per_chip": sum(bill["weights"].values()),
             # radix prefix cache: the deterministic counters the
             # cached-vs-cold A/B and the serve_report gates read
             "prefix_hit_rate": (
@@ -2596,16 +2626,18 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
     max_prompt_len = 8
     prefill_batch = 2
 
-    raw = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    # as the engine holds them: rounded to cfg.dtype once, then placed
+    raw = paged_model(cfg).resident(
+        llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    )
     n_buckets = 0
     if weight_stream:
         from ddl25spring_tpu.parallel import zero
 
         params = zero.zero_stream_llama_params(raw, mesh, model_axis)
-        template = jax.eval_shape(
-            lambda: llama.init_llama_params(jax.random.PRNGKey(0), cfg)
-        )
-        n_buckets = len(zero.stream_block_plan(template["blocks"], t).buckets)
+        n_buckets = len(zero.stream_block_plan(
+            _resident_template(cfg)["blocks"], t
+        ).buckets)
     else:
         params = shard_tp_params(raw, mesh, model_axis, shard_vocab=False)
     fn, pool, _specs = make_tp_serve_program(

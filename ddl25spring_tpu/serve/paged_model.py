@@ -26,7 +26,16 @@ the page pool (:mod:`.kv_pages`) know a model only through the
   ``serve.<name>``, and added to the pass's span; the second are stats of
   the span only.  ``None`` with ``aux``;
 - ``tp_shard``: ``{plane: pool axis}`` a tensor-parallel build splits over
-  its model axis, or ``None`` for a model that offers no such layout.
+  its model axis, or ``None`` for a model that offers no such layout;
+- ``resident(params) -> params``: the parameters as the programs READ
+  them.  Only the model knows which leaves its block casts at each use and
+  which it multiplies as they are, so it says: the dense block's returns
+  its matrices in ``cfg.dtype`` and its norm scales untouched; a model
+  whose weights arrive in the served type leaves the member at its
+  default, the identity.  Idempotent, and a leaf already in its type comes
+  back as the same array.  The engine calls it once, before any
+  placement, and holds only what it returns: no pass reads a master of
+  another type or casts one.
 
 A configuration object offers its model as ``cfg.paged_model()``;
 :func:`paged_model` is the one place that asks, and holds the one error
@@ -39,6 +48,10 @@ from dataclasses import dataclass
 from typing import Any, Callable, Mapping
 
 
+def _as_given(params):
+    return params
+
+
 @dataclass(frozen=True)
 class PagedModel:
     planes: Mapping[str, tuple[int, ...]]
@@ -49,6 +62,7 @@ class PagedModel:
     layers: Callable
     pass_stats: Callable | None = None
     tp_shard: Mapping[str, int] | None = None
+    resident: Callable = _as_given
 
 
 def paged_model(cfg) -> PagedModel:

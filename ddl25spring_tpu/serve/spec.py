@@ -424,7 +424,9 @@ def describe(mesh, program: str = "verify", model_axis: str = "model",
 
     from ddl25spring_tpu.parallel.tp import shard_tp_params
 
-    params = llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    params = paged_model(cfg).resident(
+        llama.init_llama_params(jax.random.PRNGKey(0), cfg)
+    )
     if program == "draft":
         draft_params, run_cfg = early_exit_drafter(params, cfg, draft_layers)
         run_params = shard_tp_params(

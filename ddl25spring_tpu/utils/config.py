@@ -22,7 +22,10 @@ class LlamaConfig:
     n_layers: int = 6
     ctx_size: int = 256
     pad_id: int = 0
-    dtype: str = "bfloat16"     # MXU-friendly compute dtype; params stay fp32
+    # MXU-friendly compute dtype.  Training keeps float32 masters and
+    # casts at each use; the paged server holds its matrices in this type
+    # (``PagedModel.resident``, serve/paged_model.py)
+    dtype: str = "bfloat16"
     use_flash: bool = False     # Pallas flash-attention kernel for the hot op
     n_experts: int = 0          # > 0: switch-MoE FFN in every block
     capacity_factor: float = 1.25
