@@ -149,20 +149,6 @@ def attach_parent_telemetry(
             **resume,
         }
     if compile_report is not None:
-        # the child's measured perf cell prices the compile report's
-        # H001 overlap complaints: the linter's "sync collective, no
-        # overlap" findings on the bench workload gain the strategy's
-        # measured exposed-comms time (ddl25spring_tpu/analysis/engine.
-        # attach_measured_costs) before the report rides the line
-        perf = tel.get("perf")
-        if isinstance(perf, dict) and "error" not in perf:
-            from ddl25spring_tpu.analysis.engine import (
-                attach_measured_costs,
-            )
-
-            for name, r in (compile_report.get("strategies") or {}).items():
-                if name.startswith("bench") and r.get("findings"):
-                    attach_measured_costs(r["findings"], perf)
         tel["compile_report"] = compile_report
         tel["lint"] = lint_summary(compile_report)
     # runtime-health summary: when the record (or any attempt) carries a
@@ -346,16 +332,16 @@ def run_with_retries(
             if isinstance(tel, dict):
                 tel["goodput"] = goodput_mod.goodput_cell(merged)
             if final is not None and merged.get("strategy"):
-                from ddl25spring_tpu.obs import perfscope
+                from ddl25spring_tpu.obs import logger as obs_logger
 
-                perfscope.append_ledger(
+                obs_logger.append_ledger(
                     goodput_mod.ledger_row(
                         merged,
                         strategy=merged["strategy"],
                         mesh=merged.get("mesh"),
-                        host=perfscope.host_fingerprint(),
+                        host=obs_logger.host_fingerprint(),
                     ),
-                    ledger_path or perfscope.DEFAULT_LEDGER,
+                    ledger_path or obs_logger.DEFAULT_LEDGER,
                 )
         except Exception as e:  # noqa: BLE001 — observability only
             print(f"lineage goodput merge failed: {type(e).__name__}: "
@@ -610,16 +596,10 @@ def main(argv=None) -> None:
                          "capacity_change target that does not divide "
                          "the global batch is lowered to the largest "
                          "device count that does")
-    ap.add_argument("--perf-reps", type=int, default=8, metavar="K",
-                    help="barriered step reps for the measured perf "
-                         "record (ddl25spring_tpu.obs.perfscope: "
-                         "measured MFU, overlap efficiency, exposed "
-                         "comms on the BENCH line's telemetry.perf); "
-                         "0 disables the measurement")
     ap.add_argument("--perf-ledger", default=None, metavar="JSONL",
-                    help="append the measured perf record here "
-                         "(default runs/perf_ledger.jsonl; gate trends "
-                         "with tools/perf_report.py --check)")
+                    help="append the run's mem / goodput / serve "
+                         "trend rows here (default "
+                         "runs/perf_ledger.jsonl)")
     ap.add_argument("--smoke", action="store_true",
                     help="CPU smoke run with telemetry: single-device DP, "
                          "tiny dataset/steps, no FedAvg; writes "
@@ -1424,31 +1404,7 @@ def main(argv=None) -> None:
                 "note": f"failed: {type(e).__name__}: {e}",
             }]
 
-    # measured perf record (ddl25spring_tpu/obs/perfscope.py): re-lowers
-    # the per-batch step once (the cost the old FLOPs-only pass already
-    # paid), times it barriered, times the 1-device compute-only
-    # counterfactual, micro-costs the live collective inventory, and
-    # derives measured MFU / overlap efficiency / exposed comms.  Any
-    # perf-side failure degrades to the bare FLOPs count — measurement
-    # must never cost the bench line.
-    perf_record = None
-    flops_step = None
-    if args.perf_reps > 0:
-        try:
-            from ddl25spring_tpu.obs import perfscope
-
-            perf_record, params, opt_state = perfscope.measure_bench_step(
-                step, params, opt_state, feed.fixed, meta, devices,
-                reps=args.perf_reps, per_chip_batch=args.per_chip_batch,
-            )
-            flops_step = perf_record.get("flops")
-        except Exception as e:  # noqa: BLE001 — keep the bench metric
-            print(f"perfscope measurement failed ({type(e).__name__}: "
-                  f"{e}); falling back to FLOPs-only accounting",
-                  file=sys.stderr)
-            perf_record = {"error": f"{type(e).__name__}: {e}"}
-    if flops_step is None:
-        flops_step = compiled_flops(step, params, opt_state, feed.fixed)
+    flops_step = compiled_flops(step, params, opt_state, feed.fixed)
     achieved_tf, frac = mfu(flops_step, dt_per_step, n_chips, meta["device"])
     peak = chip_peak_flops(meta["device"])
 
@@ -1495,27 +1451,6 @@ def main(argv=None) -> None:
             },
         }
 
-    # the measured-perf cell + artifacts: perf.json in the run dir for
-    # obs_report's "performance" section, and a ledger append so this
-    # run becomes one point on the cross-run trend that
-    # tools/perf_report.py --check gates
-    if perf_record is not None:
-        if "error" in perf_record:
-            telemetry["perf"] = {"error": perf_record["error"]}
-        else:
-            from ddl25spring_tpu.obs import perfscope
-
-            telemetry["perf"] = perfscope.perf_cell(perf_record)
-            try:
-                telemetry["perf"]["ledger"] = perfscope.append_ledger(
-                    perf_record,
-                    args.perf_ledger or perfscope.DEFAULT_LEDGER,
-                )
-                if args.obs_dir:
-                    perfscope.write_run_perf(perf_record, args.obs_dir)
-            except OSError as e:  # a read-only FS must not kill the line
-                telemetry["perf"]["ledger_error"] = str(e)
-
     # the runtime-memory cell + artifacts (graft-mem, PR 17): mem.json
     # in the run dir for obs_report's Memory section, a record:"mem"
     # ledger row for tools/mem_report.py --check, and the reshape
@@ -1557,10 +1492,10 @@ def main(argv=None) -> None:
         )
         telemetry["mem"] = memscope.mem_cell(mem_record)
         try:
-            from ddl25spring_tpu.obs import perfscope
+            from ddl25spring_tpu.obs import logger as obs_logger
 
-            telemetry["mem"]["ledger"] = perfscope.append_ledger(
-                mem_record, args.perf_ledger or perfscope.DEFAULT_LEDGER
+            telemetry["mem"]["ledger"] = obs_logger.append_ledger(
+                mem_record, args.perf_ledger or obs_logger.DEFAULT_LEDGER
             )
             if args.obs_dir:
                 telemetry["mem"]["mem_json"] = memscope.write_run_mem(
@@ -1633,7 +1568,7 @@ def main(argv=None) -> None:
     # graft-goodput (PR 20): close this attempt's badput decomposition.
     # Watchdog stall idle rides as seconds-only (its span overlaps the
     # step that eventually completed); everything never measured
-    # (imports, FedAvg, the h2d probe, perfscope) is the honest
+    # (imports, FedAvg, the h2d probe) is the honest
     # ``other`` residual.  A retry child's doc is the attempt view the
     # parent merges into the lineage view; an in-process run (plain CPU
     # smoke) is its own one-attempt lineage and appends its own ledger
@@ -1659,14 +1594,14 @@ def main(argv=None) -> None:
         goodput_mod.write_run_goodput(attempt_goodput, args.obs_dir)
     if own_lineage:
         try:
-            from ddl25spring_tpu.obs import perfscope
+            from ddl25spring_tpu.obs import logger as obs_logger
 
-            telemetry["goodput"]["ledger"] = perfscope.append_ledger(
+            telemetry["goodput"]["ledger"] = obs_logger.append_ledger(
                 goodput_mod.ledger_row(
                     attempt_goodput, strategy=meta["layout"],
-                    mesh=gp_mesh, host=perfscope.host_fingerprint(),
+                    mesh=gp_mesh, host=obs_logger.host_fingerprint(),
                 ),
-                args.perf_ledger or perfscope.DEFAULT_LEDGER,
+                args.perf_ledger or obs_logger.DEFAULT_LEDGER,
             )
         except OSError as e:  # a read-only FS must not kill the line
             telemetry["goodput"]["ledger_error"] = str(e)

@@ -6,7 +6,7 @@ optimized HLO on CPU.  This package adds compile-time *judgment*: a
 rule engine (:mod:`.engine`) that runs a hazard pack (:mod:`.rules`,
 H001-H013) over those same structured facts for every registered
 parallel strategy — the collective hazards (H001-H007), the schedule
-verifier graft-sched (:mod:`.sched`, H008-H010), and the sharding-flow
+verifier graft-sched (:mod:`.sched`, H008-H009), and the sharding-flow
 verifier graft-shard (:mod:`.shard_flow`, H011-H013: implicit
 reshards, partition-rule coverage proofs, cross-program layout
 contracts) — plus an AST linter (:mod:`.source_lint`, S101-S103)

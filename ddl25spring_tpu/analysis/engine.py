@@ -316,81 +316,3 @@ def summarize(findings: list[Finding | dict]) -> dict[str, Any]:
         "worst": worst_severity(f["severity"] for f in unwaived),
         "by_rule": by_rule,
     }
-
-
-def attach_measured_costs(
-    findings: list[dict],
-    perf_record: dict[str, Any],
-    sched: dict[str, Any] | None = None,
-    strategy: str | None = None,
-    waivers: list | None = None,
-) -> int:
-    """Cross-reference a perfscope record (:mod:`ddl25spring_tpu.obs.
-    perfscope`) onto H001 findings, in place — and price the schedule's
-    overlap windows (H010).
-
-    H001 says "this sync collective leaves overlap on the table" — a
-    judgment with no price tag until a measurement exists.  Each H001
-    finding whose HLO op name appears in the record's micro-cost table
-    gains ``finding["measured"]`` = the standalone wall cost of that
-    very collective on this host, plus the strategy-level measured
-    exposed-comms time and overlap efficiency; findings from a
-    *different* compilation of the same workload (op names don't match,
-    e.g. the bench parent's fake-mesh report vs the child's live run)
-    still gain the strategy-level context.  Only dict findings are
-    annotated (``Finding.to_dict()`` upstream).  Returns the number of
-    findings annotated.
-
-    With ``sched`` (the ``analysis/sched.py`` report riding the same
-    compile), every overlap window is additionally priced against the
-    measured micro-cost of its own op: windows that cannot hide the
-    transfer even in principle append **H010** findings to
-    ``findings`` (waiver-resolved against ``waivers``, default the repo
-    waiver file) — the only rule that needs both a static window and a
-    live measurement, hence emitted here rather than in the pure-HLO
-    rule pass.
-    """
-    micro_by_op = {
-        m["op"]: m
-        for m in perf_record.get("micro") or []
-        if m.get("op")
-    }
-    exposed = perf_record.get("exposed_comms_s")
-    if exposed is None and perf_record.get("exposed_comms_ms") is not None:
-        exposed = perf_record["exposed_comms_ms"] / 1e3
-    eff = perf_record.get("overlap_eff")
-    n = 0
-    for f in findings:
-        if not isinstance(f, dict) or f.get("rule") != "H001":
-            continue
-        meas: dict[str, Any] = {
-            "exposed_comms_s": exposed,
-            "overlap_eff": eff,
-        }
-        m = micro_by_op.get(f.get("op"))
-        if m and m.get("t_s") is not None:
-            meas["t_s_per_exec"] = m["t_s"]
-            meas["t_total_s"] = m.get("t_total_s")
-        f["measured"] = meas
-        n += 1
-    if sched:
-        from ddl25spring_tpu.analysis import sched as sched_mod
-        from ddl25spring_tpu.analysis.rules import h010_finding
-
-        already = {
-            f.get("op") for f in findings
-            if isinstance(f, dict) and f.get("rule") == "H010"
-        }
-        fresh = [
-            h010_finding(strategy, rec)
-            for rec in sched_mod.slack_vs_measured(sched, perf_record)
-            if rec["op"] not in already
-        ]
-        if fresh:
-            waivers_mod.apply_waivers(
-                fresh,
-                waivers_mod.load_waivers() if waivers is None else waivers,
-            )
-            findings.extend(f.to_dict() for f in fresh)
-            n += len(fresh)
-    return n

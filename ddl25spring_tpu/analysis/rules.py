@@ -53,13 +53,6 @@ H009      error     mismatched or reordered collective sequence across
                     windows over unequal overlapping groups — the
                     static deadlock shapes H007's shape-local check
                     cannot see
-H010      warn      overlap window priced under the measured micro-cost
-                    of the very op it must hide (``runs/perf_ledger.
-                    jsonl``): the schedule cannot hide the transfer
-                    even in principle.  Emitted by
-                    :func:`ddl25spring_tpu.analysis.engine.
-                    attach_measured_costs` when a perf record is in
-                    hand (``graft_lint --perf-ledger``, perfscope)
 H011      error     implicit reshard: a non-scalar collective kind in
                     the compiled HLO that the strategy's ``describe()``
                     signature neither declares nor forbids — XLA's
@@ -748,30 +741,5 @@ def h013_finding(
             "save layout in ft/reshard's contract / the serve pool "
             "specs) so every program in the round-trip sees the same "
             "split"
-        ),
-    )
-
-
-def h010_finding(strategy: str | None, rec: dict[str, Any]) -> Finding:
-    """One H010 finding from a :func:`ddl25spring_tpu.analysis.sched.
-    slack_vs_measured` record — the constructor lives here so the rule
-    pack owns every severity/message, while the emission point is
-    :func:`~ddl25spring_tpu.analysis.engine.attach_measured_costs`
-    (the only place a measured perf record is in hand)."""
-    return Finding(
-        rule="H010", severity="warn", strategy=strategy,
-        op=rec.get("op"), bytes=rec.get("result_bytes"),
-        message=(
-            f"{rec['kind']} measured at "
-            f"{rec['t_measured_s'] * 1e3:.3f} ms standalone but its "
-            f"overlap window holds only {rec['t_slack_s'] * 1e3:.3f} ms "
-            f"of independent compute ({rec['slack_flops']:.3g} FLOPs at "
-            "the record's calibrated peak) — the schedule cannot hide "
-            "this transfer even in principle"
-        ),
-        fix_hint=(
-            "grow the window (smaller buckets issued earlier, or more "
-            "compute between issue and use) or shrink the transfer "
-            "(dtype, bucket size) until the measured cost fits"
         ),
     )

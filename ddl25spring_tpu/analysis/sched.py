@@ -35,8 +35,8 @@ independent work schedulable while its transfer is in flight:
   collective to its first use regardless of how the program staged it.
 
 The per-strategy roll-up is ``static_overlap_bound``: an analytical
-upper bound on perfscope's measured ``overlap_eff`` under the
-strategy's issue discipline.  Each collective can hide at most
+upper bound on the share of its transfers a schedule can hide under
+the strategy's issue discipline.  Each collective can hide at most
 ``min(t_wire, t_slack)`` seconds of its transfer, with both times taken
 from ONE reference chip spec (:data:`REF_CHIP` — a datasheet constant,
 so the bound is noise-free and host-independent by construction)::
@@ -69,10 +69,8 @@ duplicate-permute-target rule) cannot see:
   the shared participants hold A's resources while B's disjoint
   participants cannot make progress on B.
 
-Rules H008 (zero-slack window), H009 (participant-stream mismatch) and
-H010 (slack priced under the measured micro-cost of the very op, via
-``engine.attach_measured_costs`` + the perf ledger) surface both
-families through the existing engine/waiver machinery; see
+Rules H008 (zero-slack window) and H009 (participant-stream mismatch)
+surface both families through the existing engine/waiver machinery; see
 ``analysis/rules.py`` and ``tools/graft_lint.py --sched``.
 """
 
@@ -766,51 +764,3 @@ def discipline_of(meta: dict[str, Any] | None) -> str:
     if meta.get("discipline") in ("sync", "overlap"):
         return meta["discipline"]
     return "overlap" if (meta.get("overlap") or meta.get("prefetch")) else "sync"
-
-
-def slack_vs_measured(
-    sched: dict[str, Any],
-    perf_record: dict[str, Any],
-    scalar_bytes: int | None = None,
-) -> list[dict[str, Any]]:
-    """Price each overlap window against the measured micro-cost of the
-    very op it belongs to (PR 7's cost model): records where the window
-    cannot hide the transfer *even in principle* — the measured
-    standalone wall cost of the collective exceeds the window's compute
-    time at the record's own calibrated peak.
-
-    Returns ``{"op", "kind", "t_measured_s", "t_slack_s",
-    "slack_flops"}`` per underwater op — the evidence H010 turns into
-    findings (:func:`ddl25spring_tpu.analysis.engine.
-    attach_measured_costs`).  Only windows that claim overlap (async
-    pairs / dataflow windows) are judged: a sync schedule window is
-    H001's department, not a broken overlap promise.
-    """
-    peak = perf_record.get("peak_flops_per_chip")
-    if not peak:
-        return []
-    if scalar_bytes is None:
-        scalar_bytes = sched.get("scalar_bytes", 64)
-    micro = {
-        m["op"]: m for m in perf_record.get("micro") or [] if m.get("op")
-    }
-    out = []
-    for rec in sched.get("slack") or []:
-        if rec["window"] not in ("pair", "dataflow"):
-            continue
-        if rec["result_bytes"] <= scalar_bytes:
-            continue  # scalar bookkeeping: hiding it is not a goal
-        m = micro.get(rec["op"])
-        if not m or m.get("t_s") is None:
-            continue
-        t_slack = rec["slack_flops"] / peak
-        if t_slack < m["t_s"]:
-            out.append({
-                "op": rec["op"],
-                "kind": rec["kind"],
-                "t_measured_s": m["t_s"],
-                "t_slack_s": t_slack,
-                "slack_flops": rec["slack_flops"],
-                "result_bytes": rec["result_bytes"],
-            })
-    return out

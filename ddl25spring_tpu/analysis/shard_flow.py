@@ -40,9 +40,8 @@ H011/H012 and the per-program half of H013 run inside the ordinary rule
 pass (:mod:`ddl25spring_tpu.analysis.rules`), so every registered
 strategy's clean pin covers them; the cross-program half needs several
 compiled programs in hand and is emitted by
-:func:`check_layout_contracts` (``tools/graft_lint.py --shard-flow``),
-the same pattern as H010's measured-cost emission.  Waivers ride the
-shared file; findings are never dropped, only marked.
+:func:`check_layout_contracts` (``tools/graft_lint.py --shard-flow``).
+Waivers ride the shared file; findings are never dropped, only marked.
 
 Grounding: pjit-on-TPUv4 scalable training (arXiv:2204.06514) and
 automatic cross-replica weight-update sharding (arXiv:2004.13336) both

@@ -39,9 +39,9 @@ from ddl25spring_tpu.analysis.rules import Finding
 
 # module scopes per rule: path substrings relative to the repo root.
 # ft/ builds the auto-resume/checkpoint steps that trace on the hot
-# path, and sentinels/perfscope compile guards and micro-benches INTO
-# programs — an env read inside any of them silently forks compiled
-# program structure on ambient process state (PR-9 satellite: scope
+# path, and sentinels compiles guards INTO programs — an env read
+# inside any of them silently forks compiled program structure on
+# ambient process state (PR-9 satellite: scope
 # grown from parallel/+benchmarks to the ft and obs trace surfaces;
 # PR-12 satellite: serve/ joins — the driver/engine resolve every
 # DDL25_SERVE_* knob through utils.config.env_int at the entry point,
@@ -59,7 +59,6 @@ _TRACED_CODE_DIRS = (
     "ddl25spring_tpu/ft/",
     "ddl25spring_tpu/serve/",
     "ddl25spring_tpu/obs/sentinels.py",
-    "ddl25spring_tpu/obs/perfscope.py",
     "ddl25spring_tpu/obs/timeline.py",
     "ddl25spring_tpu/obs/memscope.py",
 )

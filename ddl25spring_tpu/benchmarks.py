@@ -247,20 +247,6 @@ def build_resnet_scan_step(
     return multi, step1, params, opt_state, meta
 
 
-def build_compute_counterfactual(
-    devices: list,
-    per_chip_batch: int,
-    **kw: Any,
-):
-    """The collective-free twin of the bench step: the SAME model at the
-    SAME per-device batch on ONE device (dp=1, S=1 — the optimized HLO
-    carries no cross-device collective at all).  Timing it next to the
-    real multi-chip step decomposes the step wall into compute vs
-    exposed comms (:mod:`ddl25spring_tpu.obs.perfscope` — the bench's
-    measured-MFU/overlap attribution rides this)."""
-    return build_resnet_step(devices[:1], 1, 1, 1, per_chip_batch, **kw)
-
-
 class DeviceDataset:
     """TPU-native input pipeline for datasets that fit in HBM.
 

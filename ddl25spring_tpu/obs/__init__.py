@@ -24,12 +24,10 @@ by ``chip_smoke.py``'s ``runtime_probe`` on every chip run):
   cadence, ZeRO collective bytes);
 - ``tools/obs_report.py`` — folds a run directory into a summary table
   (steps/sec p50/p95, MFU, bubble fraction, h2d bandwidth);
-- :mod:`~ddl25spring_tpu.obs.perfscope` — steady-state measurement
-  harness (imported on demand, not re-exported here): barriered step
-  wall p50/p95, a one-device compute-only counterfactual, standalone
-  micro-costs per collective-inventory site, measured MFU against the
-  calibrated chip peak, and the cross-run regression ledger
-  (``runs/perf_ledger.jsonl`` + ``tools/perf_report.py --check``).
+
+Rates, utilizations and idle shares are not measured here: ``benchmark/run.py``
+measures them on the chip and the driver records them in ``PERF_LEDGER.jsonl``
+(``PERF.md``).
 
 Runtime health (the operable half — the compile-time analytics'
 runtime counterpart):

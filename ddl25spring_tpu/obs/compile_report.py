@@ -37,7 +37,7 @@ COMPILE_REPORT_BASENAME = "compile_report.json"
 # every registered strategy, in report order — the full fourteen.  The
 # sched verifier (PR 9) pins each *-overlap strategy's static overlap
 # bound strictly above its sync twin's, which needs BOTH twins compiled
-# under every gate (signature pins, graft-lint H008-H010, perfscope);
+# under every gate (signature pins, graft-lint H008-H009);
 # zero1/zero2's overlap twins therefore graduated from on-demand to
 # default.  PR 10 adds the two serving programs (serve-decode /
 # serve-prefill: the paged-KV TP inference steps, pinned all-reduce-only

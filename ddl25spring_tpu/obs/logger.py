@@ -22,6 +22,9 @@ import subprocess
 import time
 from typing import Any, Iterator
 
+# the cross-run trend ledger the `mem`, `goodput` and `serve` rows share
+DEFAULT_LEDGER = os.path.join("runs", "perf_ledger.jsonl")
+
 
 def git_sha(cwd: str | None = None) -> str | None:
     """Best-effort HEAD sha (None outside a repo / without git)."""
@@ -150,3 +153,31 @@ def iter_jsonl(path: str) -> Iterator[dict[str, Any]]:
             line = line.strip()
             if line:
                 yield json.loads(line)
+
+
+def host_fingerprint() -> str:
+    """Stable-ish identity of the measuring machine+backend — part of
+    the ledger key, so one host's trend never gates another's."""
+    import platform as _platform
+
+    import jax
+
+    try:
+        d = jax.devices()[0]
+        kind = getattr(d, "device_kind", None) or d.platform
+    except Exception:  # noqa: BLE001 — no backend, still fingerprintable
+        kind = "no-backend"
+    return f"{_platform.node()}/{os.cpu_count()}cpu/{kind}"
+
+
+def append_ledger(
+    record: dict[str, Any], path: str | None = None
+) -> str:
+    """Append one record to the JSONL ledger (created on first use)."""
+    path = path or DEFAULT_LEDGER
+    parent = os.path.dirname(path)
+    if parent:
+        os.makedirs(parent, exist_ok=True)
+    with open(path, "a") as f:
+        f.write(json.dumps(record, default=str) + "\n")
+    return path

@@ -630,13 +630,12 @@ def mem_record(
     reshape_steps: list[dict[str, Any]] | None = None,
     extra: dict[str, Any] | None = None,
 ) -> dict[str, Any]:
-    """One ``record:"mem"`` ledger row / ``mem.json`` document — same
-    identity envelope as the perf rows (strategy/mesh/host/git_sha), so
-    ``mem_report`` groups trends the same way ``perf_report`` does."""
+    """One ``record:"mem"`` ledger row / ``mem.json`` document, under
+    the identity envelope (strategy/mesh/host/git_sha) that
+    ``mem_report`` groups trends by."""
     import jax
 
-    from ddl25spring_tpu.obs.logger import git_sha
-    from ddl25spring_tpu.obs.perfscope import host_fingerprint
+    from ddl25spring_tpu.obs.logger import git_sha, host_fingerprint
 
     return {
         "record": "mem",

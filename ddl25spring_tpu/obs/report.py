@@ -30,9 +30,8 @@ from ddl25spring_tpu.obs.logger import read_jsonl
 
 # the serving artifact a `bench.py --serve` run drops in the obs dir
 # (written by ddl25spring_tpu/serve/driver.py, which imports this name
-# — the obs layer owns its artifact basenames, like FLIGHT_BASENAME /
-# PERF_BASENAME; tools/serve_report.py restates the string to stay
-# stdlib-only)
+# — the obs layer owns its artifact basenames, like FLIGHT_BASENAME;
+# tools/serve_report.py restates the string to stay stdlib-only)
 SERVE_BASENAME = "serve.json"
 
 
@@ -325,20 +324,6 @@ def summarize_run(run_dir: str) -> dict[str, Any]:
             rec.setdefault("ckpt_dir", cd)
             break
 
-    # measured perf record, when a bench/perfscope run dropped one here
-    # (ddl25spring_tpu/obs/perfscope.py): step-wall decomposition into
-    # compute vs exposed comms, measured MFU against the calibrated
-    # chip peak, and the projection error vs the compile-time roofline
-    from ddl25spring_tpu.obs.perfscope import PERF_BASENAME
-
-    ppath = os.path.join(run_dir, PERF_BASENAME)
-    if os.path.exists(ppath):
-        try:
-            with open(ppath) as f:
-                out["perf"] = json.load(f)
-        except (json.JSONDecodeError, OSError) as e:
-            out["perf"] = {"error": f"unreadable {PERF_BASENAME}: {e}"}
-
     # serving record, when a `bench.py --serve` run dropped one here
     # (ddl25spring_tpu/serve/driver.py): admission counters, TTFT /
     # per-token latency percentiles, page-pool occupancy, and the
@@ -477,55 +462,10 @@ def format_report(summary: dict[str, Any]) -> str:
                 + (
                     ""
                     if ph.get("mfu") is not None
-                    else "  (no chip peak in the run header — not even "
-                         "the calibrated cpu-host one; MFU n/a)"
+                    else "  (no chip peak in the run header; MFU n/a)"
                 )
             )
             break
-
-    p = summary.get("perf")
-    if p:
-        lines.append("")
-        lines.append(
-            "performance (perf.json — measured, not projected; "
-            "see tools/perf_report.py for the cross-run trend):"
-        )
-        if p.get("error"):
-            lines.append(f"  {p['error']}")
-        else:
-            def pms(key):
-                v = p.get(key)
-                return f"{v * 1e3:.3f} ms" if v is not None else "n/a"
-
-            lines.append(
-                f"  step p50 {pms('step_s_p50')}  p95 {pms('step_s_p95')}"
-                f"  compute-only {pms('compute_s_p50')}"
-                f"  exposed comms {pms('exposed_comms_s')}"
-            )
-            peak = p.get("peak_flops_per_chip")
-            mm = p.get("measured_mfu")
-            pm = p.get("projected_mfu")
-            pe = p.get("projection_err")
-            lines.append(
-                "  measured MFU "
-                + (f"{mm:.4f}" if mm is not None else "n/a")
-                + (f" (chip {p.get('chip')}, peak "
-                   f"{peak / 1e12:.2f} TFLOP/s {p.get('peak_source')})"
-                   if peak else "")
-                + (f"  projected {pm:.4f}"
-                   f" [{p.get('projected_bound')}-bound]"
-                   if pm is not None else "")
-                + (f"  err {pe * 100:+.1f}%" if pe is not None else "")
-            )
-            eff = p.get("overlap_eff")
-            n_sites = len(p.get("micro") or [])
-            lines.append(
-                "  overlap efficiency "
-                + (f"{eff:.3f}" if eff is not None
-                   else "n/a (no costed collectives)")
-                + f"  (micro comms total {pms('micro_total_s')}"
-                + f" over {n_sites} inventory site(s))"
-            )
 
     sv = summary.get("serve")
     if sv:

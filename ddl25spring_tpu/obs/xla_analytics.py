@@ -502,9 +502,7 @@ def parse_hlo_collectives(hlo_text: str, mesh=None) -> list[dict[str, Any]]:
             kind = cm.group(1)
             type_str = line.split("=", 1)[1].split(cm.group(0), 1)[0]
             result_bytes = _shape_bytes(type_str)
-            # primary element dtype of the result — what a standalone
-            # re-synthesis of this op must move (obs/perfscope.py's
-            # measured comms cost model keys on it)
+            # primary element dtype of the result
             dm = _SHAPE_RE.search(type_str)
             dtype = dm.group(1) if dm and dm.group(1) in _DTYPE_BYTES else None
             groups = _parse_groups(line)
@@ -630,7 +628,7 @@ def analyze_compiled(
     # whole-program schedule analysis (analysis/sched.py): per-collective
     # overlap-slack windows, participant-stream safety, and the
     # per-strategy static_overlap_bound — computed once here and reused
-    # by the lint context, perfscope records, and the report tables;
+    # by the lint context and the report tables;
     # sched breakage degrades to an error note, never costs the report
     try:
         from ddl25spring_tpu.analysis import sched as sched_mod
@@ -656,8 +654,7 @@ def roofline_projection(
     collective wire bytes (ICI).  The projection assumes no overlap — a
     deliberate upper bound on step time; its ``bound`` field names the
     roofline the program would sit on.  ``specs`` overlays/extends
-    :data:`~ddl25spring_tpu.utils.flops.CHIP_SPECS` (how perfscope
-    injects the runtime-calibrated cpu-host peak, and how
+    :data:`~ddl25spring_tpu.utils.flops.CHIP_SPECS` (how
     ``tools/resnet_roofline.py`` derates a peak by MXU occupancy)."""
     from ddl25spring_tpu.utils.flops import CHIP_SPECS
 
@@ -672,8 +669,8 @@ def roofline_projection(
         if not spec:
             continue
         # a peak-only spec (a chip in PEAK_BF16_FLOPS with no full
-        # CHIP_SPECS entry, e.g. v2/v3 via host_peak_spec) still
-        # projects: an unknown bandwidth simply doesn't bound the step
+        # CHIP_SPECS entry, e.g. v2/v3) still projects: an unknown
+        # bandwidth simply doesn't bound the step
         t_compute = flops / spec["peak_bf16_flops"]
         hbm_bw = spec.get("hbm_bytes_per_s")
         ici_bw = spec.get("ici_bytes_per_s")

@@ -55,8 +55,8 @@ elementwise; packing commutes with it), pinned in
 The bucket threshold itself is tunable per host: builders default to
 :data:`AUTO`, resolved at BUILD time by :func:`resolve_bucket_bytes`
 from the ``DDL25_BUCKET_BYTES`` env knob (via the sanctioned
-``utils.config`` boundary — rule S101), so a ``tools/bucket_sweep.py``
-recommendation applies without touching code.  ``describe()`` hooks pin
+``utils.config`` boundary — rule S101), so a size found on the chip
+applies without touching code.  ``describe()`` hooks pin
 explicit sizes so compile-time signatures never drift with the
 environment.
 """

@@ -18,8 +18,8 @@ One call (:func:`run_serve_bench`) produces the whole serving record:
 3. **artifacts** — ``serve.json`` in the obs dir (the Serving section
    of ``tools/obs_report.py``; histograms for ``tools/serve_report.py``)
    and a ``record: "serve"`` line appended to the perf ledger
-   (``runs/perf_ledger.jsonl``) keyed like perfscope's records (host
-   fingerprint + workload key, git sha as the trend variable) so
+   (``runs/perf_ledger.jsonl``) keyed by host
+   fingerprint + workload key (git sha as the trend variable) so
    ``serve_report --check`` gates cross-run regressions.
 
 Engine knobs resolve from ``DDL25_SERVE_*`` env (documented in the
@@ -789,8 +789,7 @@ def run_serve_bench(
 
     from ddl25spring_tpu.models import llama
     from ddl25spring_tpu.obs import flight, sentinels, spans
-    from ddl25spring_tpu.obs.logger import git_sha
-    from ddl25spring_tpu.obs.perfscope import host_fingerprint
+    from ddl25spring_tpu.obs.logger import git_sha, host_fingerprint
     from ddl25spring_tpu.obs.report import SERVE_BASENAME
     from ddl25spring_tpu.serve.paged_model import paged_model
     from ddl25spring_tpu.serve.traffic import TrafficSpec, synth_trace
@@ -1093,7 +1092,7 @@ def run_serve_bench(
             record["goodput"], obs_dir
         )
     if ledger_path is not None:
-        from ddl25spring_tpu.obs.perfscope import append_ledger
+        from ddl25spring_tpu.obs.logger import append_ledger
 
         try:
             record["ledger"] = append_ledger(

@@ -37,22 +37,7 @@ PEAK_BF16_FLOPS: dict[str, float] = {
 # (obs/xla_analytics.py): bf16 peak, HBM bandwidth, and aggregate
 # per-chip ICI bandwidth.  Public datasheet numbers, approximate — the
 # projection is a planning instrument, not a measurement.
-#
-# "cpu-host" is the pseudo-chip for the CPU CI image: nominal
-# order-of-magnitude numbers so the roofline/MFU math is *defined*
-# everywhere the suite runs, refined at runtime by
-# :func:`calibrated_host_peak_flops` (the measured-MFU path —
-# obs/perfscope.py — always uses the calibrated peak).  A cpu-host MFU
-# is a host-relative utilization for trend/regression tracking, not a
-# datasheet comparison.
-CPU_HOST_KIND = "cpu-host"
-
 CHIP_SPECS: dict[str, dict[str, float]] = {
-    CPU_HOST_KIND: {
-        "peak_bf16_flops": 5e10,       # placeholder; calibrated at runtime
-        "hbm_bytes_per_s": 2e10,       # host DRAM, single-socket ballpark
-        "ici_bytes_per_s": 5e9,        # fake-device "interconnect" = memcpy
-    },
     "TPU v4": {
         "peak_bf16_flops": 275e12,
         "hbm_bytes_per_s": 1.228e12,
@@ -76,21 +61,15 @@ CHIP_SPECS: dict[str, dict[str, float]] = {
 }
 
 
-def chip_peak_flops(
-    device: jax.Device | None = None, allow_host: bool = True
-) -> float | None:
+def chip_peak_flops(device: jax.Device | None = None) -> float | None:
     """Per-chip bf16 peak FLOP/s for ``device`` (default:
     ``jax.devices()[0]`` — a backend that cannot be reached raises).
     On a TPU the datasheet peak of its ``device_kind``; a kind that is
-    not in :data:`PEAK_BF16_FLOPS` is an error, not a default.  On a
-    non-TPU platform the *measured* host peak
-    (:func:`calibrated_host_peak_flops`) stands in under the explicit
-    ``cpu-host`` name, so MFU math is defined for CPU test runs;
-    ``allow_host=False`` gives None there, for callers that only want
-    datasheet peaks."""
+    not in :data:`PEAK_BF16_FLOPS` is an error, not a default.  Off a
+    TPU there is no peak (None): a host never stands in for a chip."""
     d = device if device is not None else jax.devices()[0]
     if d.platform != "tpu":
-        return calibrated_host_peak_flops() if allow_host else None
+        return None
     kind = getattr(d, "device_kind", "") or ""
     best = None
     for prefix, peak in PEAK_BF16_FLOPS.items():
@@ -102,88 +81,6 @@ def chip_peak_flops(
             "PEAK_BF16_FLOPS with its source"
         )
     return best[1]
-
-
-_HOST_PEAK: float | None = None
-_HOST_PEAK_TRIED = False
-
-
-def calibrated_host_peak_flops(refresh: bool = False) -> float | None:
-    """Measured f32 matmul peak of the *host* backend (FLOP/s), cached
-    per process.
-
-    This calibrates the ``cpu-host`` pseudo-spec: a jitted chain of
-    512x512 matmuls (big enough to amortize dispatch, small enough to
-    stay cache-resident) is timed best-of-3, and the achieved FLOP/s
-    becomes the denominator of every cpu-host MFU.  It is a
-    host-relative number — fake CPU devices share the host's cores, so
-    treat cpu-host MFU as a utilization *trend* (the perf ledger's
-    regression signal), never a cross-machine comparison.  Returns None
-    when even the calibration program fails to run — and a failure is
-    cached too, so a broken backend pays the attempt (and the warning)
-    once per process, not on every peak lookup."""
-    global _HOST_PEAK, _HOST_PEAK_TRIED
-    if _HOST_PEAK_TRIED and not refresh:
-        return _HOST_PEAK
-    _HOST_PEAK_TRIED = True
-    import time
-
-    import jax.numpy as jnp
-
-    m, chain = 512, 8
-    flops = 2.0 * m * m * m * chain
-
-    try:
-        @jax.jit
-        def _chain(a):
-            x = a
-            for _ in range(chain):
-                x = x @ a
-            return x
-
-        a = jnp.full((m, m), 0.5, jnp.float32)
-        _chain(a).block_until_ready()  # compile outside the clock
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            _chain(a).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        _HOST_PEAK = flops / best if best > 0 else None
-    except Exception as e:  # noqa: BLE001 — degrade to None, but say why
-        _log.warning("host peak calibration failed (%s: %s)",
-                     type(e).__name__, e)
-        _HOST_PEAK = None
-    return _HOST_PEAK
-
-
-def host_peak_spec(
-    device: jax.Device | None = None,
-) -> tuple[str | None, dict[str, float] | None]:
-    """``(chip kind, roofline spec)`` for the backend actually running —
-    the pair the measured-MFU/projection-error math keys on
-    (obs/perfscope.py).  TPU: the datasheet :data:`CHIP_SPECS` entry
-    matching ``device_kind`` (peak from the :data:`PEAK_BF16_FLOPS`
-    prefix table when no full spec exists).  Anything else: the
-    ``cpu-host`` pseudo-spec with its peak replaced by the calibrated
-    measurement.  An unreachable backend or an unknown TPU kind raises;
-    ``(CPU_HOST_KIND, None)`` when host calibration failed — the
-    placeholder peak must never masquerade as a measurement (an MFU
-    against an arbitrary constant would poison the perf ledger's
-    regression bands)."""
-    d = device if device is not None else jax.devices()[0]
-    if d.platform != "tpu":
-        peak = calibrated_host_peak_flops()
-        if not peak:
-            return CPU_HOST_KIND, None
-        spec = dict(CHIP_SPECS[CPU_HOST_KIND])
-        spec["peak_bf16_flops"] = peak
-        return CPU_HOST_KIND, spec
-    peak = chip_peak_flops(d, allow_host=False)
-    kind = getattr(d, "device_kind", "") or "tpu"
-    for name, spec in CHIP_SPECS.items():
-        if spec.get("peak_bf16_flops") == peak and name != CPU_HOST_KIND:
-            return name, dict(spec)
-    return kind, {"peak_bf16_flops": peak}
 
 
 def compiled_flops(jitted_fn: Any, *args: Any, **kwargs: Any) -> float | None:

@@ -47,46 +47,18 @@ def test_chip_peak_prefix_match_prefers_longest():
         chip_peak_flops(FakeUnknown())
 
 
-def test_chip_peak_on_cpu_calibrates_host_fallback():
-    # datasheet-only callers still get None off-TPU ...
-    assert chip_peak_flops(jax.devices("cpu")[0], allow_host=False) is None
-    # ... but the default contract is now DEFINED on the CPU CI image:
-    # the calibrated cpu-host pseudo-peak (perfscope's measured-MFU
-    # denominator), so obs_report's MFU column stops reading n/a here
-    peak = chip_peak_flops(jax.devices("cpu")[0])
-    assert peak is not None and peak > 0
-
-
-def test_host_peak_spec_cpu_host():
-    from ddl25spring_tpu.utils.flops import (
-        CHIP_SPECS,
-        CPU_HOST_KIND,
-        host_peak_spec,
-    )
-
-    kind, spec = host_peak_spec(jax.devices("cpu")[0])
-    assert kind == CPU_HOST_KIND
-    assert spec["peak_bf16_flops"] > 0
-    # the calibrated peak replaces the placeholder; bandwidth terms
-    # come from the static pseudo-spec
-    assert spec["hbm_bytes_per_s"] == (
-        CHIP_SPECS[CPU_HOST_KIND]["hbm_bytes_per_s"]
-    )
-
-    class FakeV4:
-        platform = "tpu"
-        device_kind = "TPU v4"
-
-    kind, spec = host_peak_spec(FakeV4())
-    assert kind == "TPU v4"
-    assert spec == CHIP_SPECS["TPU v4"]
+def test_no_peak_off_tpu():
+    # a host never stands in for a chip: no peak, so no MFU fraction
+    cpu = jax.devices("cpu")[0]
+    assert chip_peak_flops(cpu) is None
+    achieved_tf, frac = mfu(1e9, 1.0, n_chips=1, device=cpu)
+    assert achieved_tf == 1e-3 and frac is None
 
 
 def test_roofline_projects_with_peak_only_spec():
     """A chip known only by its bf16 peak (TPU v2/v3/7x — in
-    PEAK_BF16_FLOPS but without a full CHIP_SPECS entry, the shape
-    host_peak_spec returns there) must still project: an unknown
-    bandwidth just doesn't bound the step."""
+    PEAK_BF16_FLOPS but without a full CHIP_SPECS entry) must still
+    project: an unknown bandwidth just doesn't bound the step."""
     from ddl25spring_tpu.obs.xla_analytics import roofline_projection
 
     p = roofline_projection(
@@ -97,34 +69,8 @@ def test_roofline_projects_with_peak_only_spec():
     assert p["projected_mfu"] == 1.0
 
 
-def test_calibration_failure_is_cached(monkeypatch):
-    import jax as _jax
-
-    from ddl25spring_tpu.utils import flops as fl
-
-    monkeypatch.setattr(fl, "_HOST_PEAK", None)
-    monkeypatch.setattr(fl, "_HOST_PEAK_TRIED", False)
-    calls = []
-
-    def broken_jit(*a, **k):
-        calls.append(1)
-        raise RuntimeError("broken backend")
-
-    monkeypatch.setattr(_jax, "jit", broken_jit)
-    assert fl.calibrated_host_peak_flops() is None
-    assert fl.calibrated_host_peak_flops() is None
-    # the failed attempt is cached: one timed-matmul attempt per
-    # process, not one per peak lookup
-    assert len(calls) == 1
-    # and the placeholder peak never masquerades as a calibration:
-    # spec is None, so perfscope nulls measured_mfu instead of faking
-    # one against the 5e10 constant
-    kind, spec = fl.host_peak_spec(jax.devices("cpu")[0])
-    assert kind == fl.CPU_HOST_KIND and spec is None
-
-
 def test_resnet_roofline_rides_shared_projection():
-    """Drift pin for the PR-7 fold: tools/resnet_roofline.py must source
+    """Drift pin: tools/resnet_roofline.py must source
     its chip numbers from the one CHIP_SPECS table and compute each
     layer through xla_analytics.roofline_projection — re-deriving a
     layer independently must reproduce the tool's row exactly."""

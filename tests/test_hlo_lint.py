@@ -405,7 +405,7 @@ def test_h007_axis_leak_against_declared_signature(mesh22):
     assert "H007" not in _rules_fired(_lint(H007_AXIS_LEAK, mesh=mesh22))
 
 
-# ------------------------------------------ sched rule pack (H008-H010)
+# ------------------------------------------ sched rule pack (H008-H009)
 
 # 4 MiB async pair closed immediately: the cosmetic-overlap shape the
 # PR-9 motivation names — H001's has-a-pair test passes it trivially,
@@ -545,66 +545,6 @@ ENTRY %main (p: pred[], x: f32[256]) -> f32[256] {{
         "replica_groups={{0,1,2,3}}, to_apply=%add",
     )
     assert "H009" not in _rules_fired(_lint(same))
-
-
-def test_h010_prices_windows_against_measured_micro_costs():
-    """H010 rides attach_measured_costs (the only place a static window
-    and a live measurement meet): a window whose compute cannot cover
-    the op's measured standalone cost fires; a window that can stays
-    quiet."""
-    from ddl25spring_tpu.analysis import sched as sched_mod
-
-    zero = sched_mod.analyze_schedule(H008_ZERO_SLACK_PAIR)
-    record = {
-        "peak_flops_per_chip": 1e12,
-        "micro": [{"op": "ars", "t_s": 1e-3}],
-    }
-    findings: list = []
-    n = engine.attach_measured_costs(
-        findings, record, sched=zero, strategy="synthetic", waivers=[]
-    )
-    assert n == 1
-    (f,) = findings
-    assert f["rule"] == "H010" and f["severity"] == "warn"
-    assert "even in principle" in f["message"]
-    assert not f["waived"]
-    # near miss: the paired-with-dot window holds ~268 us of compute at
-    # this peak — a 100 us measured transfer hides, no finding
-    hlo_ok = H008_ZERO_SLACK_PAIR.replace(
-        "  %ard = f32[1048576]{0} all-reduce-done(f32[1048576]{0} %ars)\n"
-        "  %d = f32[512,512]{1,0} dot(f32[512,512]{1,0} %a, "
-        "f32[512,512]{1,0} %b), lhs_contracting_dims={1}, "
-        "rhs_contracting_dims={0}\n",
-        "  %d = f32[512,512]{1,0} dot(f32[512,512]{1,0} %a, "
-        "f32[512,512]{1,0} %b), lhs_contracting_dims={1}, "
-        "rhs_contracting_dims={0}\n"
-        "  %ard = f32[1048576]{0} all-reduce-done(f32[1048576]{0} %ars)\n",
-    )
-    ok = sched_mod.analyze_schedule(hlo_ok)
-    fs2: list = []
-    engine.attach_measured_costs(
-        fs2, {"peak_flops_per_chip": 1e12,
-              "micro": [{"op": "ars", "t_s": 100e-6}]},
-        sched=ok, strategy="synthetic", waivers=[],
-    )
-    assert [f["rule"] for f in fs2] == []
-
-
-def test_h010_findings_resolve_against_waivers():
-    from ddl25spring_tpu.analysis import sched as sched_mod
-    from ddl25spring_tpu.analysis.waivers import Waiver
-
-    zero = sched_mod.analyze_schedule(H008_ZERO_SLACK_PAIR)
-    record = {"peak_flops_per_chip": 1e12,
-              "micro": [{"op": "ars", "t_s": 1e-3}]}
-    findings: list = []
-    engine.attach_measured_costs(
-        findings, record, sched=zero, strategy="dp-overlap",
-        waivers=[Waiver(rule="H010", strategy="dp-*",
-                        reason="fake mesh: micro costs are dispatch-bound")],
-    )
-    assert findings and findings[0]["waived"]
-    assert "dispatch-bound" in findings[0]["waived_reason"]
 
 
 # ------------------------------------------------------- source rule pack

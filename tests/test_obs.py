@@ -546,3 +546,25 @@ def test_compiled_flops_warns_and_returns_none_when_unavailable(caplog):
     # and the mfu path degrades to (None, None) instead of raising
     assert mfu(None, 0.1) == (None, None)
     assert mfu(1e9, 0.0) == (None, None)
+
+
+# ------------------------- the shared trend ledger survives a torn tail
+
+
+@pytest.mark.parametrize("kind", ["serve", "goodput", "mem"])
+def test_appended_row_survives_a_torn_tail(tmp_path, kind):
+    """A writer killed mid-line must not cost the gate its whole rows:
+    each reader returns the one row of its kind and skips the torn one."""
+    import importlib
+
+    from ddl25spring_tpu.obs.logger import append_ledger
+
+    led = str(tmp_path / "sub" / "ledger.jsonl")  # parent made on first use
+    others = [k for k in ("serve", "goodput", "mem") if k != kind]
+    for k in (others[0], kind, others[1]):
+        assert append_ledger({"record": k, "strategy": f"toy-{k}"}, led) == led
+    with open(led, "a") as f:
+        f.write('{"record": "%s", "torn' % kind)  # killed mid-write
+    reader = importlib.import_module(f"tools.{kind}_report")
+    (row,) = reader.read_ledger(led)
+    assert row == {"record": kind, "strategy": f"toy-{kind}"}

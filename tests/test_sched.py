@@ -403,29 +403,6 @@ def test_safety_flags_crossed_async_windows_on_unequal_groups():
     assert sched.check_schedule_safety(ok, defs, _anchor(ok, ops), dags) == []
 
 
-# --------------------------------------------------- measured-cost pricing
-
-
-def test_slack_vs_measured_flags_underwater_windows():
-    r = sched.analyze_schedule(PAIR_ZERO_SLACK)
-    record = {
-        "peak_flops_per_chip": 1e12,
-        "micro": [{"op": "ars", "t_s": 1e-3}],
-    }
-    (hit,) = sched.slack_vs_measured(r, record)
-    assert hit["op"] == "ars" and hit["t_slack_s"] == 0.0
-    # a window whose compute covers the measured cost passes
-    r2 = sched.analyze_schedule(PAIR_WITH_DOT)
-    record2 = {
-        "peak_flops_per_chip": 1e12,
-        # 2*512^3 flops at 1e12 = ~268 us of cover; 100 us measured
-        "micro": [{"op": "ars", "t_s": 100e-6}],
-    }
-    assert sched.slack_vs_measured(r2, record2) == []
-    # no peak on the record: no claim
-    assert sched.slack_vs_measured(r, {"micro": []}) == []
-
-
 # ------------------------------------------------------- strategy pins
 
 
@@ -489,17 +466,6 @@ def test_multi_bucket_describe_default():
     plan >= 2 buckets by default."""
     for name in ("dp", "dp-overlap", "zero1", "zero2", "zero3"):
         assert cached_strategy_report(name)["meta"]["n_buckets"] >= 2, name
-
-
-def test_perfscope_record_carries_static_overlap_bound():
-    """The perfscope wiring: measured records ship the analytical bound
-    next to the measured overlap_eff (the CI perf-smoke contract for
-    *-overlap strategies), and the bench telemetry cell exposes it."""
-    from ddl25spring_tpu.obs.perfscope import perf_cell
-
-    rec = {"static_overlap_bound": 0.25, "overlap_eff": 0.1}
-    cell = perf_cell(rec)
-    assert cell["static_overlap_bound"] == 0.25
 
 
 def test_comms_report_sched_cell():

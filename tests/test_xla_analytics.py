@@ -282,7 +282,7 @@ def test_dp_overlap_signature_matches_dp_with_backward_issue():
     launch ceiling, data-axis-only grouping, and the same forbidden
     kinds as sync dp — any drift here means the custom_vjp machinery
     changed what crosses the wire.  The meta declares the mode so every
-    downstream consumer (perfscope records, comms tables) names it."""
+    downstream consumer (comms tables) names it."""
     r = _report("dp-overlap")
     sync = _report("dp")
     assert r["signature_violations"] == []

@@ -16,9 +16,8 @@ availability, goodput tokens/sec/chip) — semantics in
 carry the full decomposition including the badput windows
 ``tools/trace_export.py`` renders.
 
-Gates (all CI-facing, mirroring the ``perf_report`` contract — keys
-with a single record pass with a "no baseline yet" note, different
-hosts never gate each other):
+Gates (all CI-facing — keys with a single record pass with a "no
+baseline yet" note, different hosts never gate each other):
 
 - ``--check``: within each (strategy, mesh, host, scope) key, the
   latest row's ``fraction_useful`` must not fall more than

@@ -62,21 +62,6 @@ def _fmt_finding(f: dict) -> str:
         line += f"\n      fix: {f['fix_hint']}"
     if f.get("waived"):
         line += f"\n      WAIVED: {f['waived_reason']}"
-    m = f.get("measured")
-    if m:
-        # perfscope cross-reference (--perf-ledger): the overlap
-        # complaint priced by the measured cost of the very op it flags
-        bits = []
-        if m.get("t_s_per_exec") is not None:
-            bits.append(f"~{m['t_s_per_exec'] * 1e3:.3f} ms/exec standalone")
-        if m.get("exposed_comms_s") is not None:
-            bits.append(
-                f"strategy exposed-comms {m['exposed_comms_s'] * 1e3:.3f} ms"
-            )
-        if m.get("overlap_eff") is not None:
-            bits.append(f"overlap eff {m['overlap_eff']:.3f}")
-        if bits:
-            line += f"\n      measured: {'; '.join(bits)}"
     return line
 
 
@@ -233,7 +218,7 @@ def main(argv=None) -> int:
                     help="render the whole-program schedule report per "
                          "strategy: overlap-slack windows, the static "
                          "overlap bound, and deadlock-hazard counts "
-                         "(analysis/sched.py).  The H008-H010 rules run "
+                         "(analysis/sched.py).  The H008-H009 rules run "
                          "regardless; this flag controls the report "
                          "detail.  On by default under --check")
     ap.add_argument("--shard-flow", action="store_true",
@@ -254,11 +239,6 @@ def main(argv=None) -> int:
                     help="skip the source (AST) pass")
     ap.add_argument("--waivers", default=None, metavar="TOML",
                     help="waiver file (default: analysis/waivers.toml)")
-    ap.add_argument("--perf-ledger", default=None, metavar="JSONL",
-                    help="cross-reference each strategy's latest "
-                         "measured perf record (obs/perfscope ledger) "
-                         "onto its H001 findings, so overlap "
-                         "complaints carry a measured cost")
     ap.add_argument("--root", default=str(_REPO_ROOT),
                     help="repo root for the source pass")
     args = ap.parse_args(argv)
@@ -321,34 +301,6 @@ def main(argv=None) -> int:
                 ]
             hlo_reports[name] = r
 
-        if args.perf_ledger:
-            from ddl25spring_tpu.analysis.engine import attach_measured_costs
-            from ddl25spring_tpu.obs.perfscope import (
-                host_fingerprint,
-                read_ledger,
-            )
-
-            # the ledger's trend identity is (strategy, mesh, host) —
-            # a record measured on another machine or mesh must not
-            # print its milliseconds onto THIS compile's findings
-            # (HLO op names are stable across compiles, so a
-            # strategy-only match would silently look plausible)
-            here = host_fingerprint()
-            latest: dict = {}
-            for rec in read_ledger(args.perf_ledger):
-                if rec.get("host") == here:
-                    latest[(rec.get("strategy"), str(rec.get("mesh")))] = rec
-            for name, r in hlo_reports.items():
-                rec = latest.get((name, str(r.get("mesh"))))
-                if rec and r.get("findings") is not None:
-                    # prices H001 findings AND the schedule's overlap
-                    # windows — windows that cannot hide their own
-                    # measured transfer surface as H010 findings here
-                    attach_measured_costs(
-                        r["findings"], rec, sched=r.get("sched"),
-                        strategy=name, waivers=waivers,
-                    )
-
     shard_flow_doc = None
     if args.shard_flow and hlo_reports:
         from ddl25spring_tpu.analysis import shard_flow as sf
@@ -363,8 +315,7 @@ def main(argv=None) -> int:
 
     if args.format == "json":
         # per-rule finding counts across every pass, so CI artifacts
-        # diff mechanically (mirrors perf_report --format json's
-        # verdicts-in-document shape)
+        # diff mechanically
         by_rule: dict = {}
         for f in src_findings or []:
             by_rule[f.rule] = by_rule.get(f.rule, 0) + 1

@@ -21,10 +21,10 @@ Two sources, same record schema (``ddl25spring_tpu/obs/memscope.py``):
   replica's pools were actually freed, not leaked into the retired
   roster.
 
-- ``--ledger PATH`` trends ``record: "mem"`` rows the same way
-  ``perf_report.py`` trends perf rows: within each (strategy, mesh,
-  host) key the LATEST record's live/RSS peaks must sit within the
-  ``--tolerance`` band over the median of up to ``--window`` priors.
+- ``--ledger PATH`` trends ``record: "mem"`` rows: within each
+  (strategy, mesh, host) key the LATEST record's live/RSS peaks must
+  sit within the ``--tolerance`` band over the median of up to
+  ``--window`` priors.
   Single-record keys pass with a "no baseline yet" note; different
   hosts never gate each other.
 
@@ -47,7 +47,7 @@ DEFAULT_WINDOW = 5
 
 def read_ledger(path: str) -> list[dict]:
     """Parseable ``record: "mem"`` rows in append order (torn lines
-    skipped) — the perf_report.py contract, filtered to the mem kind."""
+    skipped)."""
     out: list[dict] = []
     p = Path(path)
     if not p.exists():

@@ -11,10 +11,10 @@ renders its throughput/admission table and ASCII latency histograms
 (TTFT and per-decode-tick wall time).  The ledger
 (``runs/perf_ledger.jsonl``) additionally holds one ``record: "serve"``
 trend row per run, keyed (workload key, host) with git sha as the
-variable under test — the same ledger the perfscope records live in,
-different record kind.
+variable under test; the ``mem`` and ``goodput`` rows live in the same
+file under their own record kinds.
 
-``--check`` mirrors ``perf_report.py``: exit non-zero when, within any
+``--check`` exits non-zero when, within any
 (key, host) group, the LATEST row regresses past the tolerance band
 against the median of up to ``--window`` priors — tokens/sec/chip
 falling by more than ``--tolerance`` (fractional, default 0.5 — CPU CI
@@ -53,20 +53,10 @@ Pure stdlib — no jax import, so the gate runs anywhere the JSON does.
 from __future__ import annotations
 
 import json
+import statistics
 import sys
 from datetime import datetime, timezone
 from pathlib import Path
-
-# the torn-tail ledger contract, grouping, and number formatting are
-# perf_report's — one implementation for every stdlib gate over
-# runs/perf_ledger.jsonl
-try:  # imported as tools.serve_report (tests, package contexts)
-    from tools import perf_report as _perf_report
-except ImportError:  # run as a script: sys.path[0] is tools/
-    import perf_report as _perf_report
-
-_fmt = _perf_report._fmt
-_median = _perf_report._median
 
 DEFAULT_LEDGER = "runs/perf_ledger.jsonl"
 DEFAULT_TOLERANCE = 0.5
@@ -86,9 +76,23 @@ def read_serve_json(run_dir: str) -> dict:
 
 
 def read_ledger(path: str) -> list[dict]:
-    """Parseable ``record: "serve"`` rows in append order (torn
-    trailing lines skipped — ``perf_report.read_ledger``'s contract)."""
-    return _perf_report.read_ledger(path, kind="serve")
+    """Parseable ``record: "serve"`` rows in append order.  A torn line
+    (a writer killed mid-write) is skipped, never fatal."""
+    out: list[dict] = []
+    p = Path(path)
+    if not p.exists():
+        return out
+    for line in p.read_text().splitlines():
+        line = line.strip()
+        if not line:
+            continue
+        try:
+            rec = json.loads(line)
+        except json.JSONDecodeError:
+            continue
+        if isinstance(rec, dict) and rec.get("record") == "serve":
+            out.append(rec)
+    return out
 
 
 def ledger_key(rec: dict) -> tuple[str, str]:
@@ -103,7 +107,20 @@ def ledger_key(rec: dict) -> tuple[str, str]:
 
 
 def group_records(records: list[dict]) -> dict[tuple, list[dict]]:
-    return _perf_report.group_records(records, key=ledger_key)
+    groups: dict[tuple, list[dict]] = {}
+    for rec in records:
+        groups.setdefault(ledger_key(rec), []).append(rec)
+    return groups
+
+
+def _median(xs: list[float]) -> float | None:
+    return statistics.median(xs) if xs else None
+
+
+def _fmt(v, nd=3, scale=1.0, suffix=""):
+    if not isinstance(v, (int, float)):
+        return "n/a"
+    return f"{v * scale:.{nd}f}{suffix}"
 
 
 def check_group(
