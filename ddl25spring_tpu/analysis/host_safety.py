@@ -86,8 +86,8 @@ MIRRORS: tuple[dict[str, Any], ...] = (
         "path": "ddl25spring_tpu/serve/engine.py",
         "cls": "ServeEngine",
         "device_state": ("pool", "draft_pool"),
-        "device_ops": ("_ref", "_unref", "_adopt", "_truncate",
-                       "_release"),
+        # `_account` dispatches release / ref / unref / truncate
+        "device_ops": ("_account", "_adopt"),
         "host_mirrors": ("_reserved", "_pending_pages", "_release_mask",
                          "_cached_pages", "_adopted_pages", "_pending",
                          "prefix", "peak_pages"),

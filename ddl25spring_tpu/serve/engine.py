@@ -35,6 +35,7 @@ forbidden) and HBM budgets pin in CI like every training strategy.
 
 from __future__ import annotations
 
+import contextlib
 import random
 import time
 from collections import deque
@@ -323,24 +324,20 @@ def make_prefill(
     return prefill
 
 
-def _release(pool, mask):
-    return kv_pages.release_slots(pool, mask)
-
-
-# prefix-cache device ops: shapes respecialize per pool geometry under
-# jit, so one wrapper each serves every engine.  Neither donates its
-# pool — they run once per admission/eviction burst, the cheap side of
-# the same trade the release program documents below.
-_adopt = jax.jit(kv_pages.adopt_prefix)
-_unref = jax.jit(kv_pages.unref_pages)
+# A program that changes only the pool's accounting takes and returns only
+# the pool's accounting (``kv_pages.accounting``: five small arrays), so no
+# plane is a parameter or a result of these four and none is copied;
+# ``ServeEngine._account`` is their one caller.  Each respecialises by the
+# pool's geometry under jit and so serves every engine and both pools.
+_release = jax.jit(kv_pages.release_slots)
 _ref = jax.jit(kv_pages.ref_pages)
-# speculative rollback (PR 13): shared across both pools (the wrapper
-# respecializes per pool geometry).  Like release, it deliberately does
-# NOT donate — see the donation note in _compiled_programs; truncate
-# runs twice per spec round, but aliasing the pool through an auxiliary
-# program was measured to slow every subsequent tick/prefill ~5x on
-# the CPU backend, and the un-donated copy is the cheap side.
-_truncate = jax.jit(kv_pages.truncate_to)
+_unref = jax.jit(kv_pages.unref_pages)
+_truncate = jax.jit(kv_pages.truncate_to, static_argnames="page_len")
+# adopt_prefix WRITES planes (the copy-on-write page), so it takes the whole
+# pool: donated where the engine's programs donate theirs, so that a radix
+# hit costs a page's copy and not a pool's
+_adopt = jax.jit(kv_pages.adopt_prefix)
+_adopt_donating = jax.jit(kv_pages.adopt_prefix, donate_argnums=(0,))
 
 
 # One compiled (tick, prefill, release) triple per build key: the ramp
@@ -367,14 +364,7 @@ def _compiled_programs(
             cfg, temperature=temperature, sentinel=sentinel,
             logit_probe=logit_probe,
         )
-        # tick/prefill donate their POOL argument (position 1).  release
-        # deliberately does NOT donate: aliasing the pool through the
-        # release program was measured to slow every SUBSEQUENT
-        # tick/prefill call ~5x on the CPU backend (ramp TTFT p50
-        # 3.4 ms -> 10-26 ms), while the un-donated release copy runs
-        # once per completion burst — the cheap side of that trade.
-        # Revisit on a real-HBM pool if the transient 2x release-time
-        # footprint ever bites before the per-call tax does.
+        # tick/prefill donate their POOL argument (position 1)
         pool_kw = {"donate_argnums": (1,)} if donate else {}
         _PROGRAM_CACHE[key] = (
             jax.jit(tick, **pool_kw),
@@ -384,7 +374,7 @@ def _compiled_programs(
                 temperature=temperature, sentinel=sentinel,
                 logit_probe=logit_probe,
             ), **pool_kw),
-            jax.jit(_release),
+            _release,
         )
     return _PROGRAM_CACHE[key]
 
@@ -394,7 +384,7 @@ def _compiled_programs(
 # donate) — every same-config engine (the spec A/B's two arms, the
 # test engines) shares the XLA programs.  The drafter's prefill is
 # _compiled_programs' at the DRAFT cfg, and rollback rides the
-# module-level _truncate wrapper.
+# module-level _truncate program.
 _SPEC_CACHE: dict[tuple, dict] = {}
 
 
@@ -657,10 +647,8 @@ def _tp_compiled_programs(
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
             ),
-            # release touches only replicated accounting state; plain
-            # jit respects the committed input shardings (the k/v head
-            # split passes through untouched — pinned in tests)
-            jax.jit(_release),
+            # release sees only the replicated accounting state
+            _release,
         )
     return _TP_PROGRAM_CACHE[key]
 
@@ -1037,6 +1025,10 @@ class ServeEngine:
         self._tick, self._prefill, self._release = programs(
             cfg, temperature
         )
+        self._adopt = _adopt_donating if donate else _adopt
+        # accounting programs dispatched so far (`_account`): the spans
+        # around them carry its growth as their stat `account_ops`
+        self._account_ops = 0
         # radix prefix cache (opt-in): host index over cached prompt
         # pages; device sharing runs through kv_pages.adopt_prefix /
         # ref_pages / unref_pages, and prefill takes each row's start
@@ -1250,6 +1242,15 @@ class ServeEngine:
         trace and in the ring of its name), keyed by ``_obs_key``."""
         return _spans.span(name + self._obs_key, cat="serve", **stats)
 
+    @contextlib.contextmanager
+    def _accounting_span(self, name: str, **stats):
+        """:meth:`_span` with the late stat ``account_ops``: how many
+        accounting programs (:meth:`_account`) were dispatched inside."""
+        before = self._account_ops
+        with self._span(name, **stats) as span:
+            yield span
+            span.add(account_ops=self._account_ops - before)
+
     def _sample(self, name: str, value: float, t: float) -> None:
         _counters.sample(name + self._obs_key, value, t)
 
@@ -1339,6 +1340,9 @@ class ServeEngine:
         finally:
             self.eos_id, self.token_budget = saved_eos, saved_budget
             self.trace_label = saved_label
+        # the probe's pool goes before the fresh one is made, so that two
+        # pools never stand side by side (the drafter's likewise, below)
+        self.pool = None
         self.pool = self._place_pool(kv_pages.init_page_pool(
             self.cfg, n_pages=self.n_pages, page_len=self.page_len,
             max_slots=self.max_slots, pages_per_seq=self.pages_per_seq,
@@ -1353,6 +1357,7 @@ class ServeEngine:
         self._cached_pages = [[] for _ in range(self.max_slots)]
         self._pending = [[] for _ in range(self.max_slots)]
         if self.spec_k:
+            self.draft_pool = None
             # the probe round compiled the drafter prefill, the common
             # k-step draft variant, verify, and both pools' truncate;
             # the (k+1)-step catch-up variant only runs after a fully-
@@ -1381,13 +1386,13 @@ class ServeEngine:
             # calls them with (all-padding args: no state mutates) —
             # otherwise the FIRST radix hit pays the _adopt compile as
             # TTFT (observed: one 300 ms outlier in an all-4 ms run)
-            self.pool = _ref(self.pool, jnp.full(
+            self.pool = self._account(_ref, self.pool, jnp.full(
                 (self.pages_per_seq * self.prefill_batch,), -1, jnp.int32
             ))
-            self.pool = _unref(self.pool, jnp.full(
+            self.pool = self._account(_unref, self.pool, jnp.full(
                 (self.n_pages,), -1, jnp.int32
             ))
-            self.pool, _ok = _adopt(
+            self.pool, _ok = self._adopt(
                 self.pool,
                 jnp.full((self.prefill_batch,), -1, jnp.int32),
                 jnp.full(
@@ -1536,7 +1541,7 @@ class ServeEngine:
         if evicted:
             pages = np.full((self.n_pages,), -1, np.int32)
             pages[: len(evicted)] = evicted
-            self.pool = _unref(self.pool, jnp.asarray(pages))
+            self.pool = self._account(_unref, self.pool, jnp.asarray(pages))
         return len(evicted)
 
     def _match(self, req: Request) -> Match:
@@ -1623,7 +1628,7 @@ class ServeEngine:
             slots[row] = slot
             adopt[row, : m.n_ref] = m.pages
             cow[row] = m.cow_src
-        self.pool, ok = _adopt(
+        self.pool, ok = self._adopt(
             self.pool, jnp.asarray(slots), jnp.asarray(adopt),
             jnp.asarray(cow),
         )
@@ -1657,7 +1662,7 @@ class ServeEngine:
             width = self.pages_per_seq * self.prefill_batch
             pages = np.full((width,), -1, np.int32)
             pages[: len(claimed)] = claimed
-            self.pool = _ref(self.pool, jnp.asarray(pages))
+            self.pool = self._account(_ref, self.pool, jnp.asarray(pages))
 
     def _width_for(self, longest: int) -> int:
         """The ladder's smallest width that holds ``longest`` positions."""
@@ -1761,7 +1766,7 @@ class ServeEngine:
         # first-decode residual).  Wall: the measured device wall of
         # the pass (host overhead lands in the residual).
         prefill_cost = charge if self.clock == "virtual" else wall
-        with self._span("serve.emit"):
+        with self._accounting_span("serve.emit"):
             for row, (slot, req, m) in enumerate(batch):
                 req.admitted_t = now
                 req.prefill_start_t = t_pre
@@ -1963,7 +1968,7 @@ class ServeEngine:
         )
         now = self.now()
 
-        with self._span("serve.emit"):
+        with self._accounting_span("serve.emit"):
             new_lens = np.zeros((S,), np.int32)
             mask = np.zeros((S,), bool)
             for slot, req in enumerate(self.slots):
@@ -2012,8 +2017,12 @@ class ServeEngine:
             # inside kept pages are overwritten before they become readable
             jl = jnp.asarray(new_lens)
             jm = jnp.asarray(mask)
-            self.pool = _truncate(self.pool, jl, jm)
-            self.draft_pool = _truncate(self.draft_pool, jl, jm)
+            self.pool = self._account(
+                _truncate, self.pool, jl, jm, page_len=self.page_len
+            )
+            self.draft_pool = self._account(
+                _truncate, self.draft_pool, jl, jm, page_len=self.page_len
+            )
             self._track_pages()
         if self._spec_rounds % 8 == 0 or self._spec_rounds <= 2:
             flight.record(
@@ -2059,16 +2068,27 @@ class ServeEngine:
     def _track_pages(self) -> None:
         self.peak_pages = max(self.peak_pages, self._host_pages_used())
 
+    def _account(self, program, pool: dict, *args, **static) -> dict:
+        """Dispatch an accounting ``program`` on ``pool``'s accounting
+        arrays alone and merge what it returns into ``pool`` on the host:
+        the planes of the result are the buffers they were."""
+        self._account_ops += 1
+        return {**pool, **program(kv_pages.accounting(pool), *args, **static)}
+
     def _flush_releases(self) -> None:
         if not any(self._release_mask):
             return
-        with self._span("serve.release", slots=sum(self._release_mask)):
+        with self._accounting_span(
+            "serve.release", slots=sum(self._release_mask)
+        ):
             mask = jnp.asarray(np.asarray(self._release_mask))
-            self.pool = self._release(self.pool, mask)
+            self.pool = self._account(self._release, self.pool, mask)
             if self.spec_k:
                 # the drafter's mirror slot returns its pages in the same
-                # flush (the jitted wrapper respecializes per pool shapes)
-                self.draft_pool = self._release(self.draft_pool, mask)
+                # flush
+                self.draft_pool = self._account(
+                    self._release, self.draft_pool, mask
+                )
             for slot, flushed in enumerate(self._release_mask):
                 if flushed:  # the slot stops pinning its shared pages
                     self._adopted_pages[slot] = []
@@ -2112,7 +2132,7 @@ class ServeEngine:
             ran = False
             self._flush_releases()
             self.queue_depths.append(len(self.queue))
-            with self._span("serve.admit", queue=len(self.queue)):
+            with self._accounting_span("serve.admit", queue=len(self.queue)):
                 batch = self._admittable()
             if batch:
                 self._run_prefill(batch)

@@ -14,7 +14,9 @@ alternative, TPU-first (every operation static-shaped under jit):
   all layers of a page row together (one gather per layer serves a
   sequence's whole context).  A plane is every pool entry that is not
   one of :data:`ACCOUNTING`; no op here learns what a plane means, and
-  every op that moves page contents walks all of them.  The LAST row is
+  every op that moves page contents walks all of them; the accounting
+  ops (release, ref, unref, truncate) never see a plane, so the engine
+  hands them :func:`accounting` alone and copies none.  The LAST row is
   a trash page: masked writes (inactive slots, padded prefill rows) land
   there instead of corrupting live pages, so no ``lax.cond`` is ever
   needed on the write path.
@@ -84,13 +86,20 @@ Pool = dict[str, Any]
 __all__ = [
     "resolve_heads", "init_page_pool", "pool_geometry", "reserve_pages",
     "write_page_ids", "write_planes", "gather_planes", "planes",
-    "with_planes", "page_len_of", "ACCOUNTING",
+    "with_planes", "page_len_of", "ACCOUNTING", "accounting",
     "release_slots", "activate_slots", "used_pages",
     "adopt_prefix", "ref_pages", "unref_pages", "truncate_to",
 ]
 
 # the pool's bookkeeping entries; every other entry is a plane
 ACCOUNTING = ("page_table", "seq_len", "active", "free", "refcount")
+
+
+def accounting(pool: Pool) -> Pool:
+    """The bookkeeping of ``pool`` without its planes: all that
+    :func:`release_slots`, :func:`ref_pages`, :func:`unref_pages` and
+    :func:`truncate_to` read and write."""
+    return {k: pool[k] for k in ACCOUNTING}
 
 
 def planes(pool: Pool) -> Pool:
@@ -365,7 +374,8 @@ def adopt_prefix(pool: Pool, slots: jax.Array, adopt_pages: jax.Array,
     }, ok
 
 
-def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array) -> Pool:
+def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array,
+                page_len: int | None = None) -> Pool:
     """Roll back each masked slot's KV frontier to ``new_lens[slot]``
     written positions — speculative decoding's rejection path (PR 13):
     a verify pass writes the whole draft window optimistically, then the
@@ -386,10 +396,14 @@ def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array) -> Pool:
     A ``new_len`` at or above a slot's current frontier is a no-op for
     that slot (the drafter pool rides the same call as the target pool
     with the target's rollback length; on a fully-accepted round the
-    drafter has nothing to drop)."""
+    drafter has nothing to drop).
+
+    ``page_len`` is read off a plane unless the caller states it, as a
+    caller that hands over the accounting arrays alone must."""
     n_pages = pool["free"].shape[0]
     P = pool["page_table"].shape[1]
-    page_len = page_len_of(pool)
+    if page_len is None:
+        page_len = page_len_of(pool)
     mask = mask.astype(bool)
     new_lens = jnp.maximum(new_lens, 0)
 

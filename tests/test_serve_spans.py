@@ -66,7 +66,7 @@ def span_stats():
     try:
         with obs.scoped(True):
             yield lambda name: [
-                e["args"] for e in rec.to_chrome_trace()["traceEvents"]
+                e.get("args", {}) for e in rec.to_chrome_trace()["traceEvents"]
                 if e["name"] == name
             ]
     finally:
@@ -231,6 +231,65 @@ def test_a_speculative_round_is_spanned_and_counted_like_a_tick(params):
     assert not everything("serve.decode_tick")
     for t, d in rounds + everything("serve.verify") + everything("serve.draft_prefill"):
         assert sum(s <= t and t + d <= s + sd for s, sd in steps) == 1
+
+
+def test_admit_counts_its_evictions_as_accounting_programs(params):
+    """``account_ops``, a late stat of the spans around the accounting
+    dispatches: on ``serve.admit`` one program an eviction (two in a step
+    that admits two requests against a full cache), none where nothing is
+    evicted; on ``serve.emit`` the cache's claim after a prompt pass."""
+    eng = make_engine(params, prefix_cache=True, n_pages=8, prefill_batch=2)
+    evictions = []  # a step's evictions, in the order of the admit spans
+    inner = eng._evict_for
+
+    def evict_for(shortfall, protect):
+        got = inner(shortfall, protect)
+        evictions[-1] += bool(got)
+        return got
+
+    eng._evict_for = evict_for
+    inner_step = eng.step
+
+    def step():
+        evictions.append(0)
+        return inner_step()
+
+    eng.step = step
+    rng = np.random.default_rng(0)
+
+    def prompt():
+        return rng.integers(1, 64, 8).tolist()
+
+    with span_stats() as of:
+        for _ in range(4):  # distinct prompts, one at a time: the cache fills
+            run_steps(eng, [prompt()], max_new=2)
+        assert eng.n_pages - eng.prefix.held_pages < 3
+        # two requests at once, 3 pages each, where fewer are uncommitted
+        run_steps(eng, [prompt(), prompt()], max_new=4)
+        admits = [a["account_ops"] for a in of("serve.admit")]
+        emits = [a.get("account_ops") for a in of("serve.emit")]
+        releases = [a["account_ops"] for a in of("serve.release")]
+    assert admits == evictions
+    assert 2 in admits and 0 in admits
+    assert set(releases) == {1}
+    # every prompt pass claims its pages for the cache; the token loop after
+    # a tick dispatches nothing and carries no such stat
+    assert emits.count(1) == eng._prefills and emits.count(None) == eng._ticks
+    emits = [n for n in emits if n]
+    assert eng._account_ops == sum(admits + emits + releases)
+
+
+def test_release_and_rollback_count_one_program_a_pool(params):
+    eng = make_engine(params, spec_k=2, n_pages=32)
+    with span_stats() as of:
+        run_steps(eng, PROMPTS[:2], max_new=6)
+        releases = [a["account_ops"] for a in of("serve.release")]
+        emits = [a["account_ops"] for a in of("serve.emit")]
+        admits = [a["account_ops"] for a in of("serve.admit")]
+    assert releases and set(releases) == {2}  # the target's pool, the drafter's
+    # a round rolls both pools back; no cache, so a prompt pass claims nothing
+    assert sorted(emits) == [0] * eng._prefills + [2] * eng._spec_rounds
+    assert set(admits) == {0}
 
 
 def test_spans_change_no_token(params):
