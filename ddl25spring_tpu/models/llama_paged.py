@@ -150,8 +150,9 @@ def paged_model(cfg: LlamaConfig) -> PagedModel | None:
     if cfg.n_experts > 0:
         return None
 
-    def layers(params, rows, pages, offs, pos, live, tp_axis):
-        del params, live  # every weight is scanned; nothing is counted
+    def layers(params, slots, rows, pages, offs, pos, live, tp_axis):
+        # every weight is scanned; nothing is counted or kept a slot
+        del params, slots, live
         cos, sin = _rope_at(pos, cfg.head_dim)
 
         def run_layer(p, li, x, planes):

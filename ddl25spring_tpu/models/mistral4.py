@@ -37,17 +37,13 @@ their exchange.
   times ``routed_scaling_factor``; ``E(h) = (silu(h W_g) * (h W_u)) W_d``;
   ``x_out = x' + sum over the chosen AND held e of w_e E_e(h2) + S(h2)``.
 
-**The expert layer** has static shapes and no capacity: ``N`` rows give
-``k N`` assignments; those whose expert is not held here, or whose row is
-not live, sort behind the held ones and cost the sort and nothing more; the
-rows are gathered in expert order, :func:`~ddl25spring_tpu.ops.moe_gmm.moe_gmm`
-runs over the held experts' stacks with the group sizes as data, and each
-row takes its weighted results back (a gather through the inverse
-permutation and a sum over its ``k``: what a scatter-add would give, in a
-fixed order).  The group sizes over the pass's live positions are the
-block's ``aux``: the held experts' load, a layer (kept by layer, because
-the sum over layers hides how many experts one call reads), beside the
-count of live positions.
+**The expert layer** (:mod:`.routed_experts`, shared with every served
+model that routes: the router, the sort by held expert, three ``moe_gmm``
+calls, the inverse permutation, the load counts) has static shapes and no
+capacity.  The group sizes over the pass's live positions are the block's
+``aux``: the held experts' load, a layer (kept by layer, because the sum
+over layers hides how many experts one call reads), beside the count of
+live positions.
 
 Parameters (in ``cfg.dtype``, resident: nothing is cast at use and the
 seam's ``resident`` stays the identity; whoever serves the model brings
@@ -67,10 +63,14 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ddl25spring_tpu.models.llama import rms_norm
-from ddl25spring_tpu.ops.moe_gmm import moe_gmm
+from ddl25spring_tpu.models.routed_experts import (
+    pass_stats as moe_pass_stats,
+    route,
+    routed_experts,
+    swiglu,
+)
 from ddl25spring_tpu.serve import kv_pages
 from ddl25spring_tpu.serve.paged_model import PagedModel
 
@@ -180,10 +180,6 @@ def _rope(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _swiglu(h, w_gate, w_up, w_down):
-    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
-
-
 # --------------------------------------------------------------- the block
 
 
@@ -247,45 +243,6 @@ def mla_attention(p, x, planes, layer, rows, pages, offs, pos, cos, sin,
         return x + o.reshape(B, T, H * dv) @ p["wo"], planes
 
 
-def route(h2, w_router, cfg: Mistral4Config):
-    """``(experts [N, k] int32, weights [N, k] float32)`` of rows ``h2
-    [N, D]``: softmax over ALL experts in float32 (products of the stored
-    values, accumulated in float32), the ``k`` largest, normalised."""
-    logits = jnp.dot(h2, w_router, preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits, axis=-1)
-    w, e = lax.top_k(p, cfg.num_experts_per_tok)
-    if cfg.norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return e.astype(jnp.int32), w * cfg.routed_scaling_factor
-
-
-def routed_experts(h2, experts, weights, live, stacks, layer,
-                   cfg: Mistral4Config):
-    """The held experts' part of the routed sum for rows ``h2 [N, D]``
-    with their ``experts``/``weights [N, k]``; ``live [N]`` marks the rows
-    of a request.  Returns ``(y [N, D] float32, load [E_held] int32)``."""
-    N, D = h2.shape
-    k, E = cfg.num_experts_per_tok, cfg.n_held
-    local = experts.reshape(-1) - cfg.expert_offset  # [kN]
-    held = (local >= 0) & (local < E) & jnp.repeat(live, k)
-    group = jnp.where(held, local, E)  # not held, or not live: behind
-    order = jnp.argsort(group, stable=True)
-    load = jnp.zeros(E + 1, jnp.int32).at[group].add(1)[:E]
-    rows = h2[order // k]  # [kN, D] in expert order
-    gate = moe_gmm(rows, stacks["w_gate"], load, layer)
-    up = moe_gmm(rows, stacks["w_up"], load, layer)
-    act = (jax.nn.silu(gate.astype(jnp.float32))
-           * up.astype(jnp.float32)).astype(h2.dtype)
-    out = moe_gmm(act, stacks["w_down"], load, layer)  # [kN, D]
-    # each row's k results, back in its own order (zeros where not held)
-    back = jnp.zeros(k * N, jnp.int32).at[order].set(
-        jnp.arange(k * N, dtype=jnp.int32)
-    )
-    mine = out[back].reshape(N, k, D).astype(jnp.float32)
-    w = jnp.where(held.reshape(N, k), weights, 0.0)
-    return jnp.einsum("nk,nkd->nd", w, mine), load
-
-
 def moe_ffn(p, x, live, stacks, layer, cfg: Mistral4Config):
     """``x + held routed experts + shared expert`` of ``norm(x)``; scopes
     ``router`` / ``experts`` / ``shared_expert``."""
@@ -299,7 +256,7 @@ def moe_ffn(p, x, live, stacks, layer, cfg: Mistral4Config):
             flat, experts, weights, live.reshape(-1), stacks, layer, cfg
         )
     with jax.named_scope("shared_expert"):
-        shared = _swiglu(h2, p["ws_gate"], p["ws_up"], p["ws_down"])
+        shared = swiglu(h2, p["ws_gate"], p["ws_up"], p["ws_down"])
     y = routed.reshape(B, T, D) + shared.astype(jnp.float32)
     return x + y.astype(x.dtype), load
 
@@ -313,7 +270,8 @@ def rope_tables(pos, cfg: Mistral4Config):
 def paged_model(cfg: Mistral4Config) -> PagedModel:
     dtype = jnp.dtype(cfg.dtype)
 
-    def layers(params, rows, pages, offs, pos, live, tp_axis):
+    def layers(params, slots, rows, pages, offs, pos, live, tp_axis):
+        del slots  # nothing is kept a slot
         if tp_axis is not None:
             raise ValueError("mistral4 offers no tensor-parallel block")
         cos, sin = rope_tables(pos, cfg)
@@ -334,14 +292,6 @@ def paged_model(cfg: Mistral4Config) -> PagedModel:
         return jnp.dot(h, params["unembed"],
                        preferred_element_type=jnp.float32)
 
-    def pass_stats(aux) -> tuple[dict[str, int], dict[str, int]]:
-        load, live = aux[:, :-1], aux[:, -1]  # [L, E_held], [L] of one pass
-        return {
-            "moe.assignments_here": int(load.sum()),
-            "moe.experts_hit": int((load > 0).sum()),
-            "moe.load_max": int(load.max(axis=-1).sum()),
-        }, {"assignments": cfg.num_experts_per_tok * int(live.sum())}
-
     return PagedModel(
         planes={"ckv": (cfg.kv_lora_rank,), "kpe": (cfg.qk_rope_head_dim,)},
         n_layers=cfg.n_layers,
@@ -349,5 +299,5 @@ def paged_model(cfg: Mistral4Config) -> PagedModel:
         embed=lambda params, tokens: params["embed"].astype(dtype)[tokens],
         unembed=unembed,
         layers=layers,
-        pass_stats=pass_stats,
+        pass_stats=lambda aux: moe_pass_stats(aux, cfg),
     )

@@ -59,7 +59,11 @@ from ddl25spring_tpu.obs import (
 from ddl25spring_tpu.obs.counters import counters as _counters
 from ddl25spring_tpu.obs.timeline import timeline as _timeline
 from ddl25spring_tpu.serve import kv_pages
-from ddl25spring_tpu.serve.paged_model import PagedModel, paged_model
+from ddl25spring_tpu.serve.paged_model import (
+    PagedModel,
+    paged_model,
+    refuse_with_state,
+)
 from ddl25spring_tpu.serve.prefix import Match, PrefixCache
 from ddl25spring_tpu.utils.config import LlamaConfig
 
@@ -78,29 +82,33 @@ REJECT_DRAINING = "draining"  # elastic scale-down: replica admits nothing
 # ------------------------------------------------------ compiled programs
 
 
-def _block_stack(model: PagedModel, params, x, planes, rows, pages, offs,
-                 pos, live, tp_axis: str | None, layer_stack=None):
+def _block_stack(model: PagedModel, params, x, cache, slots, rows, pages,
+                 offs, pos, live, tp_axis: str | None, layer_stack=None):
     """Every block of ``model`` over ``x [B, T, D]`` at positions ``pos
-    [B, T]`` against the page ``planes`` (the seam of
-    :mod:`.paged_model`): the resident-weight layer scan, or
-    ``layer_stack``'s own walk (:func:`make_decode_tick`).  Returns ``(x,
-    planes, aux)``: ``aux`` is what the model's blocks count of the pass,
-    stacked over layers, or ``None`` for a model that counts nothing."""
-    run_layer = model.layers(params, rows, pages, offs, pos, live, tp_axis)
+    [B, T]`` against what the pool holds for it (``cache``: its page planes
+    and, for rows seated at ``slots``, its slot state; the seam of
+    :mod:`.paged_model`): the resident-weight scan over the model's equal
+    units (a layer, or the model's period of layers), or ``layer_stack``'s
+    own walk (:func:`make_decode_tick`).  Returns ``(x, cache, aux)``:
+    ``aux`` is what the model's blocks count of the pass, stacked over
+    units, or ``None`` for a model that counts nothing."""
+    run_layer = model.layers(
+        params, slots, rows, pages, offs, pos, live, tp_axis
+    )
 
     if layer_stack is not None:
-        return (*layer_stack(params, run_layer, x, planes), None)
+        return (*layer_stack(params, run_layer, x, cache), None)
 
-    def layer(carry, inp):
-        x, planes, aux = run_layer(*inp, *carry)
-        return (x, planes), aux
+    def unit(carry, inp):
+        x, cache, aux = run_layer(*inp, *carry)
+        return (x, cache), aux
 
     with jax.named_scope("blocks"):
-        (x, planes), aux = lax.scan(
-            layer, (x, planes),
-            (params["blocks"], jnp.arange(model.n_layers)),
+        (x, cache), aux = lax.scan(
+            unit, (x, cache),
+            (params["blocks"], jnp.arange(model.n_units)),
         )
-    return x, planes, aux
+    return x, cache, aux
 
 
 def _pack_pass(tokens, logits, aux, logit_probe: int):
@@ -174,10 +182,10 @@ def make_decode_tick(
         rows = jnp.clip(pool["page_table"], 0, n_pages - 1)  # [S, P]
 
         x = model.embed(params, tokens[:, None])
-        x, planes, aux = _block_stack(
-            model, params, x, kv_pages.planes(pool), rows, pages[:, None],
-            offs[:, None], pos[:, None], active[:, None], tp_axis,
-            layer_stack,
+        x, cache, aux = _block_stack(
+            model, params, x, kv_pages.contents(pool), slots, rows,
+            pages[:, None], offs[:, None], pos[:, None], active[:, None],
+            tp_axis, layer_stack,
         )
         with jax.named_scope("head"):
             logits = model.unembed(params, x)[:, 0]  # [S, V] fp32
@@ -188,8 +196,8 @@ def make_decode_tick(
                 new_tok = decode_mod.sample_logits(
                     logits, key, temperature, top_k, top_p
                 )
-        pool = kv_pages.with_planes(
-            pool, planes, seq_len=jnp.where(active, pos + 1, pos)
+        pool = kv_pages.with_contents(
+            pool, cache, seq_len=jnp.where(active, pos + 1, pos)
         )
         # decode-step sentinel: a non-finite logit on any ACTIVE slot is
         # the serving analogue of a NaN loss (inactive slots carry
@@ -252,7 +260,9 @@ def make_prefill(
     at once — the block the decode tick runs with one position a row —
     after ONE all-or-nothing page reservation; the logits are taken at
     ``lens - 1`` only.  On exit the target slots are active with
-    ``seq_len = lens`` — exactly the state the next decode tick expects.
+    ``seq_len = lens`` — exactly the state the next decode tick expects;
+    a model that keeps slot state has seated, at ``slot_ids``, each row's
+    state as it stands after the row's last live position.
     What the model's blocks count of the pass, and the probe of the rows
     the first tokens were sampled from, follow the first tokens in the
     same vector (see :func:`make_decode_tick`)."""
@@ -290,9 +300,9 @@ def make_prefill(
         )  # [B, E]
 
         x = model.embed(params, prompts)
-        x, planes, aux = _block_stack(
-            model, params, x, kv_pages.planes(pool), rows, pages, offs,
-            pos, writing, tp_axis,
+        x, cache, aux = _block_stack(
+            model, params, x, kv_pages.contents(pool), slot_ids, rows, pages,
+            offs, pos, writing, tp_axis,
         )
         with jax.named_scope("head"):
             last = jnp.clip(lens - 1 - starts, 0, W - 1)
@@ -306,8 +316,8 @@ def make_prefill(
                     last_logits, key, temperature, top_k, top_p
                 )
         sent = jnp.where(valid_row, slot_ids, n_slots)
-        pool = kv_pages.with_planes(
-            pool, planes,
+        pool = kv_pages.with_contents(
+            pool, cache,
             seq_len=pool["seq_len"].at[sent].set(lens, mode="drop"),
         )
         first, pool = sentinels.guard(
@@ -909,6 +919,15 @@ class ServeEngine:
         # what the model offers the server: planes, block, embed/unembed
         # (raises the seam's one error for a model that offers none)
         self._model = paged_model(cfg)
+        # what would have to restore, split or ship a slot's state is
+        # refused here, by name, for a model that keeps any
+        for feature, asked in (
+            ("the prefix cache (prefix_cache=True)", prefix_cache),
+            ("a drafter (spec_k > 0)", spec_k),
+            ("a tp_axis (tp > 1)", tp > 1),
+        ):
+            if asked:
+                refuse_with_state(cfg, feature)
         self.page_len = page_len
         self.n_pages = n_pages
         self.max_slots = max_slots
@@ -1001,10 +1020,7 @@ class ServeEngine:
                     shard_vocab=False,
                 )
 
-        self.pool = self._place_pool(kv_pages.init_page_pool(
-            cfg, n_pages=n_pages, page_len=page_len, max_slots=max_slots,
-            pages_per_seq=self.pages_per_seq,
-        ))
+        self.pool = self._build_pool(cfg)
 
         def programs(cfg, temperature):
             """(tick, prefill, release) of ``cfg`` under this engine's
@@ -1087,10 +1103,7 @@ class ServeEngine:
             # case (no prefix discount — see _admittable) and both
             # pools are covered by the one bill; drafter writes are
             # bounded by the same per-row limits the verify honors
-            self.draft_pool = self._place_pool(kv_pages.init_page_pool(
-                draft_cfg, n_pages=n_pages, page_len=page_len,
-                max_slots=max_slots, pages_per_seq=self.pages_per_seq,
-            ))
+            self.draft_pool = self._build_pool(draft_cfg)
             if self.tp > 1:
                 progs = _tp_spec_programs(
                     cfg, draft_cfg, self.mesh, k=self.spec_k,
@@ -1202,12 +1215,27 @@ class ServeEngine:
 
     # ---- sharding ------------------------------------------------------
 
-    def _place_pool(self, pool: dict) -> dict:
-        """Place a freshly-built pool on the engine's mesh (each plane
-        split as its model says, accounting replicated) — identity at
-        tp=1, so the single-device path never touches sharding APIs.
+    def _build_pool(self, cfg) -> dict:
+        """An empty pool of this engine's geometry for ``cfg``'s model (the
+        target's or the drafter's), under the span ``serve.pool`` whose
+        stats split its bytes: ``bytes_planes`` grow with the pages,
+        ``bytes_state`` with the slots.  Placed on the engine's mesh (each
+        plane split as its model says, accounting replicated) — identity
+        at tp=1, so the single-device path never touches sharding APIs.
         The drafter's pool has the target's planes (an early exit of the
         same model), so one set of specs places both."""
+        with self._span("serve.pool") as span:
+            pool = kv_pages.init_page_pool(
+                cfg, n_pages=self.n_pages, page_len=self.page_len,
+                max_slots=self.max_slots, pages_per_seq=self.pages_per_seq,
+            )
+            span.add(**{
+                f"bytes_{part}": sum(
+                    self._leaf_bytes(x, False) for x in of(pool).values()
+                )
+                for part, of in (("planes", kv_pages.planes),
+                                 ("state", kv_pages.slot_state))
+            })
         if self.mesh is None:
             return pool
         from jax.sharding import NamedSharding
@@ -1343,10 +1371,7 @@ class ServeEngine:
         # the probe's pool goes before the fresh one is made, so that two
         # pools never stand side by side (the drafter's likewise, below)
         self.pool = None
-        self.pool = self._place_pool(kv_pages.init_page_pool(
-            self.cfg, n_pages=self.n_pages, page_len=self.page_len,
-            max_slots=self.max_slots, pages_per_seq=self.pages_per_seq,
-        ))
+        self.pool = self._build_pool(self.cfg)
         self.queue.clear()
         self.slots = [None] * self.max_slots
         self._slot_last_tok = [0] * self.max_slots
@@ -1364,22 +1389,14 @@ class ServeEngine:
             # accepted round — warm it on a scratch pool (all-padding
             # args: active is all False, nothing mutates) so the first
             # full accept mid-run never pays XLA on the wall clock
-            scratch = self._place_pool(kv_pages.init_page_pool(
-                self.draft_cfg, n_pages=self.n_pages,
-                page_len=self.page_len, max_slots=self.max_slots,
-                pages_per_seq=self.pages_per_seq,
-            ))
+            scratch = self._build_pool(self.draft_cfg)
             self._draft_k1(
                 self.draft_params, scratch,
                 jnp.zeros((self.max_slots, 2), jnp.int32),
                 jnp.zeros((self.max_slots,), jnp.int32),
                 jnp.zeros((self.max_slots,), jnp.int32),
             )
-            self.draft_pool = self._place_pool(kv_pages.init_page_pool(
-                self.draft_cfg, n_pages=self.n_pages,
-                page_len=self.page_len, max_slots=self.max_slots,
-                pages_per_seq=self.pages_per_seq,
-            ))
+            self.draft_pool = self._build_pool(self.draft_cfg)
         if self.prefix is not None:  # drop the probe's cached prompt
             self.prefix = PrefixCache(self.page_len)
             # compile the sharing ops at the exact shapes the engine
@@ -1708,6 +1725,17 @@ class ServeEngine:
         t0 = time.perf_counter()
         for key in ("prompt_tokens", "scanned_positions"):
             self._sample(f"serve.prefill.{key}", counts[key], t0)
+        # what the model counts of the pass from the rows' lengths alone,
+        # and the rows whose slot state the pass seats (late stats below)
+        seated: dict[str, int] = {}
+        if self._model.prompt_pass_counts is not None:
+            seated = dict(self._model.prompt_pass_counts(
+                lens[: len(batch)] - starts[: len(batch)], B, width
+            ))
+            for name, value in seated.items():
+                self._sample(f"serve.{name}", value, t0)
+        if self._model.slot_state:
+            seated["state_rows"] = len(batch)
         with self._span(
             "serve.prefill", **counts,
             rids=" ".join(str(req.rid) for _, req, _ in batch),
@@ -1720,6 +1748,9 @@ class ServeEngine:
             )
             # one fetch: the sampled tokens and what rides behind them
             first, probe = self._split_pass(span, jax.device_get(first), B, t0)
+            span.add(**{
+                name.rsplit(".", 1)[-1]: v for name, v in seated.items()
+            })
         if not bool(ok):
             self.pool_ok_failures += 1
         if self.spec_k:
@@ -2111,6 +2142,7 @@ class ServeEngine:
         accepted-then-lost request is therefore impossible by
         construction — the ``--check-reshape`` gate pins the count at
         zero anyway."""
+        refuse_with_state(self.cfg, "the elastic hand-off (begin_drain)")
         self.draining = True
         handoff = list(self.queue)
         self.queue.clear()
@@ -2215,7 +2247,8 @@ class ServeEngine:
     def memory_bill(self, per_chip: bool = True) -> dict[str, Any]:
         """:meth:`mem_budget_bytes` by part: ``weights`` (the drafter's
         too under spec) as ``{dtype: bytes}`` of what the engine holds,
-        the resident tree and no master, ``pool`` and ``total``."""
+        the resident tree and no master, ``pool`` (``bytes_state`` of it
+        the model's slot state) and ``total``."""
         weights: dict[str, int] = {}
         for t in [self.params] + ([self.draft_params] if self.spec_k else []):
             for x in jax.tree.leaves(t):
@@ -2223,12 +2256,18 @@ class ServeEngine:
                 weights[name] = (
                     weights.get(name, 0) + self._leaf_bytes(x, per_chip)
                 )
+        pools = [self.pool] + ([self.draft_pool] if self.spec_k else [])
         pool = sum(
             self._leaf_bytes(x, per_chip)
-            for t in [self.pool] + ([self.draft_pool] if self.spec_k else [])
-            for x in jax.tree.leaves(t)
+            for t in pools for x in jax.tree.leaves(t)
         )
         return {"weights": weights, "pool": pool,
+                # the part of ``pool`` that grows with the slots and not
+                # with the pages: the model's slot state
+                "bytes_state": sum(
+                    self._leaf_bytes(x, per_chip)
+                    for t in pools for x in kv_pages.slot_state(t).values()
+                ),
                 "total": sum(weights.values()) + pool}
 
     def mem_budget_bytes(self, per_chip: bool = True) -> int:

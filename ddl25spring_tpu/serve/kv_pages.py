@@ -19,7 +19,18 @@ alternative, TPU-first (every operation static-shaped under jit):
   hands them :func:`accounting` alone and copies none.  The LAST row is
   a trash page: masked writes (inactive slots, padded prefill rows) land
   there instead of corrupting live pages, so no ``lax.cond`` is ever
-  needed on the write path.
+  needed on the write path.  A plane has as many layer rows as the model
+  says hold it (``PagedModel.plane_layers``; every layer, unless it says).
+- **slot state**: what a model keeps a SEQUENCE and not a position
+  (``PagedModel.slot_state``: a recurrent layer's state), one array
+  ``[max_slots, state_layers, *shape]`` a name under the pool's one key
+  :data:`SLOT_STATE`, absent for a model that declares none.  It is not
+  accounting (no accounting op takes it) and not a plane: it has no page,
+  no reference count and one version, the newest.  :func:`contents` hands
+  a pass both, :func:`with_contents` takes both back, and no op here
+  learns what a state means: a pass overwrites a slot's rows (a prompt
+  pass seats them, a tick updates them), and a released slot's rows are
+  dead until the next prompt pass seats that slot again.
 - **page tables** ``[max_slots, pages_per_seq]`` int32 — slot s's page
   ``j`` holds its positions ``[j*page_len, (j+1)*page_len)``; ``-1``
   marks an unassigned entry.
@@ -75,6 +86,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # shared head-count validation with the dense cache layout — defined in
 # models/ (the layer below) so the dependency points downward only
@@ -86,13 +98,16 @@ Pool = dict[str, Any]
 __all__ = [
     "resolve_heads", "init_page_pool", "pool_geometry", "reserve_pages",
     "write_page_ids", "write_planes", "gather_planes", "planes",
-    "with_planes", "page_len_of", "ACCOUNTING", "accounting",
+    "with_planes", "slot_state", "contents", "with_contents", "page_len_of",
+    "ACCOUNTING", "SLOT_STATE", "accounting",
     "release_slots", "activate_slots", "used_pages",
     "adopt_prefix", "ref_pages", "unref_pages", "truncate_to",
 ]
 
-# the pool's bookkeeping entries; every other entry is a plane
+# the pool's bookkeeping entries; every other entry is a plane, but for
+# the one entry that holds the model's slot state (a dict of arrays)
 ACCOUNTING = ("page_table", "seq_len", "active", "free", "refcount")
+SLOT_STATE = "slot_state"
 
 
 def accounting(pool: Pool) -> Pool:
@@ -105,11 +120,37 @@ def accounting(pool: Pool) -> Pool:
 def planes(pool: Pool) -> Pool:
     """The page contents of ``pool``: ``{name: [n_pages + 1, L, page_len,
     ...]}``, whatever the model named them."""
-    return {k: v for k, v in pool.items() if k not in ACCOUNTING}
+    return {
+        k: v for k, v in pool.items()
+        if k not in ACCOUNTING and k != SLOT_STATE
+    }
 
 
 def with_planes(pool: Pool, new_planes: Pool, **accounting) -> Pool:
     return {**pool, **new_planes, **accounting}
+
+
+def slot_state(pool: Pool) -> Pool:
+    """What ``pool`` keeps a slot: ``{name: [max_slots, state_layers,
+    ...]}``; empty for a model that declares none."""
+    return pool.get(SLOT_STATE, {})
+
+
+def contents(pool: Pool) -> Pool:
+    """All that a pass of the model reads and writes of ``pool``: its
+    planes and, beside them under their own names, its slot state."""
+    return {**planes(pool), **slot_state(pool)}
+
+
+def with_contents(pool: Pool, new: Pool, **accounting) -> Pool:
+    """``pool`` with the planes and the slot state of ``new`` (as
+    :func:`contents` names them) and the given accounting entries."""
+    kept = slot_state(pool)
+    out = {**pool, **{k: v for k, v in new.items() if k not in kept},
+           **accounting}
+    if kept:
+        out[SLOT_STATE] = {k: new[k] for k in kept}
+    return out
 
 
 def page_len_of(pool: Pool) -> int:
@@ -130,31 +171,51 @@ def init_page_pool(
     ``planes``: ``{name: trailing shape of one position}``, with ``cfg``
     giving ``n_layers`` and ``dtype``).  Every plane carries ``n_pages +
     1`` rows — row ``n_pages`` is the trash page masked writes target; it
-    is never entered into a page table and never counted as capacity."""
+    is never entered into a page table and never counted as capacity —
+    and the layers that hold it; the model's slot state, where it
+    declares any, stands beside them (:data:`SLOT_STATE`), zeroed."""
     if n_pages < 1 or page_len < 1 or max_slots < 1 or pages_per_seq < 1:
         raise ValueError(
             f"n_pages={n_pages}, page_len={page_len}, "
             f"max_slots={max_slots}, pages_per_seq={pages_per_seq}: "
             "every pool dimension must be >= 1"
         )
+    state: dict[str, tuple] = {}
     if planes is None:
         model = paged_model(cfg)
-        planes, n_layers, dtype = model.planes, model.n_layers, model.dtype
+        planes, dtype = model.planes, model.dtype
+        layers_of = model.layers_of
+        if model.slot_state:
+            state = {
+                name: ((max_slots, model.state_layers, *shape), kind)
+                for name, (shape, kind) in model.slot_state.items()
+            }
     else:
-        n_layers, dtype = cfg.n_layers, cfg.dtype
-    clash = sorted(set(planes) & set(ACCOUNTING))
+        dtype = cfg.dtype
+
+        def layers_of(_name):
+            return cfg.n_layers
+    clash = sorted(
+        (set(planes) | set(state)) & {*ACCOUNTING, SLOT_STATE}
+    ) + sorted(set(planes) & set(state))
     if not planes or clash:
         raise ValueError(
-            f"planes={dict(planes)}: a pool needs at least one plane, and "
-            f"none named like its accounting {ACCOUNTING}"
+            f"planes={dict(planes)}, slot state {sorted(state)}: a pool "
+            "needs at least one plane, no two entries of one name, and "
+            f"none named like its accounting {ACCOUNTING} or {SLOT_STATE!r}"
         )
     return {
         **{
             name: jnp.zeros(
-                (n_pages + 1, n_layers, page_len, *shape), jnp.dtype(dtype)
+                (n_pages + 1, layers_of(name), page_len, *shape),
+                jnp.dtype(dtype),
             )
             for name, shape in planes.items()
         },
+        **({SLOT_STATE: {
+            name: jnp.zeros(shape, jnp.dtype(kind))
+            for name, (shape, kind) in state.items()
+        }} if state else {}),
         "page_table": jnp.full((max_slots, pages_per_seq), -1, jnp.int32),
         "seq_len": jnp.zeros((max_slots,), jnp.int32),
         "active": jnp.zeros((max_slots,), bool),
@@ -177,6 +238,11 @@ def pool_geometry(pool: Pool) -> dict[str, int]:
         "max_slots": max_slots,
         "pages_per_seq": pages_per_seq,
         "max_seq_len": pages_per_seq * page_len,
+        # what a slot holds whatever its length: bytes of slot state
+        "slot_state_bytes": sum(
+            x.dtype.itemsize * int(np.prod(x.shape[1:]))
+            for x in slot_state(pool).values()
+        ),
     }
 
 
@@ -319,6 +385,10 @@ def adopt_prefix(pool: Pool, slots: jax.Array, adopt_pages: jax.Array,
       never written.  Two rows COWing the same source each get their
       own copy.
 
+    Only pages are shared: slot state (:data:`SLOT_STATE`) has no version
+    at the matched position to seat, so a model that keeps any is refused
+    the prefix cache where the engine is built (``refuse_with_state``).
+
     ``slots[b] < 0`` marks a padding row.  Returns ``(pool, ok)`` —
     all-or-nothing like :func:`reserve_pages`: when the COW pages don't
     fit the free set, NOTHING is adopted and ``ok`` is False (the
@@ -397,6 +467,10 @@ def truncate_to(pool: Pool, new_lens: jax.Array, mask: jax.Array,
     that slot (the drafter pool rides the same call as the target pool
     with the target's rollback length; on a fully-accepted round the
     drafter has nothing to drop).
+
+    Only the page frontier rolls back: slot state was overwritten by the
+    rejected positions and cannot be restored, so a model that keeps any
+    is refused a drafter where the engine is built.
 
     ``page_len`` is read off a plane unless the caller states it, as a
     caller that hands over the accounting arrays alone must."""

@@ -203,8 +203,9 @@ def _position_step(cfg: LlamaConfig, tp_axis: str | None):
 
         x = model.embed(params, tok[:, None])
         x, planes, _aux = _block_stack(
-            model, params, x, kv_pages.planes(pool), rows, pages[:, None],
-            offs[:, None], pos[:, None], writing[:, None], tp_axis,
+            model, params, x, kv_pages.planes(pool), slots, rows,
+            pages[:, None], offs[:, None], pos[:, None], writing[:, None],
+            tp_axis,
         )
         with jax.named_scope("head"):
             logits = model.unembed(params, x)[:, 0]  # [S, V] fp32

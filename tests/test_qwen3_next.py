@@ -1,0 +1,554 @@
+"""The ``qwen3next`` family at small sizes on the CPU, in float32: the gated
+delta rule in its three forms (chunked prompt pass, one-step, the kernel in
+interpret mode) with ragged rows, the gated attention block, the expert
+layer that drops nothing at top-10, the share of a four-chip deployment, and
+the whole model through the paged engine's pages AND slot state, each
+against the ONE plain reference (``benchmark/reference_qwen3next.py``, token
+serial in its recurrence, loaded with its family file through
+``benchmark.run.load_module``)."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import run as bench_run
+from ddl25spring_tpu.models import qwen3_next as qn
+from ddl25spring_tpu.ops.gdn import gdn_step
+from ddl25spring_tpu.serve import kv_pages
+from ddl25spring_tpu.serve.engine import ServeEngine
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
+FAMILY = bench_run.load_module(BENCH, "families", "qwen3next")
+REF = FAMILY.reference
+PUBLISHED = bench_run.load_json(
+    os.path.join(BENCH, "configs", "qwen3-next-80b-ep4.json")
+)
+PAGE = 4
+
+
+def tiny_config(held=4, offset=0, router=16, layers=8, **more):
+    """The published configuration with every width shrunk: same keys, same
+    structure (3 linear layers then a full one, two value heads a key head,
+    rotary on a quarter of a head, top-k of a wider router, a share of the
+    experts), and the depth the cell serves."""
+    config = dict(
+        PUBLISHED, hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, num_experts=held,
+        num_experts_per_tok=3, num_hidden_layers=layers, vocab_size=64,
+        run={"dtype": "float32", "state_dtype": "float32"},
+        published=dict(PUBLISHED["published"], num_experts=router),
+        deployment=dict(PUBLISHED["deployment"], expert_offset=offset),
+    )
+    config.update(more)
+    return config
+
+
+def build(**more):
+    """A tiny configuration whose prompt pass has several chunks."""
+    return dataclasses.replace(FAMILY.build(tiny_config(**more)), gdn_chunk=4)
+
+
+def seeded(cfg, seed):
+    """The family's weights with every norm scale and the decay's two
+    parameters DRAWN, so that the ``1 + w`` form, the plain scale of the
+    gated norm and ``dt_bias`` are each held to the reference."""
+    params = FAMILY.init_params(cfg, seed)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
+
+    def jitter(tree, names):
+        return {k: (v + 0.3 * jax.random.normal(next(keys), v.shape)
+                    if k in names else v) for k, v in tree.items()}
+
+    blocks = params["blocks"]
+    return {**params, "ln_f": jitter(params, {"ln_f"})["ln_f"], "blocks": {
+        "lin": [jitter(layer, {"ln1", "o_norm", "dt_bias"}) for layer in blocks["lin"]],
+        "full": jitter(blocks["full"], {"ln1", "q_norm", "k_norm"}),
+        "moe": [jitter(layer, {"ln2"}) for layer in blocks["moe"]],
+    }}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _rings_are_this_files():
+    """The program's rings are global to the process: other files window
+    them from time 0 (``tests/test_mistral4.py`` its ``serve.moe.*``), so
+    this one leaves none behind, and starts from none."""
+    from ddl25spring_tpu import obs
+
+    obs.counters.reset()
+    yield
+    obs.counters.reset()
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = build()
+    return cfg, seeded(cfg, 3)
+
+
+def f32(tree, *index):
+    return jax.tree.map(lambda a: a[index].astype(jnp.float32), tree)
+
+
+def empty_cache(cfg, slots, pages=3):
+    return kv_pages.contents(kv_pages.init_page_pool(
+        cfg, n_pages=pages, page_len=PAGE, max_slots=slots, pages_per_seq=pages))
+
+
+def test_published_widths_build_and_refusals_hold():
+    cfg = FAMILY.build(PUBLISHED)
+    assert (cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim, cfg.rotary_dim, cfg.linear_num_key_heads,
+            cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, cfg.linear_conv_kernel_dim,
+            cfg.conv_channels, cfg.moe_intermediate_size,
+            cfg.shared_expert_intermediate_size) == (
+                2048, 16, 2, 256, 64, 16, 32, 128, 128, 4, 8192, 512, 512)
+    assert (cfg.num_experts, cfg.n_held, cfg.num_experts_per_tok,
+            cfg.vocab_size, cfg.gdn_chunk) == (512, 128, 10, 37984, 64)
+    assert cfg.n_layers >= 4 and cfg.n_layers == 4 * cfg.n_units
+    m = cfg.paged_model()
+    assert dict(m.planes) == {"k": (2, 256), "v": (2, 256)}
+    # the planes are the full layers' alone; the others keep a slot of state
+    assert (m.layers_of("k"), m.state_layers, m.n_units) == (2, 6, 2)
+    assert dict(m.slot_state) == {
+        "S": ((32, 128, 128), "float32"), "conv": ((3, 8192), "bfloat16")}
+    with pytest.raises(ValueError, match="mlp_only_layers"):
+        FAMILY.build(dict(PUBLISHED, mlp_only_layers=[1]))
+    with pytest.raises(ValueError, match="rope_scaling"):
+        FAMILY.build(dict(PUBLISHED, rope_scaling={"type": "yarn"}))
+    with pytest.raises(ValueError, match="whole periods"):
+        FAMILY.build(PUBLISHED, n_layers=6)
+    with pytest.raises(NotImplementedError, match="served only"):
+        FAMILY.train_flops_per_token(cfg)
+
+
+def test_pool_holds_planes_by_layer_kind_and_a_slot_of_state(model):
+    cfg, _ = model
+    pool = kv_pages.init_page_pool(
+        cfg, n_pages=5, page_len=PAGE, max_slots=3, pages_per_seq=4)
+    assert {k: v.shape for k, v in kv_pages.planes(pool).items()} == {
+        "k": (6, 2, PAGE, 2, 8), "v": (6, 2, PAGE, 2, 8)}
+    assert {k: (v.shape, v.dtype.name) for k, v in
+            kv_pages.slot_state(pool).items()} == {
+        "S": ((3, 6, 4, 8, 8), "float32"), "conv": ((3, 6, 3, 64), "float32")}
+    assert set(kv_pages.contents(pool)) == {"k", "v", "S", "conv"}
+    # no accounting program may take the state as an argument
+    assert kv_pages.SLOT_STATE not in kv_pages.accounting(pool)
+    assert kv_pages.page_len_of(pool) == PAGE
+    geometry = kv_pages.pool_geometry(pool)
+    assert geometry["slot_state_bytes"] == 6 * (4 * 8 * 8 + 3 * 64) * 4
+    back = kv_pages.with_contents(
+        pool, {k: v + 1 for k, v in kv_pages.contents(pool).items()})
+    assert set(back) == set(pool)
+    assert float(kv_pages.slot_state(back)["S"].min()) == 1.0
+    assert float(back["k"].min()) == 1.0
+
+
+LENS = (11, 3, 0, 7)  # ragged rows of one batch; a padding row among them
+
+
+@pytest.fixture(scope="module")
+def delta(model):
+    """One linear layer (period 1, its 2nd) over a ragged batch in ONE
+    prompt pass, and the reference's token-serial walk of each row."""
+    cfg, params = model
+    u, j, T = 1, 1, 12
+    layer = u * cfg.n_linear + j
+    p = jax.tree.map(lambda a: a[u], params["blocks"]["lin"][j])
+    x = jax.random.normal(jax.random.PRNGKey(1), (len(LENS), T, cfg.hidden_size))
+    lens = jnp.asarray(LENS)
+    live = jnp.arange(T)[None, :] < lens[:, None]
+    slots = jnp.asarray([2, 0, -1, 3])
+    out, cache = jax.jit(lambda x, cache: qn.gdn_mixer(
+        p, x, cache, layer, slots, live, cfg))(x, empty_cache(cfg, 4))
+    refs = []
+    with jax.default_matmul_precision("highest"):
+        for b, n in enumerate(LENS):
+            refs.append(REF.delta_rule(f32(params["blocks"]["lin"][j], u),
+                                       x[b, :n], FAMILY._w(cfg)) if n else None)
+    return cfg, p, layer, x, slots, out, cache, refs
+
+
+def test_chunked_prompt_pass_matches_the_token_serial_reference(delta):
+    cfg, _, layer, _, slots, out, cache, refs = delta
+    for b, n in enumerate(LENS):
+        if not n:
+            continue
+        want, S, tail = refs[b]
+        np.testing.assert_allclose(out[b, :n], want, atol=3e-5)
+        # what is seated is the state after the row's LAST LIVE token
+        np.testing.assert_allclose(cache["S"][slots[b], layer], S, atol=3e-5)
+        np.testing.assert_allclose(cache["conv"][slots[b], layer], tail, atol=1e-6)
+    # the padding row seated nothing, and no other layer or slot was touched
+    untouched = np.ones(cache["S"].shape[:2], bool)
+    untouched[[2, 0, 3], layer] = False
+    assert float(jnp.abs(cache["S"][untouched]).max()) == 0.0
+    assert float(jnp.abs(cache["conv"][untouched]).max()) == 0.0
+
+
+@pytest.mark.parametrize("form", ["one step", "kernel"])
+def test_one_step_and_kernel_match_the_token_serial_reference(model, delta, form):
+    """Decode continues each row from the state its prompt pass seated: the
+    ``T = 1`` form through the kernel (interpret mode), one token a slot
+    with a dead slot among them, against the reference's walk of the row
+    plus the new tokens; and the kernel alone against the same step written
+    in ``jax.numpy``."""
+    cfg, params = model
+    _, p, layer, x, slots, _, cache, _ = delta
+    if form == "kernel":
+        ks = jax.random.split(jax.random.PRNGKey(7), 6)
+        S, H, dk, dv = 5, 4, 8, 8
+        state = jax.random.normal(ks[0], (S, 3, H, dk, dv))
+        q, k = (jax.random.normal(ks[i], (S, H, dk)) for i in (1, 2))
+        v = jax.random.normal(ks[3], (S, H, dv))
+        g, beta = -jax.random.uniform(ks[4], (S, H)), jax.random.uniform(ks[5], (S, H))
+        live = jnp.asarray([True, False, True, True, False])
+        o, new = gdn_step(state, 1, q, k, v, g, beta, live)
+        s = state[:, 1] * jnp.exp(g)[..., None, None]
+        u = beta[..., None] * (v - jnp.einsum("shkv,shk->shv", s, k))
+        s = s + jnp.einsum("shk,shv->shkv", k, u)
+        want = jnp.einsum("shkv,shk->shv", s, q)
+        np.testing.assert_allclose(o, jnp.where(live[:, None, None], want, 0), atol=1e-5)
+        np.testing.assert_allclose(new[live, 1], s[live], atol=1e-5)
+        # a dead slot, and every other layer, is bit for bit what it was
+        assert jnp.array_equal(new[~live], state[~live])
+        assert jnp.array_equal(new[:, [0, 2]], state[:, [0, 2]])
+        return
+    more = jax.random.normal(jax.random.PRNGKey(2), (4, 5, cfg.hidden_size))
+    step = jax.jit(lambda x, cache, live: qn.gdn_mixer(
+        p, x, cache, layer, jnp.arange(4), live, cfg))
+    by_slot = {int(s): b for b, s in enumerate(slots) if s >= 0}  # slot -> row
+    live = jnp.asarray([[s in by_slot] for s in range(4)])
+    outs = []
+    for t in range(5):
+        xs = jnp.stack([more[by_slot.get(s, 0), t] for s in range(4)])[:, None]
+        o, cache = step(xs, cache, live)
+        outs.append(o)
+    outs = jnp.concatenate(outs, axis=1)  # [slot, 5, D]
+    lin = f32(params["blocks"]["lin"][1], 1)
+    with jax.default_matmul_precision("highest"):
+        for s, b in by_slot.items():
+            n = LENS[b]
+            whole = jnp.concatenate([x[b, :n], more[b]])
+            want, S, tail = REF.delta_rule(lin, whole, FAMILY._w(cfg))
+            np.testing.assert_allclose(outs[s], want[n:], atol=5e-5)
+            np.testing.assert_allclose(cache["S"][s, layer], S, atol=5e-5)
+            np.testing.assert_allclose(cache["conv"][s, layer], tail, atol=1e-6)
+    assert float(jnp.abs(cache["S"][1]).max()) == 0.0  # the dead slot
+
+
+def test_gated_attention_matches_the_reference_in_both_widths(model):
+    """GQA, rotary on a quarter of a head, ``1 + w`` norms and the sigmoid
+    gate: all positions in one pass, and one position at a time."""
+    cfg, params = model
+    S, pages, u = 12, 3, 1
+    p = jax.tree.map(lambda a: a[u], params["blocks"]["full"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, S, cfg.hidden_size))
+    rows = jnp.arange(pages)[None, :]
+    pos = jnp.arange(S)[None, :]
+
+    @jax.jit  # traced twice: once a width (T = S, then T = 1)
+    def attend(x, cache, at):
+        cos, sin = qn.rope_tables(at, cfg)
+        return qn.gated_attention(
+            p, x, cache, u, rows, at // PAGE, at % PAGE, at, cos, sin, cfg)
+
+    batch, cache = attend(x, empty_cache(cfg, 1), pos)
+    assert float(jnp.abs(cache["k"][:, 0]).max()) == 0.0  # the other full layer
+    cache, steps = empty_cache(cfg, 1), []
+    for t in range(S):
+        out, cache = attend(x[:, t:t + 1], cache, pos[:, t:t + 1])
+        steps.append(out)
+    with jax.default_matmul_precision("highest"):
+        ref = REF.attention(f32(params["blocks"]["full"], u), x[0], FAMILY._w(cfg))
+    np.testing.assert_allclose(batch[0], ref, atol=3e-5)
+    np.testing.assert_allclose(jnp.concatenate(steps, axis=1)[0], ref, atol=3e-5)
+
+
+def test_expert_layer_matches_the_reference_at_top_10_of_a_wider_router(model):
+    cfg, params = model
+    u, j, li = 1, 2, 6
+    p = jax.tree.map(lambda a: a[u], params["blocks"]["moe"][j])
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 9, cfg.hidden_size))
+    live = jnp.ones((2, 9), bool)
+    got, load = qn.moe_ffn(p, x, live, params["experts"], li, cfg)
+    with jax.default_matmul_precision("highest"):
+        ref, _gap = REF.experts(
+            f32(params["blocks"]["moe"][j], u), params["experts"], li,
+            x.reshape(18, -1), FAMILY._w(cfg), FAMILY._held(cfg))
+    np.testing.assert_allclose(got.reshape(18, -1), ref, atol=3e-5)
+    # 18 rows x top-3 over 16 experts of which 4 are held: about a quarter
+    assert 0 < int(load.sum()) < 54 and load.shape == (cfg.n_held,)
+    _, none = qn.moe_ffn(p, x, ~live, params["experts"], li, cfg)
+    assert int(none.sum()) == 0
+
+
+@pytest.mark.parametrize("case", ["all_on_one_held_expert", "none_held"])
+def test_expert_layer_drops_nothing_at_any_imbalance_at_top_10(case):
+    """No capacity at the published top-10: every one of 10 N assignments on
+    ONE held expert is legal and exact; none held gives exactly zero."""
+    from ddl25spring_tpu.models.routed_experts import routed_experts
+
+    cfg = build(num_experts_per_tok=10)
+    params = FAMILY.init_params(cfg, 4)
+    N, k, e = 24, 10, 2
+    h2 = jax.random.normal(jax.random.PRNGKey(4), (N, cfg.hidden_size))
+    weights = jax.random.uniform(jax.random.PRNGKey(5), (N, k))
+    chosen = e if case == "all_on_one_held_expert" else cfg.n_held + 1
+    y, load = routed_experts(
+        h2, jnp.full((N, k), chosen, jnp.int32), weights, jnp.ones(N, bool),
+        params["experts"], 5, cfg)
+    assert np.isfinite(np.asarray(y)).all()
+    if case == "none_held":
+        assert int(load.sum()) == 0 and float(jnp.abs(y).max()) == 0.0
+        return
+    assert load.tolist() == [0, 0, N * k, 0]
+    with jax.default_matmul_precision("highest"):
+        one = REF.swiglu(h2, *(params["experts"][n][5, e]
+                               for n in ("w_gate", "w_up", "w_down")))
+    np.testing.assert_allclose(y, weights.sum(-1, keepdims=True) * one, atol=3e-5)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """What each of four chips computes for its 4 of 16 experts, the gated
+    shared expert (and the mixer before it) counted once, is the uncut
+    reference's whole layer."""
+    whole = build(held=16)
+    params = seeded(whole, 11)
+    u, j, li = 0, 1, 1
+    p = jax.tree.map(lambda a: a[u], params["blocks"]["moe"][j])
+    pf, w = f32(params["blocks"]["moe"][j], u), FAMILY._w(whole)
+    lin = f32(params["blocks"]["lin"][j], u)
+    x0 = jax.random.normal(jax.random.PRNGKey(6), (1, 16, whole.hidden_size))
+    with jax.default_matmul_precision("highest"):
+        mixed, _, _ = REF.delta_rule(lin, x0[0], w)  # every chip computes it alike
+        uncut, _ = REF.experts(pf, params["experts"], li, mixed, w, (0, 16))
+        h2 = REF.norm(mixed, pf["ln2"], w["rms_norm_eps"])
+        shared = jax.nn.sigmoid(h2 @ pf["w_sg"])[:, None] * REF.swiglu(
+            h2, pf["ws_gate"], pf["ws_up"], pf["ws_down"])
+    x = mixed[None]
+    routed = jnp.zeros_like(uncut)
+    for chip in range(4):
+        cfg = dataclasses.replace(whole, experts_held=4, expert_offset=4 * chip)
+        stacks = {n: a[:, 4 * chip:4 * chip + 4] for n, a in params["experts"].items()}
+        out, load = jax.jit(lambda stacks, cfg=cfg: qn.moe_ffn(
+            p, x, jnp.ones((1, 16), bool), stacks, li, cfg))(stacks)
+        assert 0 < int(load.sum()) <= 3 * 16
+        routed = routed + (out[0] - x[0] - shared)
+    np.testing.assert_allclose(x[0] + routed + shared, uncut, atol=5e-5)
+
+
+def engine(cfg, params, **more):
+    kw = dict(page_len=PAGE, n_pages=96, max_slots=4, pages_per_seq=12,
+              prefill_batch=2, max_prompt_len=12, clock="virtual", logit_probe=16)
+    return ServeEngine(params, cfg, **{**kw, **more})
+
+
+def drain(eng, requests):
+    for prompt, max_new in requests:
+        assert eng.submit(eng.make_request(prompt, max_new)) is None
+    while not eng.drained:
+        eng.step()
+    assert eng.mem_leak_check()["ok"] and eng.pool_ok_failures == 0
+    return [(r.prompt, r.tokens) for r in eng.done]
+
+
+@pytest.fixture(scope="module")
+def served(model):
+    """Six requests of ragged lengths through an engine of four slots: the
+    last two are seated in slots that earlier requests released."""
+    from ddl25spring_tpu.obs.counters import counters
+
+    cfg, params = model
+    eng = engine(cfg, params)
+    eng.warmup()
+    counters.reset()  # the rings below hold this engine's passes alone
+    rng = np.random.default_rng(0)
+    done = drain(eng, [(rng.integers(1, 64, n).tolist(), new) for n, new in
+                       ((9, 20), (5, 33), (12, 9), (3, 33), (10, 24), (7, 30))])
+    rings = {name: counters.window(f"serve.{name}", 0.0, float("inf"))
+             for name in ("gdn.chunks_live", "gdn.chunks_scanned",
+                          "moe.experts_hit")}
+    return eng, done, rings
+
+
+def test_prefill_then_decode_through_pages_and_slot_state_matches_the_reference(
+        model, served):
+    cfg, params = model
+    eng, done, _ = served
+    assert sorted(len(t) for _, t in done) == [9, 20, 24, 30, 33, 33]
+    out = FAMILY.check_served(cfg, params, done, pad_to=eng.max_seq_len)
+    assert out["ok"], out
+    assert out["tokens_checked"] == 149 and out["probe_ids"] == 16
+    # float32 against float32: 2e-6 in the median; single decode positions
+    # read up to 6e-3 and the next ones fall back.  Those are positions at
+    # which rounding is amplified, not a fault of a path: there the chunked
+    # prompt pass over the same tokens, the one-step path and the reference
+    # lie 2e-3 to 6e-3 from one another, all three (a mis-seated state or a
+    # stale tail reads 0.1 and stays)
+    assert out["logit_rel_err"] < 2e-2 and out["logit_rel_err_near_tie"] < 2e-2
+    assert out["logit_rel_err_p50"] < 2e-5
+    assert out["worst_margin"] == 0.0  # float32: the reference's own argmax
+    assert set(kv_pages.planes(eng.pool)) == {"k", "v"}
+    assert set(kv_pages.slot_state(eng.pool)) == {"S", "conv"}
+
+
+def test_a_readmitted_slot_starts_from_the_new_prompts_state(model, served):
+    """Six requests through four slots: two ran in a slot another request
+    had left its state in, and each matched the reference on its own; and a
+    request served alone in a fresh engine gives the same logits as it did
+    in the slot it inherited."""
+    cfg, params = model
+    _, done, _ = served
+    prompt, tokens = done[-1]
+    alone = drain(engine(cfg, params), [(prompt, len(tokens))])[0][1]
+    assert list(alone) == list(tokens)
+    np.testing.assert_allclose(np.asarray(alone.probe), np.asarray(tokens.probe),
+                               atol=1e-5)
+
+
+def test_engine_counts_chunks_state_rows_and_the_state_in_its_bill(served):
+    eng, _, rings = served
+    live, scanned = rings["gdn.chunks_live"], rings["gdn.chunks_scanned"]
+    n = len(live)
+    assert n >= 3 and len(scanned) == n
+    for (_, a), (_, b) in zip(live, scanned):
+        # 2 rows x width 3..12 in chunks of 4: 2..6 scanned, 1..6 of them live
+        assert 1 <= a <= b <= 6 and b % 2 == 0
+    hit = rings["moe.experts_hit"]  # a sample a pass, tick or prompt
+    assert len(hit) > n and all(0 <= v <= 8 * 4 for _, v in hit)
+    bill = eng.memory_bill()
+    state = kv_pages.pool_geometry(eng.pool)["slot_state_bytes"] * eng.max_slots
+    assert bill["bytes_state"] == state > 0 and bill["pool"] > state
+    assert bill["total"] == sum(bill["weights"].values()) + bill["pool"]
+
+
+def test_prefill_span_carries_the_late_stats(model):
+    """``state_rows`` and the two chunk counts are late stats of
+    ``serve.prefill``; the span that builds the pool splits its bytes."""
+    from ddl25spring_tpu import obs
+
+    cfg, params = model
+    rec = obs.SpanRecorder()
+    old = obs.set_recorder(rec)
+    try:
+        with obs.scoped(True):
+            drain(engine(cfg, params), [([3, 4, 5, 6, 7], 2), ([8, 9], 2)])
+    finally:
+        obs.set_recorder(old)
+
+    def stats(name):
+        return [e.get("args", {}) for e in rec.to_chrome_trace()["traceEvents"]
+                if e["name"] == name]
+
+    (late,) = stats("serve.prefill")
+    assert late["state_rows"] == 2
+    assert (late["chunks_live"], late["chunks_scanned"]) == (3, 4)
+    pool = stats("serve.pool")
+    assert pool and pool[0]["bytes_state"] > 0 and pool[0]["bytes_planes"] > 0
+
+
+@pytest.mark.parametrize("feature, kw", [
+    ("prefix cache", dict(prefix_cache=True)),
+    ("drafter", dict(spec_k=2, logit_probe=0)),
+    ("tp_axis", dict(tp=2, logit_probe=0)),
+    ("hand-off", None),
+])
+def test_engine_refuses_what_cannot_carry_the_state(model, feature, kw):
+    cfg, params = model
+    if kw is None:
+        with pytest.raises(NotImplementedError, match="hand-off.*PERF.md section 7"):
+            engine(cfg, params).begin_drain()
+        return
+    with pytest.raises(NotImplementedError, match=f"{feature}.*PERF.md section 7"):
+        engine(cfg, params, **kw)
+
+
+def to_8_bits(params):
+    """``params`` with the experts' weights (routed and shared) at e4m3's 3
+    mantissa bits."""
+    def low(a):
+        return jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=3)
+    out = dict(params, experts=jax.tree.map(low, params["experts"]))
+    out["blocks"] = dict(params["blocks"], moe=[
+        {k: low(v) if k.startswith("ws_") else v for k, v in layer.items()}
+        for layer in params["blocks"]["moe"]])
+    return out
+
+
+def serve_whole(cfg, params):
+    eng = engine(cfg, params, prefill_batch=4)
+    rng = np.random.default_rng(0)
+    done = drain(eng, [(rng.integers(1, 64, n).tolist(), 36) for n in (9, 10, 11, 12)])
+    return eng.max_seq_len, done
+
+
+@pytest.fixture(scope="module")
+def whole_served():
+    """A chip that holds ALL 16 experts of a 16-wide router, so that the
+    experts are as large a part of its result as they can be."""
+    cfg = build(held=16)
+    params = FAMILY.init_params(cfg, 5)
+    return cfg, params, *serve_whole(cfg, params)
+
+
+@pytest.mark.parametrize("control", [
+    "none", "8-bit experts", "8-bit experts in the engine",
+    "state in bfloat16", "state in bfloat16 in the engine", "a dropped mixer"])
+def test_the_tolerances_refuse_lower_precision_and_missing_work(whole_served, control):
+    """The family's limits at a small size, on the logits the ENGINE's own
+    passes kept: against a reference with 8-bit expert weights the engine
+    reads as not correct, and so does an engine that serves them against
+    the true reference; against a reference that skips a layer's mixer,
+    too; against its own weights it is correct.  A recurrent state held in
+    bfloat16 (rounded after every token, in the reference or in the engine)
+    is SEEN here, in float32: the median error rises a hundredfold, from
+    1e-5 to 1e-3 or more; but that is a fortieth of what serving in bfloat16 reads for
+    every other reason, so the limits made for the chip cannot refuse it
+    (PERF.md section 6, PR 33, says what the check then guards)."""
+    cfg, params, pad_to, done = whole_served
+    other, kw = None, {}
+    if control == "8-bit experts":
+        other = to_8_bits(params)
+    elif control == "8-bit experts in the engine":
+        pad_to, done = serve_whole(cfg, to_8_bits(params))
+    elif control == "state in bfloat16":
+        kw = dict(state_dtype="bfloat16")
+    elif control == "state in bfloat16 in the engine":
+        pad_to, done = serve_whole(
+            dataclasses.replace(cfg, state_dtype="bfloat16"), params)
+    elif control == "a dropped mixer":
+        kw = dict(skip_mixers=(1,))
+    out = FAMILY.check_served(cfg, params, done, pad_to=pad_to,
+                              reference_params=other, **kw)
+    if control.startswith("state in bfloat16"):
+        assert 1e-3 < out["logit_rel_err_p50"] < out["limits"]["logit_rel_err_p50"]
+        return
+    assert out["ok"] == (control == "none"), out
+    if control == "none":
+        assert out["logit_rel_err_p50"] < 2e-5
+
+
+def test_a_request_without_probed_rows_is_refused(model):
+    cfg, params = model
+    with pytest.raises(ValueError, match="logit_probe"):
+        FAMILY.check_served(cfg, params, [([1, 2, 3], [4, 5])], pad_to=16)
+
+
+def test_the_counts_are_of_what_the_algorithm_needs():
+    flops, nbytes = FAMILY.gdn_step_flops_bytes(100)
+    state = 32 * 128 * 128
+    assert flops == 7 * state * 100
+    # a live slot's state read once and written once in float32, its q, k
+    # (16 key heads), v, o (32 value heads) in bfloat16, two scalars a head
+    assert nbytes == 100 * (2 * state * 4 + 2 * (2 * 16 * 128 + 2 * 32 * 128) + 8 * 32)
+    assert FAMILY.gdn_step_flops_bytes(0) == (0.0, 0.0)
+    flops, nbytes = FAMILY.moe_gmm_flops_bytes(320, 118)
+    assert flops == 2 * 3 * 2048 * 512 * 320
+    assert nbytes == 2 * (3 * 2048 * 512 * 118 + 2 * 2048 * 320)
