@@ -216,16 +216,43 @@ def make_decode_tick(
 
 
 def prefill_widths(max_prompt_len: int) -> tuple[int, ...]:
-    """The widths a prefill pass is padded to: quarters of
-    ``max_prompt_len`` (64, 128, 192, 256 at 256).  A pass rides the
-    smallest that holds its batch's longest unmatched suffix, so the one
-    jitted prefill specialises into at most four programs, all of them
-    run once by :meth:`ServeEngine.warmup`."""
-    step = -(-max_prompt_len // 4)
-    return tuple(
-        min(w, max_prompt_len)
-        for w in range(step, max_prompt_len + step, step)
-    )
+    """The widths a prefill pass is padded to: half of ``max_prompt_len``
+    and the whole of it (128, 256 at 256); the widths of
+    :func:`pass_shapes`."""
+    return tuple(sorted({-(-max_prompt_len // 2), max_prompt_len}))
+
+
+def pass_shapes(
+    prefill_batch: int, max_prompt_len: int
+) -> tuple[tuple[int, int], ...]:
+    """The ladder of shapes ``(rows, width)`` a prefill pass may take,
+    cheapest first: a quarter of ``prefill_batch`` rows at each of
+    :func:`prefill_widths`, then half of it and the whole of it at the
+    full width ((2, 128) (2, 256) (4, 256) (8, 256) at 8 and 256).  A pass
+    rides the first that holds its batch's rows and its longest unmatched
+    suffix (:func:`shape_for`), so the one jitted prefill specialises into
+    at most four programs, all of them run once by
+    :meth:`ServeEngine.warmup`: a request admitted alone is not padded to
+    ``prefill_batch`` rows.  The widths split where the rows are few
+    because that is where the passes are: a closed loop admits one or two
+    requests a pass after its opening, and its few batches of three or
+    four set the tail of the time to a first token (``PERF.md`` section 6,
+    PR 34, has the counts)."""
+    narrow, wide = prefill_widths(max_prompt_len)[0], max_prompt_len
+    quarter, half = max(1, prefill_batch // 4), max(1, prefill_batch // 2)
+    return tuple(sorted(
+        {(quarter, narrow), (quarter, wide), (half, wide),
+         (prefill_batch, wide)},
+        key=lambda shape: (shape[0] * shape[1], shape),
+    ))
+
+
+def shape_for(
+    shapes: tuple[tuple[int, int], ...], rows: int, longest: int
+) -> tuple[int, int]:
+    """The first (cheapest) of ``shapes`` that holds ``rows`` rows of up
+    to ``longest`` positions."""
+    return next((r, w) for r, w in shapes if r >= rows and w >= longest)
 
 
 def make_prefill(
@@ -253,14 +280,15 @@ def make_prefill(
     ``kv_pages.adopt_prefix`` seated in its table (a radix hit; 0 cold).
     ``prompts [B, W]`` int32 holds each row's UNMATCHED suffix:
     ``prompts[b, j]`` is the token at position ``starts[b] + j``, and
-    the row writes while that position is below ``lens[b]``.  The width
-    ``W`` is the shape's (one compiled program a width: the engine pads
-    to :func:`prefill_widths`), so a hit saves work by riding a narrower
-    pass.  All ``B x W`` positions run through the model's paged block
-    at once — the block the decode tick runs with one position a row —
-    after ONE all-or-nothing page reservation; the logits are taken at
-    ``lens - 1`` only.  On exit the target slots are active with
-    ``seq_len = lens`` — exactly the state the next decode tick expects;
+    the row writes while that position is below ``lens[b]``.  ``B`` and
+    ``W`` are the shape's (one compiled program a shape: the engine pads
+    to :func:`pass_shapes`), so a request admitted alone rides a pass of
+    few rows and a hit a narrower one.  All ``B x W`` positions run
+    through the model's paged block at once — the block the decode tick
+    runs with one position a row — after ONE all-or-nothing page
+    reservation; the logits are taken at ``lens - 1`` only.  On exit the
+    target slots are active with ``seq_len = lens`` — exactly the state
+    the next decode tick expects;
     a model that keeps slot state has seated, at ``slot_ids``, each row's
     state as it stands after the row's last live position.
     What the model's blocks count of the pass, and the probe of the rows
@@ -936,6 +964,7 @@ class ServeEngine:
         self.pages_per_seq = pages_per_seq
         self.max_seq_len = self.pages_per_seq * page_len
         self.prefill_batch = prefill_batch
+        self._pass_shapes = pass_shapes(prefill_batch, max_prompt_len)
         self.max_prompt_len = max_prompt_len
         self.max_queue = max_queue
         self.token_budget = token_budget
@@ -1335,8 +1364,8 @@ class ServeEngine:
         return counts
 
     def warmup(self) -> None:
-        """Compile all three programs (prefill at every width of
-        :func:`prefill_widths`, decode tick, release) before the clock
+        """Compile all three programs (prefill at every shape of
+        :func:`pass_shapes`, decode tick, release) before the clock
         starts, then reset every piece of host state
         and telemetry: a serving bench must not bill XLA compile time
         as the first requests' TTFT.  The jitted wrappers persist, so
@@ -1418,21 +1447,19 @@ class ServeEngine:
                 ),
                 jnp.full((self.prefill_batch,), -1, jnp.int32),
             )
-        # run every prefill width once (all-padding batch: each write
+        # run every pass shape once (all-padding batch: each write
         # trash-routes, the pool keeps its state), so that no pass
         # compiles on the clock
-        B = self.prefill_batch
-        zeros = jnp.zeros((B,), jnp.int32)
-        pad = (zeros, zeros, jnp.full((B,), -1, jnp.int32),
-               jax.random.PRNGKey(0))
-        for width in prefill_widths(self.max_prompt_len):
-            prompts = jnp.zeros((B, width), jnp.int32)
+        for rows, width in self._pass_shapes:
+            zeros = jnp.zeros((rows,), jnp.int32)
+            pad = (jnp.zeros((rows, width), jnp.int32), zeros, zeros,
+                   jnp.full((rows,), -1, jnp.int32), jax.random.PRNGKey(0))
             self.pool, _first, _ok = self._prefill(
-                self.params, self.pool, prompts, *pad
+                self.params, self.pool, *pad
             )
             if self.spec_k:
                 self.draft_pool, _first, _ok = self._draft_prefill(
-                    self.draft_params, self.draft_pool, prompts, *pad
+                    self.draft_params, self.draft_pool, *pad
                 )
         jax.block_until_ready(self.pool["seq_len"])
         self._vtime = 0.0
@@ -1681,21 +1708,16 @@ class ServeEngine:
             pages[: len(claimed)] = claimed
             self.pool = self._account(_ref, self.pool, jnp.asarray(pages))
 
-    def _width_for(self, longest: int) -> int:
-        """The ladder's smallest width that holds ``longest`` positions."""
-        return next(
-            w for w in prefill_widths(self.max_prompt_len) if w >= longest
-        )
-
     def _run_prefill(self, batch: list[tuple[int, Request, Match]]) -> None:
         from ddl25spring_tpu.obs import flight
 
-        B = self.prefill_batch
         # each row carries its UNMATCHED suffix from column 0, and the
-        # pass is as wide as the ladder's smallest width that holds the
-        # batch's longest: a radix hit rides a narrower (cheaper) pass
-        width = self._width_for(
-            max(req.prompt_len - m.matched for _, req, m in batch)
+        # pass takes the ladder's cheapest shape that holds the batch's
+        # rows and its longest suffix: a request admitted alone is not
+        # padded to prefill_batch rows, a radix hit rides a narrower pass
+        B, width = shape_for(
+            self._pass_shapes, len(batch),
+            max(req.prompt_len - m.matched for _, req, m in batch),
         )
         prompts = np.zeros((B, width), np.int32)
         lens = np.zeros((B,), np.int32)
@@ -1717,7 +1739,7 @@ class ServeEngine:
         # what the pass does and what it could have done: the prompt
         # positions it writes against the positions it computes
         counts = {
-            "rows": len(batch), "width": width,
+            "rows": len(batch), "pass_rows": B, "width": width,
             "prompt_tokens": int(lens.sum() - starts.sum()),
             "scanned_positions": B * width,
         }
@@ -1761,16 +1783,18 @@ class ServeEngine:
             # `first` is the committed stream).  Greedy: the key is
             # never consumed, so the engine's key stream — and with it
             # the spec-off bitwise twin — is untouched.
-            d_width = self._width_for(lens.max())
-            whole = np.zeros((B, d_width), np.int32)
+            d_rows, d_width = shape_for(
+                self._pass_shapes, len(batch), int(lens.max())
+            )
+            whole = np.zeros((d_rows, d_width), np.int32)
             for row, (_slot, req, _m) in enumerate(batch):
                 whole[row, : req.prompt_len] = req.prompt
             with self._span("serve.draft_prefill", rows=len(batch),
-                            width=d_width):
+                            pass_rows=d_rows, width=d_width):
                 self.draft_pool, _draft_first, ok_d = self._draft_prefill(
                     self.draft_params, self.draft_pool,
                     jnp.asarray(whole),
-                    jnp.asarray(lens), jnp.zeros((B,), jnp.int32),
+                    jnp.asarray(lens), jnp.zeros((d_rows,), jnp.int32),
                     jnp.asarray(slot_ids), self._zero_key,
                 )
             if not bool(ok_d):

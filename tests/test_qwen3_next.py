@@ -19,7 +19,7 @@ from benchmark import run as bench_run
 from ddl25spring_tpu.models import qwen3_next as qn
 from ddl25spring_tpu.ops.gdn import gdn_step
 from ddl25spring_tpu.serve import kv_pages
-from ddl25spring_tpu.serve.engine import ServeEngine
+from ddl25spring_tpu.serve.engine import ServeEngine, pass_shapes
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
 FAMILY = bench_run.load_module(BENCH, "families", "qwen3next")
@@ -418,9 +418,13 @@ def test_engine_counts_chunks_state_rows_and_the_state_in_its_bill(served):
     live, scanned = rings["gdn.chunks_live"], rings["gdn.chunks_scanned"]
     n = len(live)
     assert n >= 3 and len(scanned) == n
+    # the ladder's shapes in chunks of 4: (1, 6) (1, 12) (2, 12)
+    chunks = {r * -(-w // 4) for r, w in pass_shapes(2, 12)}
+    assert chunks == {2, 3, 6}
     for (_, a), (_, b) in zip(live, scanned):
-        # 2 rows x width 3..12 in chunks of 4: 2..6 scanned, 1..6 of them live
-        assert 1 <= a <= b <= 6 and b % 2 == 0
+        assert 1 <= a <= b and b in chunks
+    # a request admitted alone is scanned as one row, not as prefill_batch
+    assert {b for _, b in scanned} & {2, 3}
     hit = rings["moe.experts_hit"]  # a sample a pass, tick or prompt
     assert len(hit) > n and all(0 <= v <= 8 * 4 for _, v in hit)
     bill = eng.memory_bill()
@@ -429,9 +433,16 @@ def test_engine_counts_chunks_state_rows_and_the_state_in_its_bill(served):
     assert bill["total"] == sum(bill["weights"].values()) + bill["pool"]
 
 
-def test_prefill_span_carries_the_late_stats(model):
+@pytest.mark.parametrize("prompts, shape, chunks", [
+    ([[3, 4, 5, 6, 7], [8, 9]], (2, 12), (3, 6)),
+    ([[3, 4, 5, 6, 7]], (1, 6), (2, 2)),
+    ([[3, 4, 5, 6, 7, 8, 9]], (1, 12), (2, 3)),
+], ids=["two_rows", "alone", "alone_wide"])
+def test_prefill_span_carries_the_late_stats(model, prompts, shape, chunks):
     """``state_rows`` and the two chunk counts are late stats of
-    ``serve.prefill``; the span that builds the pool splits its bytes."""
+    ``serve.prefill``, and with ``pass_rows`` and ``scanned_positions`` they
+    are of the shape that ran; the span that builds the pool splits its
+    bytes."""
     from ddl25spring_tpu import obs
 
     cfg, params = model
@@ -439,7 +450,7 @@ def test_prefill_span_carries_the_late_stats(model):
     old = obs.set_recorder(rec)
     try:
         with obs.scoped(True):
-            drain(engine(cfg, params), [([3, 4, 5, 6, 7], 2), ([8, 9], 2)])
+            drain(engine(cfg, params), [(p, 2) for p in prompts])
     finally:
         obs.set_recorder(old)
 
@@ -448,10 +459,51 @@ def test_prefill_span_carries_the_late_stats(model):
                 if e["name"] == name]
 
     (late,) = stats("serve.prefill")
-    assert late["state_rows"] == 2
-    assert (late["chunks_live"], late["chunks_scanned"]) == (3, 4)
+    assert late["state_rows"] == late["rows"] == len(prompts)
+    assert (late["pass_rows"], late["width"]) == shape
+    assert late["scanned_positions"] == shape[0] * shape[1]
+    assert (late["chunks_live"], late["chunks_scanned"]) == chunks
     pool = stats("serve.pool")
     assert pool and pool[0]["bytes_state"] > 0 and pool[0]["bytes_planes"] > 0
+
+
+def test_a_lone_request_seats_at_the_small_shape_what_it_seats_at_the_full(model):
+    """One request through the ladder's cheapest shape and through its full
+    one: the same first token, the same pages and, in its slot, the same
+    recurrent state and convolution tail.  Only padding rows went."""
+    from ddl25spring_tpu.serve.engine import make_prefill
+
+    cfg, params = model
+    prompt = np.random.default_rng(5).integers(1, 64, 6)
+    prefill = jax.jit(make_prefill(cfg, max_prompt_len=12, sentinel=False))
+
+    def one_pass(rows, width):
+        packed = np.zeros((rows, width), np.int32)
+        packed[0, :6] = prompt
+        lens, slot_ids = np.zeros((rows,), np.int32), np.full((rows,), -1, np.int32)
+        lens[0], slot_ids[0] = 6, 2
+        pool = kv_pages.init_page_pool(
+            cfg, n_pages=12, page_len=PAGE, max_slots=3, pages_per_seq=4)
+        pool, first, ok = prefill(
+            params, pool, jnp.asarray(packed), jnp.asarray(lens),
+            jnp.zeros((rows,), jnp.int32), jnp.asarray(slot_ids),
+            jax.random.PRNGKey(0))
+        assert bool(ok)
+        return pool, int(first[0])
+
+    shapes = pass_shapes(2, 12)
+    got, first = one_pass(*shapes[0])
+    ref, ref_first = one_pass(*shapes[-1])
+    assert shapes[0] == (1, 6) and shapes[-1] == (2, 12) and first == ref_first
+    for key in kv_pages.accounting(ref):
+        np.testing.assert_array_equal(got[key], ref[key], err_msg=key)
+    for key, want in kv_pages.planes(ref).items():
+        np.testing.assert_allclose(kv_pages.planes(got)[key][:-1], want[:-1],
+                                   atol=1e-5, err_msg=key)
+    for key, want in kv_pages.slot_state(ref).items():
+        assert float(jnp.abs(want[2]).max()) > 0  # seated, in its slot alone
+        np.testing.assert_allclose(kv_pages.slot_state(got)[key], want,
+                                   atol=1e-5, err_msg=key)
 
 
 @pytest.mark.parametrize("feature, kw", [

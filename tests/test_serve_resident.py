@@ -8,7 +8,7 @@ the engine is built, serves the same bits.  On the 15M preset at
 
 - an engine given float32 masters holds matrices in bfloat16, norm scales
   in float32 and no leaf of the masters that it had to cast;
-- the decode tick's and every prefill width's tokens, logits and pages are
+- the decode tick's and every prefill shape's tokens, logits and pages are
   BITWISE what the same programs give on the masters (the parent's
   arithmetic);
 - ``resident`` is idempotent and returns the same arrays, so several
@@ -43,7 +43,7 @@ from ddl25spring_tpu.serve.engine import (
     ServeEngine,
     make_decode_tick,
     make_prefill,
-    prefill_widths,
+    pass_shapes,
 )
 from ddl25spring_tpu.serve.paged_model import paged_model
 from ddl25spring_tpu.utils.config import LlamaConfig
@@ -52,8 +52,8 @@ CFG = LlamaConfig(dtype="bfloat16")  # the 15M preset, as a server runs it
 BF16, F32 = jnp.dtype("bfloat16"), jnp.dtype("float32")
 PAGE_LEN, PAGES_PER_SEQ, SLOTS, N_PAGES = 16, 8, 4, 24
 MAX_PROMPT = 64
-WIDTHS = prefill_widths(MAX_PROMPT)
-PROGRAMS = ["tick"] + [f"prefill{w}" for w in WIDTHS]
+SHAPES = pass_shapes(SLOTS, MAX_PROMPT)  # (1, 32) (1, 64) (2, 64) (4, 64)
+PROGRAMS = ["tick"] + [f"prefill{r}x{w}" for r, w in SHAPES]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,25 +210,28 @@ def jitted(name: str):
 
 
 def args_of(name: str, params):
-    """A pool and the arguments after it: for a pass, two prompts of
-    different lengths, one across a page boundary; for the tick, the pages
-    that pass left (written with ``params``) and two live rows."""
+    """A pool and the arguments after it: for a pass, a full batch of its
+    shape, prompts of different lengths, the first across a page boundary;
+    for the tick, the pages a two-row pass left (written with ``params``)
+    and two live rows."""
     if name != "tick":
-        return fresh_pool(), prompt_batch(int(name[len("prefill"):]))
-    pool, _first, ok = jitted(f"prefill{WIDTHS[0]}")(
-        params, fresh_pool(), *prompt_batch(WIDTHS[0])
+        rows, width = name[len("prefill"):].split("x")
+        return fresh_pool(), prompt_batch(int(rows), int(width))
+    pool, _first, ok = jitted("prefill")(
+        params, fresh_pool(), *prompt_batch(2, MAX_PROMPT // 2)
     )
     assert bool(ok)
     return pool, (jnp.asarray([5, 9, 0, 0], jnp.int32), jax.random.PRNGKey(2))
 
 
-def prompt_batch(width: int):
-    lens = np.asarray([width, max(1, width - 21)], np.int32)
-    prompts = np.zeros((2, width), np.int32)
+def prompt_batch(rows: int, width: int):
+    lens = np.asarray([max(1, width - 21 * r) for r in range(rows)], np.int32)
+    prompts = np.zeros((rows, width), np.int32)
     for row, n in enumerate(lens):
         prompts[row, :n] = tokens_of(11 + row, n)
-    return (jnp.asarray(prompts), jnp.asarray(lens), jnp.zeros((2,), jnp.int32),
-            jnp.arange(2, dtype=jnp.int32), jax.random.PRNGKey(1))
+    return (jnp.asarray(prompts), jnp.asarray(lens),
+            jnp.zeros((rows,), jnp.int32), jnp.arange(rows, dtype=jnp.int32),
+            jax.random.PRNGKey(1))
 
 
 def same_bits(a, b):
@@ -252,10 +255,13 @@ def test_pass_on_resident_weights_is_bitwise_the_pass_on_masters(
     assert bool(ok_m) and bool(ok_r)
     same_bits(got, want)
     same_bits(got_pool, want_pool)
-    n = 4 if name == "tick" else 2
+    n = SLOTS if name == "tick" else args[0].shape[0]
+    live = 2 if name == "tick" else n
     logits = np.asarray(want[n:]).view(np.float32).reshape(n, CFG.vocab_size)
     assert np.isfinite(logits).all() and np.ptp(logits[0]) > 0.1
-    np.testing.assert_array_equal(np.asarray(want[:n])[:2], logits.argmax(-1)[:2])
+    np.testing.assert_array_equal(
+        np.asarray(want[:n])[:live], logits.argmax(-1)[:live]
+    )
 
 
 # -------------------------------------------- what the programs take

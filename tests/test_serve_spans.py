@@ -16,7 +16,7 @@ import pytest
 
 from ddl25spring_tpu import obs
 from ddl25spring_tpu.models import llama
-from ddl25spring_tpu.serve.engine import ServeEngine, prefill_widths
+from ddl25spring_tpu.serve.engine import ServeEngine, pass_shapes
 from ddl25spring_tpu.utils.config import LlamaConfig
 
 CFG = LlamaConfig(
@@ -115,9 +115,14 @@ def test_n_steps_leave_n_step_spans_with_their_children_inside(params):
     assert len(everything("serve.decode_tick")) == eng._ticks
     # a token loop follows every pass, prefill or tick
     assert len(everything("serve.emit")) == eng._prefills + eng._ticks
-    # the harness's view and the program's agree on the tick's wall
-    walls = [d for _, d in everything("serve.decode_tick")]
-    assert walls == pytest.approx(list(eng.tick_wall_s), rel=0.2, abs=2e-3)
+    # the harness's view and the program's agree on the tick's wall: the
+    # engine's two stamps stand around the span and inside the step, so the
+    # three lengths nest whatever the machine does between two of them
+    ticks = everything("serve.decode_tick")
+    assert len(ticks) == len(eng.tick_wall_s)
+    for (t, d), wall in zip(ticks, eng.tick_wall_s):
+        step = next(sd for s, sd in steps if s <= t and t + d <= s + sd)
+        assert 0 < d <= wall <= step
 
 
 def test_tick_counts_equal_the_engines_own_state_at_each_tick(params):
@@ -196,14 +201,19 @@ def test_prefill_counts_are_the_admitted_prompts_less_matched_prefixes(params):
     rows = [a["rows"] for a in stats]
     assert sum(rows) == eng.admitted == len(prompts)
     assert sum(tokens) == sum(map(len, prompts)) - eng.prefix.hit_tokens
-    # a pass computes prefill_batch x width positions, at the smallest
-    # width of the ladder that holds its longest unmatched suffix
-    widths = prefill_widths(eng.max_prompt_len)
-    assert [a["width"] for a in stats] == [s // eng.prefill_batch for s in scanned]
-    assert all(a["width"] in widths for a in stats)
-    # cold [6+2] rides the full width; the hits' suffixes (4, 4, 1 after
-    # the cached page of 4) ride half of it, the cold [8, 8, 8] with them
-    assert scanned == [2 * 8, 2 * 4, 2 * 4]
+    # a pass computes the positions of its shape, the ladder's cheapest that
+    # holds its rows and its longest unmatched suffix, and says so
+    shapes = pass_shapes(eng.prefill_batch, eng.max_prompt_len)
+    ran = [(a["pass_rows"], a["width"]) for a in stats]
+    assert all(shape in shapes for shape in ran)
+    assert scanned == [r * w for r, w in ran]
+    assert all(a["rows"] <= a["pass_rows"] for a in stats)
+    # cold [6+2] alone rides one row of the full width; the hits' suffixes
+    # (4 and 3 after the cached page of 4) two rows, which the ladder has
+    # at the full width only; the cold [8, 8, 8], admitted alone behind
+    # them, one row of half
+    assert shapes == ((1, 4), (1, 8), (2, 8))
+    assert ran == [(1, 8), (2, 8), (1, 4)] and rows == [1, 2, 1]
     # and what a hit skips is its matched positions, row for row
     assert eng.prefill_tokens_saved == eng.prefix.hit_tokens > 0
 

@@ -12,9 +12,11 @@ explicit positions), run position by position on the same pool, on the
 - the first tokens and the greedy stream that follows are identical;
 - the dense decode path (``models/decode.decode_step``), which shares no
   block with the pass, leaves the same K and V and the same first tokens;
-- the width is the ladder's smallest that holds the batch's longest
-  unmatched suffix, a batch of radix hits rides a narrower pass than its
-  cold twin, and nothing compiles after ``warmup()``;
+- the pass's shape ``(rows, width)`` is the ladder's cheapest that holds
+  the batch's rows and its longest unmatched suffix (PR 34: a request
+  admitted alone is not padded to ``prefill_batch`` rows, and leaves what
+  it leaves at the full shape), a batch of radix hits rides a narrower
+  pass than its cold twin, and nothing compiles after ``warmup()``;
 - a pool too small fails the pass whole.
 """
 
@@ -34,7 +36,9 @@ from ddl25spring_tpu.serve.engine import (
     ServeEngine,
     make_decode_tick,
     make_prefill,
+    pass_shapes,
     prefill_widths,
+    shape_for,
 )
 from ddl25spring_tpu.serve.spec import _position_step
 from ddl25spring_tpu.utils.config import LlamaConfig
@@ -236,16 +240,31 @@ def test_a_pool_too_small_fails_the_pass_whole(params):
 # --------------------------------------------------------- the ladder
 
 
-@pytest.mark.parametrize("max_prompt_len,widths", [
-    (256, (64, 128, 192, 256)),
-    (64, (16, 32, 48, 64)),
-    (8, (2, 4, 6, 8)),
-    (7, (2, 4, 6, 7)),
-    (3, (1, 2, 3)),
-    (1, (1,)),
+@pytest.mark.parametrize("prefill_batch,max_prompt_len,shapes", [
+    (8, 256, ((2, 128), (2, 256), (4, 256), (8, 256))),  # the dense cell's
+    (8, 512, ((2, 256), (2, 512), (4, 512), (8, 512))),  # the expert cells'
+    (4, 64, ((1, 32), (1, 64), (2, 64), (4, 64))),
+    (16, 7, ((4, 4), (4, 7), (8, 7), (16, 7))),
+    (2, 64, ((1, 32), (1, 64), (2, 64))),  # a quarter and a half are one row
+    (3, 8, ((1, 4), (1, 8), (3, 8))),
+    (1, 8, ((1, 4), (1, 8))),  # one row count
+    (8, 1, ((2, 1), (4, 1), (8, 1))),  # one width
+    (1, 1, ((1, 1),)),
 ])
-def test_the_ladder_is_quarters_of_the_longest_prompt(max_prompt_len, widths):
-    assert prefill_widths(max_prompt_len) == widths
+def test_the_ladder_is_a_quarter_of_the_rows_at_two_widths_then_full_width(
+        prefill_batch, max_prompt_len, shapes):
+    """A function of ``prefill_batch`` and ``max_prompt_len`` alone, at
+    most four shapes, cheapest first, the last of them the full one, and
+    every batch the scheduler can admit rides one of them."""
+    got = pass_shapes(prefill_batch, max_prompt_len)
+    assert got == shapes and len(got) <= 4
+    assert got[-1] == (prefill_batch, max_prompt_len)
+    assert [r * w for r, w in got] == sorted(r * w for r, w in got)
+    assert prefill_widths(max_prompt_len) == tuple(sorted({w for _, w in got}))
+    for rows in range(1, prefill_batch + 1):
+        for longest in {1, -(-max_prompt_len // 2), max_prompt_len}:
+            r, w = shape_for(got, rows, longest)
+            assert r >= rows and w >= longest
 
 
 @pytest.fixture(autouse=True)
@@ -284,26 +303,89 @@ def scanned():
     )]
 
 
-@pytest.mark.parametrize("longest,width", [
-    (1, 16), (16, 16), (17, 32), (32, 32), (33, 48), (49, 64), (64, 64),
-])
-def test_a_pass_rides_the_smallest_width_that_holds_it(params, longest, width):
-    eng = make_engine(params)
+def watch_shapes(eng, name="_prefill"):
+    """The shapes of the prompts handed to the engine's prefill program
+    from here on."""
     seen = []
-    inner = eng._prefill
-    eng._prefill = lambda p, pool, prompts, *a: (
-        seen.append(prompts.shape), inner(p, pool, prompts, *a))[1]
-    serve(eng, [tokens_of(3, longest), tokens_of(4, min(longest, 9))])
-    assert seen == [(eng.prefill_batch, width)]
-    assert scanned() == [eng.prefill_batch * width]
+    inner = getattr(eng, name)
+    setattr(eng, name, lambda p, pool, prompts, *a: (
+        seen.append(prompts.shape), inner(p, pool, prompts, *a))[1])
+    return seen
+
+
+@pytest.mark.parametrize("rows,longest,shape", [
+    (1, 1, (1, 32)), (1, 32, (1, 32)), (1, 33, (1, 64)), (1, 64, (1, 64)),
+    (2, 9, (2, 64)), (2, 64, (2, 64)), (3, 17, (4, 64)), (4, 32, (4, 64)),
+    (4, 64, (4, 64)),
+])
+def test_a_pass_rides_the_cheapest_shape_that_holds_it(
+        params, rows, longest, shape):
+    eng = make_engine(params, prefill_batch=4)  # (1, 32) (1, 64) (2, 64) (4, 64)
+    assert shape_for(
+        pass_shapes(eng.prefill_batch, MAX_PROMPT), rows, longest) == shape
+    seen = watch_shapes(eng)
+    serve(eng, [tokens_of(3, longest)]
+          + [tokens_of(4 + i, min(longest, 9)) for i in range(rows - 1)])
+    assert seen == [shape]
+    assert scanned() == [shape[0] * shape[1]]
+    assert eng.admitted == rows and eng.pool_ok_failures == 0
+
+
+@pytest.mark.parametrize("n", [1, 21, 32])
+def test_a_lone_request_leaves_at_the_small_shape_what_it_leaves_at_the_full(
+        params, n):
+    """The same request through the ladder's cheapest shape and through its
+    full one: the same pages in the same table, the same K and V in them,
+    the same first token and the same greedy stream after it.  Only
+    padding rows went."""
+    shapes = pass_shapes(SLOTS, MAX_PROMPT)
+    prompt = tokens_of(30 + n, n)
+    prefill = jax.jit(make_prefill(CFG, max_prompt_len=MAX_PROMPT,
+                                   sentinel=False))
+
+    def one_pass(rows, width):
+        packed = np.zeros((rows, width), np.int32)
+        packed[0, :n] = prompt
+        lens = np.zeros((rows,), np.int32)
+        lens[0] = n
+        slot_ids = np.full((rows,), -1, np.int32)
+        slot_ids[0] = 2
+        pool, first, ok = prefill(
+            params, fresh_pool(), jnp.asarray(packed), jnp.asarray(lens),
+            jnp.zeros((rows,), jnp.int32), jnp.asarray(slot_ids),
+            jax.random.PRNGKey(0),
+        )
+        assert bool(ok) and first.shape == (rows,)
+        return pool, int(first[0])
+
+    small, full = shape_for(shapes, 1, n), shapes[-1]
+    assert small == (1, 32) and full == (SLOTS, MAX_PROMPT)
+    got, first = one_pass(*small)
+    ref, ref_first = one_pass(*full)
+    assert first == ref_first
+    for key in ("page_table", "refcount", "free", "seq_len", "active"):
+        np.testing.assert_array_equal(got[key], ref[key], err_msg=key)
+    for key in ("k", "v"):
+        np.testing.assert_allclose(
+            got[key][:-1], ref[key][:-1], rtol=KV_RTOL, atol=KV_ATOL,
+            err_msg=key,
+        )
+    tick = jax.jit(make_decode_tick(CFG, sentinel=False))
+    a = b = jnp.zeros((SLOTS,), jnp.int32).at[2].set(first)
+    for _ in range(6):
+        got, a, _ = tick(params, got, a, jax.random.PRNGKey(0))
+        ref, b, _ = tick(params, ref, b, jax.random.PRNGKey(0))
+        assert int(a[2]) == int(b[2])
 
 
 def test_radix_hits_ride_a_narrower_pass_than_their_cold_twin(params):
     shared = tokens_of(5, 40)  # two full pages of 16 are cacheable
     batch = [shared + tokens_of(6, 6), shared + tokens_of(7, 9)]
-    cold = make_engine(params)
-    hot = make_engine(params, prefix_cache=True)
+    # a quarter of prefill_batch is two rows: (2, 32) (2, 64) (4, 64) (8, 64)
+    cold = make_engine(params, prefill_batch=8)
+    hot = make_engine(params, prefill_batch=8, prefix_cache=True)
     serve(hot, [shared + tokens_of(8, 3)])  # seeds the cache
+    assert scanned() == [2 * 64]  # alone: two rows of the full width, not 8
     obs.counters.reset()
     want = serve(cold, batch)
     assert scanned() == [2 * 64]  # 49 positions: the full width
@@ -315,11 +397,18 @@ def test_radix_hits_ride_a_narrower_pass_than_their_cold_twin(params):
     assert hot.pool_ok_failures == cold.pool_ok_failures == 0
 
 
-def test_nothing_compiles_after_warmup(params):
-    """Every width, a radix hit with a COW'd partial page and the decode
-    tick run on programs ``warmup()`` already compiled."""
-    eng = make_engine(params, prefix_cache=True)
+@pytest.mark.parametrize("kw", [
+    dict(prefix_cache=True), dict(spec_k=2),
+], ids=["prefix_cache", "drafter"])
+def test_nothing_compiles_after_warmup(params, kw):
+    """Every shape of the ladder (the drafter's passes too), a radix hit
+    with a COW'd partial page and the decode tick or speculative round run
+    on programs ``warmup()`` already compiled."""
+    eng = make_engine(params, prefill_batch=4, **kw)
     eng.warmup()
+    shapes = pass_shapes(eng.prefill_batch, MAX_PROMPT)
+    seen = watch_shapes(eng)
+    seen_draft = watch_shapes(eng, "_draft_prefill") if eng.spec_k else None
     compiled = []
 
     def listener(event, _seconds, **_):
@@ -328,15 +417,20 @@ def test_nothing_compiles_after_warmup(params):
 
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
-        shared = tokens_of(9, 21)  # a full page and a partial one
-        for n, width in zip((3, 30, 40, 60), prefill_widths(MAX_PROMPT)):
-            serve(eng, [tokens_of(10 + n, n)])
-        serve(eng, [shared])
-        serve(eng, [shared + tokens_of(11, 4)])
+        for rows, width in shapes:
+            serve(eng, [tokens_of(10 * rows + width + i, width - i)
+                        for i in range(rows)])
+        if eng.prefix is not None:
+            shared = tokens_of(9, 21)  # a full page and a partial one
+            serve(eng, [shared])
+            serve(eng, [shared + tokens_of(11, 4)])
     finally:
         jax.monitoring.unregister_event_duration_listener(listener)
-    assert eng.prefix.hits == 1 and eng._prefills == 6
-    assert sorted(set(scanned())) == [
-        eng.prefill_batch * w for w in prefill_widths(MAX_PROMPT)
-    ]
+    assert sorted(set(seen)) == sorted(shapes) and len(shapes) == 4
+    assert sorted(set(scanned())) == sorted({r * w for r, w in shapes})
+    if eng.prefix is not None:
+        assert eng.prefix.hits == 1 and eng._prefills == 6
+    if eng.spec_k:
+        assert sorted(set(seen_draft)) == sorted(shapes)
+        assert eng._spec_rounds > 0
     assert compiled == []
