@@ -89,23 +89,34 @@ def _block_stack(model: PagedModel, params, x, cache, slots, rows, pages,
     and, for rows seated at ``slots``, its slot state; the seam of
     :mod:`.paged_model`): the resident-weight scan over the model's equal
     units (a layer, or the model's period of layers), or ``layer_stack``'s
-    own walk (:func:`make_decode_tick`).  Returns ``(x, cache, aux)``:
-    ``aux`` is what the model's blocks count of the pass, stacked over
-    units, or ``None`` for a model that counts nothing."""
+    own walk (:func:`make_decode_tick`).  What a model hands from unit to
+    unit beside ``x`` (``PagedModel.carry``) rides the scan behind ``x``
+    and ``cache``, unopened, and is dropped after the last unit; a model
+    that declares none is scanned on ``(x, cache)`` alone; ``layer_stack``'s
+    walk hands on no carry and refuses a model that declares one.  Returns
+    ``(x, cache, aux)``: ``aux`` is what the model's blocks count of the
+    pass, stacked over units, or ``None`` for a model that counts nothing."""
     run_layer = model.layers(
         params, slots, rows, pages, offs, pos, live, tp_axis
     )
+    handed = () if model.carry is None else (model.carry(x),)
 
     if layer_stack is not None:
+        if handed:
+            raise NotImplementedError(
+                "a custom walk over the block stack (layer_stack) hands "
+                "(x, cache) from unit to unit and nothing else; this model "
+                "declares a carry (PagedModel.carry)"
+            )
         return (*layer_stack(params, run_layer, x, cache), None)
 
     def unit(carry, inp):
-        x, cache, aux = run_layer(*inp, *carry)
-        return (x, cache), aux
+        x, cache, aux, *handed = run_layer(*inp, *carry)
+        return (x, cache, *handed), aux
 
     with jax.named_scope("blocks"):
-        (x, cache), aux = lax.scan(
-            unit, (x, cache),
+        (x, cache, *_), aux = lax.scan(
+            unit, (x, cache, *handed),
             (params["blocks"], jnp.arange(model.n_units)),
         )
     return x, cache, aux

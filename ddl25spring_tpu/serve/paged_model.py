@@ -26,7 +26,8 @@ the page pool (:mod:`.kv_pages`) know a model only through the
 - ``layers(params, slots, rows, pages, offs, pos, live, tp_axis)``: what a
   pass shares over its layers (rotary tables, masks, weights that are not
   to be sliced by the layer scan) is taken once here; it returns
-  ``run_layer(p, ui, x, cache) -> (x, cache, aux)``, unit ``ui`` of the
+  ``run_layer(p, ui, x, cache) -> (x, cache, aux)`` (with a ``carry``:
+  ``run_layer(p, ui, x, cache, c) -> (x, cache, aux, c)``), unit ``ui`` of the
   scan on ``x [B, T, D]`` at absolute positions ``pos [B, T]`` for ANY
   ``T`` (a decode tick is ``T = 1``, a prompt batch ``T = W``): write this
   pass's positions at ``(pages, layer, offs)``, gather the page view
@@ -40,6 +41,16 @@ the page pool (:mod:`.kv_pages`) know a model only through the
   vector of the unit's counts of the pass, a layer after a layer; the
   programs append them, a row a unit, to the vector of sampled tokens, so
   that the host's one fetch brings both;
+- ``carry``: what one POSITION hands from unit to unit beside ``x`` (a
+  router that reads the previous layer's router state): ``carry(x)`` gives
+  the first unit's, any pytree of arrays whose shapes follow ``x [B, T,
+  D]``; ``run_layer`` takes it after ``cache`` and returns the next
+  unit's after ``aux``.  It is of the pass alone: neither a plane (no
+  later position reads it) nor slot state (no later pass does), so the
+  pool never sees it, and the engine's walk over the units passes it on
+  without opening it and drops it after the last.  ``None`` for a model
+  whose units exchange ``x`` only, whose ``run_layer`` keeps the shorter
+  form;
 - ``embed(params, tokens)`` / ``unembed(params, x)``;
 - ``pass_stats(aux)``: the fetched counts ``aux [n_layers, c]`` of one
   pass as two ``{name: number}``: the first are sampled, each into the ring
@@ -95,6 +106,7 @@ class PagedModel:
     state_layers: int = 0
     scan_units: int | None = None
     prompt_pass_counts: Callable | None = None
+    carry: Callable | None = None
 
     @property
     def n_units(self) -> int:

@@ -126,6 +126,42 @@ def test_gdn_step_compiles_for_v5e_and_updates_the_state_in_place(
     assert mem.temp_size_in_bytes < nbytes // 100
 
 
+# (rows a call, layers, experts held, hidden, expert width): a tick of the
+# latent cell (64 slots x top-4 of 32 held), of the hybrid cell (128 x top-10
+# of 128) and of the top-1 cell (64 x top-1 of 16: every expert of a layer
+# held, four rows an expert), and the top-1 cell's widest prompt pass
+MOE_GMM_REGIMES = {
+    "latent_tick": (256, 6, 32, 4096, 2048),
+    "hybrid_tick": (1280, 8, 128, 2048, 512),
+    "top1_tick": (64, 20, 16, 2048, 2048),
+    "top1_pass": (4096, 20, 16, 2048, 2048),
+}
+
+
+@pytest.mark.parametrize("regime", list(MOE_GMM_REGIMES))
+def test_moe_gmm_compiles_for_v5e_in_every_served_regime(
+        one_chip, as_on_tpu, regime):
+    """One kernel, three expert shapes: a tile or a buffer chosen for one is
+    compiled for the other two here, at the served widths, with the whole
+    stack as the operand (no layer sliced out: no temporary near a layer's
+    size)."""
+    from ddl25spring_tpu.ops.moe_gmm import moe_gmm
+
+    rows, L, E, D, F = MOE_GMM_REGIMES[regime]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for k, n in ((D, F), (F, D)):  # gate / up, then down
+        lowered = jax.jit(moe_gmm).lower(
+            arg((rows, k)), arg((L, E, k, n)), arg((E,), jnp.int32),
+            arg((), jnp.int32))
+        text = lowered.as_text()
+        assert "tpu_custom_call" in text and "moe_gmm" in text
+        mem = lowered.compile().memory_analysis()
+        assert mem.temp_size_in_bytes < E * k * n * 2 // 4
+
+
 @pytest.mark.parametrize("direction,kernels", [
     ("fwd", ["flash_fwd"]),
     ("bwd", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
