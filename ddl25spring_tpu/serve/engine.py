@@ -122,21 +122,26 @@ def _block_stack(model: PagedModel, params, x, cache, slots, rows, pages,
     return x, cache, aux
 
 
-def _pack_pass(tokens, logits, aux, logit_probe: int):
+def _pack_pass(tokens, logits, aux, ok, logit_probe: int, table=None):
     """What a pass hands the host, as ONE int32 vector, one fetch:
     ``tokens [n]``; where the engine keeps a probe of the rows they were
     sampled from (``logit_probe > 0``), that many evenly strided logits of
-    each row ``logits [n, V]`` as float32 bits; where the model counts
-    any, the pass's counts ``aux [L, c]``.  With neither, the tokens come
-    back as they are (the dense programs' outputs are what they were)."""
+    each row ``logits [n, V]`` as float32 bits; for a prompt pass, the
+    table entries its rows' prompts can reach, ``table [n, E]``; where the
+    model counts any, the pass's counts ``aux [L, c]``; last, the pool
+    flag ``ok`` as 0 or 1 (:meth:`ServeEngine._split_pass` takes it
+    apart)."""
     parts = [tokens]
     if logit_probe:
         stride = logits.shape[-1] // logit_probe
         kept = logits[:, : logit_probe * stride : stride].astype(jnp.float32)
         parts.append(lax.bitcast_convert_type(kept, jnp.int32).reshape(-1))
+    if table is not None:
+        parts.append(table.reshape(-1))
     if aux is not None:
         parts.append(aux.astype(jnp.int32).reshape(-1))
-    return tokens if len(parts) == 1 else jnp.concatenate(parts)
+    parts.append(ok.astype(jnp.int32).reshape(1))
+    return jnp.concatenate(parts)
 
 
 def make_decode_tick(
@@ -153,10 +158,18 @@ def make_decode_tick(
 ):
     """Build the decode program body: one token for EVERY active slot.
 
-    ``tick(params, pool, tokens, key) -> (pool, new_tokens, ok)`` —
-    ``tokens [max_slots]`` are the tokens to append at each slot's
-    current position (the previous tick's samples), ``new_tokens`` the
-    next ones, ``ok`` the pool-exhaustion backstop flag.  Static shapes
+    ``tick(params, pool, key) -> (pool, packed, key)`` — the tokens it
+    appends at each slot's current position are the pool's ``last_tok``
+    (the previous pass's samples, never uploaded), and the ones it
+    samples replace them there at every active slot; ``packed`` is the
+    int32 vector of :func:`_pack_pass`: the new tokens ``[max_slots]``
+    first and the pool-exhaustion backstop flag ``ok`` last.  A program
+    that samples at a temperature splits the key inside (``key, sub =
+    split(key)``, ``sub`` draws) and returns the first half for the next
+    program, so the engine's key sequence is the one a host-side split
+    would make; a greedy one draws nothing and hands the key back as it
+    came (a split it does not use would cost every program's lowering
+    the threefry rounds on the chip).  Static shapes
     throughout: one compile serves the engine's whole lifetime.  The
     gate+policy of the logits sentinel resolve at BUILD time
     (:func:`ddl25spring_tpu.obs.sentinels.resolve`).
@@ -164,9 +177,9 @@ def make_decode_tick(
     The block, the page planes, ``embed`` and ``unembed`` are the
     model's (``cfg.paged_model()``, :mod:`.paged_model`); what a model's
     blocks count of a pass (``aux``) rides BEHIND the tokens in the same
-    int32 vector (:func:`_pack_pass`), so that the host's one fetch of
-    the sampled tokens brings it along; ``logit_probe`` strided logits of
-    every sampled row ride there too (``ServeEngine(logit_probe=)``).
+    int32 vector, so that the host's one fetch of the sampled tokens
+    brings it along; ``logit_probe`` strided logits of every sampled row
+    ride there too (``ServeEngine(logit_probe=)``).
 
     ``layer_stack`` swaps the default resident-weight layer scan for a
     custom walk over the block stack — ``layer_stack(params, run_layer,
@@ -178,7 +191,8 @@ def make_decode_tick(
     model = paged_model(cfg)
     s_on, s_policy = sentinels.resolve(sentinel)
 
-    def tick(params, pool, tokens, key):
+    def tick(params, pool, key):
+        tokens = pool["last_tok"]  # [S] — what each slot appends
         active = pool["active"]
         pos = pool["seq_len"]  # [S] — position this tick writes
         page_len = kv_pages.page_len_of(pool)
@@ -204,11 +218,13 @@ def make_decode_tick(
             if temperature == 0.0:
                 new_tok = logits.argmax(-1).astype(jnp.int32)
             else:
+                key, sub = jax.random.split(key)
                 new_tok = decode_mod.sample_logits(
-                    logits, key, temperature, top_k, top_p
+                    logits, sub, temperature, top_k, top_p
                 )
         pool = kv_pages.with_contents(
-            pool, cache, seq_len=jnp.where(active, pos + 1, pos)
+            pool, cache, seq_len=jnp.where(active, pos + 1, pos),
+            last_tok=jnp.where(active, new_tok, tokens),
         )
         # decode-step sentinel: a non-finite logit on any ACTIVE slot is
         # the serving analogue of a NaN loss (inactive slots carry
@@ -221,7 +237,7 @@ def make_decode_tick(
             fallback=(new_tok, pool),
             axis=tp_axis, enabled=s_on, policy=s_policy,
         )
-        return pool, _pack_pass(new_tok, logits, aux, logit_probe), ok
+        return pool, _pack_pass(new_tok, logits, aux, ok, logit_probe), key
 
     return tick
 
@@ -283,7 +299,7 @@ def make_prefill(
     request's FIRST generated token.
 
     ``prefill(params, pool, prompts, lens, starts, slot_ids, key) ->
-    (pool, first_tokens, ok)`` — ``slot_ids [B]`` are the target slots
+    (pool, packed, key)`` — ``slot_ids [B]`` are the target slots
     (``-1`` = padding row, which writes only to the trash page), ``lens
     [B]`` the prompts' lengths (at most ``max_prompt_len``, the capacity
     pages are reserved and gathered for), ``starts [B]`` how many
@@ -302,9 +318,13 @@ def make_prefill(
     the next decode tick expects;
     a model that keeps slot state has seated, at ``slot_ids``, each row's
     state as it stands after the row's last live position.
-    What the model's blocks count of the pass, and the probe of the rows
-    the first tokens were sampled from, follow the first tokens in the
-    same vector (see :func:`make_decode_tick`)."""
+    The first tokens are also the target slots' ``last_tok``, which the
+    next decode tick reads.  ``packed`` is :func:`_pack_pass`'s vector:
+    the first tokens ``[B]``, the probe of the rows they were sampled
+    from, each row's first ``E`` table entries (the pages its prompt
+    reaches, which the prefix cache indexes), what the model's blocks
+    count of the pass, and ``ok`` last; the key comes back as
+    :func:`make_decode_tick` hands it back."""
     model = paged_model(cfg)
     s_on, s_policy = sentinels.resolve(sentinel)
 
@@ -333,10 +353,8 @@ def make_prefill(
                 jnp.broadcast_to(opens, (E, B)).reshape(-1), need.reshape(-1),
             )
             pages, offs = kv_pages.write_page_ids(pool, slots, pos, writing)
-        rows = jnp.clip(
-            pool["page_table"][jnp.clip(slot_ids, 0, n_slots - 1), :E],
-            0, n_pages - 1,
-        )  # [B, E]
+        table = pool["page_table"][jnp.clip(slot_ids, 0, n_slots - 1), :E]
+        rows = jnp.clip(table, 0, n_pages - 1)  # [B, E]
 
         x = model.embed(params, prompts)
         x, cache, aux = _block_stack(
@@ -351,13 +369,15 @@ def make_prefill(
             if temperature == 0.0:
                 first = last_logits.argmax(-1).astype(jnp.int32)
             else:
+                key, sub = jax.random.split(key)
                 first = decode_mod.sample_logits(
-                    last_logits, key, temperature, top_k, top_p
+                    last_logits, sub, temperature, top_k, top_p
                 )
         sent = jnp.where(valid_row, slot_ids, n_slots)
         pool = kv_pages.with_contents(
             pool, cache,
             seq_len=pool["seq_len"].at[sent].set(lens, mode="drop"),
+            last_tok=pool["last_tok"].at[sent].set(first, mode="drop"),
         )
         first, pool = sentinels.guard(
             strategy, (first, pool),
@@ -368,13 +388,14 @@ def make_prefill(
             fallback=(first, pool),
             axis=tp_axis, enabled=s_on, policy=s_policy,
         )
-        return pool, _pack_pass(first, last_logits, aux, logit_probe), ok
+        packed = _pack_pass(first, last_logits, aux, ok, logit_probe, table)
+        return pool, packed, key
 
     return prefill
 
 
 # A program that changes only the pool's accounting takes and returns only
-# the pool's accounting (``kv_pages.accounting``: five small arrays), so no
+# the pool's accounting (``kv_pages.accounting``: six small arrays), so no
 # plane is a parameter or a result of these four and none is copied;
 # ``ServeEngine._account`` is their one caller.  Each respecialises by the
 # pool's geometry under jit and so serves every engine and both pools.
@@ -682,7 +703,7 @@ def _tp_compiled_programs(
         _TP_PROGRAM_CACHE[key] = (
             _tp_jit(
                 tick_body, mesh, cfg, model_axis=model_axis,
-                n_extra=2,
+                n_extra=1,
                 p_specs=_tp_param_specs(cfg, model_axis, weight_stream),
                 donate=donate,
             ),
@@ -791,6 +812,22 @@ class Request:
         return len(self.prompt)
 
 
+@dataclass
+class _Tick:
+    """A decode tick on the device whose tokens the host has not fetched:
+    what it returns, the slots it serves, and what was known of it at its
+    dispatch."""
+
+    packed: Any  # its _pack_pass vector, on the device
+    rows: list[tuple[int, Request]]  # (slot, request) it samples for
+    counts: dict[str, int]  # its span stats (_tick_counts)
+    t_dispatch: float  # perf_counter at its dispatch: its rings' stamp
+    ahead: bool  # dispatched before the previous program was fetched
+    # where its tick_wall_s sample starts: its dispatch, or for a tick
+    # dispatched ahead the previous program's fetch (set then)
+    t_start: float | None = None
+
+
 # default sample cap for the engine's per-run host reservoirs: far
 # above any smoke/test population (behavior identical below the cap),
 # small enough that a week-long soak holds kilobytes, not gigabytes
@@ -892,6 +929,26 @@ class ServeEngine:
     ``clock="virtual"`` advances ``tick_s`` per program call — fully
     deterministic, which is what the continuous-vs-static equivalence
     and admission tests pin.
+
+    **Running ahead.**  A tick's input tokens (the pool's ``last_tok``)
+    and its key are what the previous pass left on the device, so on the
+    wall clock the engine dispatches the next decode tick BEFORE it
+    fetches the program in flight (the last tick, or this step's prompt
+    pass) wherever the host can already tell that the next step would
+    dispatch exactly that tick: no live slot reaches its
+    ``max_new_tokens`` at the program in flight (a pass's rows count
+    their first token), ``eos_id`` is None, nothing queued is admittable
+    and speculation is off (:meth:`_runs_ahead`).  The device then runs
+    the next tick while the host fetches and emits the last one's tokens,
+    and at most one tick is left unfetched between two steps
+    (:attr:`drained` is False while one is).  Where a condition fails
+    the engine fetches first, as it always did: a finished slot is
+    flushed, and a new arrival's pass goes to the device with no tick
+    queued ahead of it.  With ``eos_id`` set, under speculation, and on
+    the virtual clock (which charges no host time, so has none to hide,
+    and whose pinned schedule is the fetch-first one) the engine runs
+    exactly as before.  The ring ``serve.tick_ahead`` says of each tick
+    whether it went ahead.
     """
 
     def __init__(
@@ -1177,7 +1234,19 @@ class ServeEngine:
         # host state
         self.queue: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * max_slots
+        # a host mirror of the pool's last_tok, filled from fetched
+        # tokens; the speculative round uploads from it
         self._slot_last_tok: list[int] = [0] * max_slots
+        # tokens each slot's dispatched passes have sampled that the host
+        # has not fetched and emitted yet (0, 1 or, for the moment between
+        # a tick's dispatch ahead and the fetch before it, 2)
+        self._owed: list[int] = [0] * max_slots
+        # the decode tick dispatched ahead and not fetched yet
+        self._in_flight: _Tick | None = None
+        # table entries a prompt can reach: what a pass hands back a row
+        self._prompt_entries = min(
+            self.pages_per_seq, -(-max_prompt_len // page_len)
+        )
         self._reserved: list[int] = [0] * max_slots  # pages per slot
         self._release_mask: list[bool] = [False] * max_slots
         # pages a completed slot still holds on device until the next
@@ -1322,19 +1391,24 @@ class ServeEngine:
     def _sample(self, name: str, value: float, t: float) -> None:
         _counters.sample(name + self._obs_key, value, t)
 
-    def _split_pass(self, span, fetched, n: int, t: float):
+    def _split_pass(self, span, fetched, n: int, t: float, entries: int = 0):
         """Split a pass's fetched vector (:func:`_pack_pass`) into its
-        ``n`` tokens and the probe ``[n, logit_probe]`` of the rows they
-        were sampled from (``None`` where none is kept), which are
-        returned, and what the model's blocks counted of the pass
-        (nothing for a model that counts nothing), which goes through the
-        model's ``pass_stats``: each sampled number into the ring
-        ``serve.<name>`` stamped at the pass's dispatch ``t``, and every
-        number a stat of the pass's span under the name's last part."""
+        ``n`` tokens, the probe ``[n, logit_probe]`` of the rows they were
+        sampled from (``None`` where none is kept), the rows' first
+        ``entries`` table entries (``None`` for none: a tick) and the pool
+        flag, which are returned, and what the model's blocks counted of
+        the pass (nothing for a model that counts nothing), which goes
+        through the model's ``pass_stats``: each sampled number into the
+        ring ``serve.<name>`` stamped at the pass's dispatch ``t``, and
+        every number a stat of the pass's span under the name's last
+        part."""
         k = self.logit_probe
-        tokens, rest = fetched[:n], fetched[n:]
+        ok = bool(fetched[-1])
+        tokens, rest = fetched[:n], fetched[n:-1]
         probe = rest[: n * k].view(np.float32).reshape(n, k) if k else None
-        counts = rest[n * k:]
+        rest = rest[n * k:]
+        table = rest[: n * entries].reshape(n, entries) if entries else None
+        counts = rest[n * entries:]
         if counts.size:
             sampled, stats = self._model.pass_stats(
                 counts.reshape(self._model.n_layers, -1)
@@ -1345,24 +1419,26 @@ class ServeEngine:
                 name.rsplit(".", 1)[-1]: v
                 for name, v in {**stats, **sampled}.items()
             })
-        return tokens, probe
+        return tokens, probe, table, ok
 
-    def _tick_counts(self) -> dict[str, int]:
+    def _tick_counts(self, t: float | None = None) -> dict[str, int]:
         """The counts a decode pass starts with, from host state alone
         (no device sync), as the pass's span stats.  The two that a
         reader windows (the benchmark's ``slot_occupancy_pct`` and
-        ``kv_gather_live_pct``) are also sampled, under one stamp, into
-        the rings ``serve.active_slots`` and ``serve.kv_live_positions``;
-        a slot's live positions are the ones this pass attends to, its
-        own write included: ``prompt + generated``.  ``pages_used``
-        walks every slot, so it is counted only where a trace will show
-        it."""
+        ``kv_gather_live_pct``) are also sampled, under one stamp ``t``
+        (now, unless given), into the rings ``serve.active_slots`` and
+        ``serve.kv_live_positions``; a slot's live positions are the ones
+        this pass attends to, its own write included: ``prompt +
+        generated``, where generated counts the tokens a pass still in
+        flight owes the slot.  ``pages_used`` walks every slot, so it is
+        counted only where a trace will show it."""
         active = live = 0
-        for req in self.slots:
+        for slot, req in enumerate(self.slots):
             if req is not None:
                 active += 1
-                live += req.prompt_len + len(req.tokens)
-        t = time.perf_counter()
+                live += req.prompt_len + len(req.tokens) + self._owed[slot]
+        if t is None:
+            t = time.perf_counter()
         self._sample("serve.active_slots", active, t)
         self._sample("serve.kv_live_positions", live, t)
         counts = {
@@ -1415,6 +1491,7 @@ class ServeEngine:
         self.queue.clear()
         self.slots = [None] * self.max_slots
         self._slot_last_tok = [0] * self.max_slots
+        self._owed = [0] * self.max_slots
         self._reserved = [0] * self.max_slots
         self._release_mask = [False] * self.max_slots
         self._pending_pages = [0] * self.max_slots
@@ -1465,11 +1542,11 @@ class ServeEngine:
             zeros = jnp.zeros((rows,), jnp.int32)
             pad = (jnp.zeros((rows, width), jnp.int32), zeros, zeros,
                    jnp.full((rows,), -1, jnp.int32), jax.random.PRNGKey(0))
-            self.pool, _first, _ok = self._prefill(
+            self.pool, _packed, _key = self._prefill(
                 self.params, self.pool, *pad
             )
             if self.spec_k:
-                self.draft_pool, _first, _ok = self._draft_prefill(
+                self.draft_pool, _packed, _key = self._draft_prefill(
                     self.draft_params, self.draft_pool, *pad
                 )
         jax.block_until_ready(self.pool["seq_len"])
@@ -1604,6 +1681,23 @@ class ServeEngine:
             return Match()
         return self.prefix.match(req.prompt)
 
+    def _may_admit(self) -> bool:
+        """Whether :meth:`_admittable` could admit a request now, read
+        from counts alone (no match, no eviction, no state touched).
+        Exact without the prefix cache; with it, a queued request that
+        finds a free slot counts as admittable whatever its pages, since
+        a match or an eviction may make room."""
+        if self.draining or not self.queue or not self._free_slots():
+            return False  # draining: an elastic scale-down admits none
+        if self.admission == "static" and any(
+            r is not None for r in self.slots
+        ):
+            return False  # static batching: wait for the batch to drain
+        if self.prefix is not None:
+            return True
+        return (self._pages_needed(self.queue[0])
+                <= self.n_pages - self._committed_pages())
+
     def _admittable(self) -> list[tuple[int, Request, Match]]:
         """(slot, request, prefix-match) triples the scheduler can
         admit right now: bounded by free slots, the prefill batch
@@ -1612,12 +1706,8 @@ class ServeEngine:
         adopted prefix is already resident).  When the free set is
         short, LRU eviction of unpinned cached pages runs before
         backpressure."""
-        if self.draining:
-            return []  # elastic scale-down: finish live work, admit none
-        if self.admission == "static" and any(
-            r is not None for r in self.slots
-        ):
-            return []  # static batching: wait for the batch to drain
+        if not self._may_admit():
+            return []
         free = self._free_slots()
         budget = self.n_pages - self._committed_pages()
         out: list[tuple[int, Request, Match]] = []
@@ -1660,9 +1750,40 @@ class ServeEngine:
 
     # ---- the scheduler iteration --------------------------------------
 
-    def _split_key(self):
-        self._key, sub = jax.random.split(self._key)
-        return sub
+    def _runs_ahead(self) -> bool:
+        """Whether the next decode tick may go to the device before the
+        program in flight is fetched: the host can already tell that the
+        next step would dispatch exactly it.  That holds on the wall clock
+        with no speculation and no ``eos_id`` (a slot ends only at its
+        ``max_new_tokens``, which the counts tell), when no live slot
+        reaches that count with the tokens owed it and nothing queued is
+        admittable.  See the class docstring."""
+        if self.clock != "wall" or self.spec_k or self.eos_id is not None:
+            return False
+        if any(
+            req is not None
+            and len(req.tokens) + self._owed[slot] >= req.max_new_tokens
+            for slot, req in enumerate(self.slots)
+        ):
+            return False
+        return not self._may_admit()
+
+    def _dispatch_tick(self, ahead: bool, t_start: float | None = None) -> _Tick:
+        """Dispatch one decode tick over every live slot and return it
+        unfetched.  Its counts are taken, and its rings sampled (the ring
+        ``serve.tick_ahead`` among them: 1 where it goes ahead of the
+        previous program's fetch), under one stamp, at its dispatch."""
+        t = time.perf_counter()
+        counts = self._tick_counts(t)
+        self._sample("serve.tick_ahead", int(ahead), t)
+        rows = [(slot, req) for slot, req in enumerate(self.slots)
+                if req is not None]
+        self.pool, packed, self._key = self._tick(
+            self.params, self.pool, self._key
+        )
+        for slot, _req in rows:
+            self._owed[slot] += 1
+        return _Tick(packed, rows, counts, t, ahead, t_start)
 
     def _adopt_batch(self, batch: list[tuple[int, Request, Match]]) -> None:
         """Seat every matched prefix before the suffix prefill: full
@@ -1691,19 +1812,19 @@ class ServeEngine:
             self.pool_ok_failures += 1
 
     def _insert_prefixes(
-        self, batch: list[tuple[int, Request, Match]]
+        self, batch: list[tuple[int, Request, Match]], table
     ) -> None:
         """Index the just-prefilled prompts in the radix tree and take
         the cache's device references on every NEWLY claimed page.
-        Pages the cache claims move from the slot's bill to the
-        cache's (``_committed_pages`` stays exact); a slot that
-        completed during this very prefill re-buckets its pending
-        mirror instead."""
+        ``table [rows, E]`` is what the pass handed back of its rows'
+        page tables, fetched with its tokens.  Pages the cache claims
+        move from the slot's bill to the cache's (``_committed_pages``
+        stays exact); a slot that completed during this very prefill
+        re-buckets its pending mirror instead."""
         assert self.prefix is not None
-        table = np.asarray(jax.device_get(self.pool["page_table"]))
         claimed: list[int] = []
-        for _row, (slot, req, _m) in enumerate(batch):
-            new_pages = self.prefix.insert(req.prompt, table[slot])
+        for row, (slot, req, _m) in enumerate(batch):
+            new_pages = self.prefix.insert(req.prompt, table[row])
             claimed.extend(new_pages)
             self._cached_pages[slot] = new_pages
             n_new = len(new_pages)
@@ -1718,6 +1839,34 @@ class ServeEngine:
             pages = np.full((width,), -1, np.int32)
             pages[: len(claimed)] = claimed
             self.pool = self._account(_ref, self.pool, jnp.asarray(pages))
+
+    def _seat(self, batch: list[tuple[int, Request, Match]]) -> None:
+        """The host's half of admitting ``batch``, which needs none of
+        its tokens: each request takes its slot, its admission bill and
+        the one token its prompt pass owes it, and the prefix counters
+        count it.  Done as the pass is dispatched, so that a tick
+        dispatched ahead of the pass's fetch sees the rows."""
+        for _row, (slot, req, m) in enumerate(batch):
+            self.slots[slot] = req
+            self._owed[slot] = 1
+            self._slot_last_rid[slot] = req.rid
+            # _adopted_pages[slot] was billed by _adopt_batch (S204: same
+            # method as the device refcount bump)
+            self._cached_pages[slot] = []
+            # mirror of the admission bill: full worst case under spec
+            # (the drafter pool's share-less need), discounted otherwise
+            self._reserved[slot] = self._pages_needed(req) - (
+                0 if self.spec_k else m.n_ref
+            )
+            self.admitted += 1
+            if self.prefix is not None:
+                self.prefix.lookups += 1
+                if m.matched > 0:
+                    self.prefix.hits += 1
+                    self.prefix.hit_tokens += m.matched
+            # saved = the matched positions: the pass computes none
+            self.prefill_tokens_saved += m.matched
+            self.prefill_flops_saved += m.matched * self._flops_per_token
 
     def _run_prefill(self, batch: list[tuple[int, Request, Match]]) -> None:
         from ddl25spring_tpu.obs import flight
@@ -1773,18 +1922,25 @@ class ServeEngine:
             "serve.prefill", **counts,
             rids=" ".join(str(req.rid) for _, req, _ in batch),
         ) as span:
-            self.pool, first, ok = self._prefill(
+            self.pool, packed, self._key = self._prefill(
                 self.params, self.pool, jnp.asarray(prompts),
                 jnp.asarray(lens), jnp.asarray(starts),
-                jnp.asarray(slot_ids),
-                self._split_key(),
+                jnp.asarray(slot_ids), self._key,
             )
+            self._seat(batch)
+            if self._runs_ahead():
+                # the first tokens are the tick's inputs, on the device
+                self._in_flight = self._dispatch_tick(ahead=True)
             # one fetch: the sampled tokens and what rides behind them
-            first, probe = self._split_pass(span, jax.device_get(first), B, t0)
+            first, probe, table, ok = self._split_pass(
+                span, jax.device_get(packed), B, t0, self._prompt_entries
+            )
             span.add(**{
                 name.rsplit(".", 1)[-1]: v for name, v in seated.items()
             })
-        if not bool(ok):
+        if self._in_flight is not None:
+            self._in_flight.t_start = time.perf_counter()
+        if not ok:
             self.pool_ok_failures += 1
         if self.spec_k:
             # the drafter prefills its OWN pool over the same batch,
@@ -1802,14 +1958,15 @@ class ServeEngine:
                 whole[row, : req.prompt_len] = req.prompt
             with self._span("serve.draft_prefill", rows=len(batch),
                             pass_rows=d_rows, width=d_width):
-                self.draft_pool, _draft_first, ok_d = self._draft_prefill(
+                self.draft_pool, drafted, _key = self._draft_prefill(
                     self.draft_params, self.draft_pool,
                     jnp.asarray(whole),
                     jnp.asarray(lens), jnp.zeros((d_rows,), jnp.int32),
                     jnp.asarray(slot_ids), self._zero_key,
                 )
-            if not bool(ok_d):
-                self.pool_ok_failures += 1
+                # its pool flag rides last in its vector
+                if not np.asarray(jax.device_get(drafted))[-1]:
+                    self.pool_ok_failures += 1
         wall = time.perf_counter() - t0
         self._prefills += 1
         # the virtual clock charges a pass by its width (a full-width
@@ -1837,25 +1994,6 @@ class ServeEngine:
                 req.admitted_t = now
                 req.prefill_start_t = t_pre
                 req.prefill_s = prefill_cost
-                self.slots[slot] = req
-                self._slot_last_rid[slot] = req.rid
-                # _adopted_pages[slot] was billed by _adopt_batch (S204:
-                # same method as the device refcount bump)
-                self._cached_pages[slot] = []
-                # mirror of the admission bill: full worst case under spec
-                # (the drafter pool's share-less need), discounted otherwise
-                self._reserved[slot] = self._pages_needed(req) - (
-                    0 if self.spec_k else m.n_ref
-                )
-                self.admitted += 1
-                if self.prefix is not None:
-                    self.prefix.lookups += 1
-                    if m.matched > 0:
-                        self.prefix.hits += 1
-                        self.prefix.hit_tokens += m.matched
-                # saved = the matched positions: the pass computes none
-                self.prefill_tokens_saved += m.matched
-                self.prefill_flops_saved += m.matched * self._flops_per_token
                 # the drafter owes this first committed token its KV; a
                 # request that completes at this very token is released by
                 # the flush, which clears the pending list with the slot
@@ -1883,10 +2021,11 @@ class ServeEngine:
                     prefill_s=round(prefill_cost, 6),
                     first_decode_s=round(first_decode, 6),
                 )
+                self._owed[slot] -= 1
                 self._emit_token(slot, req, int(first[row]), now,
                                  None if probe is None else probe[row])
             if self.prefix is not None:
-                self._insert_prefixes(batch)
+                self._insert_prefixes(batch, table)
             self._track_pages()
         flight.record(
             kind="serve_prefill", step=self._prefills, wall_s=round(wall, 6),
@@ -1920,39 +2059,51 @@ class ServeEngine:
             )
 
     def _run_decode_tick(self) -> None:
+        """Land one decode tick: the one dispatched ahead, else a fresh
+        one.  Before its fetch, the next tick goes to the device wherever
+        :meth:`_runs_ahead` allows, so that the device runs it while the
+        host fetches and emits this one's tokens.  A tick's wall sample
+        runs from its dispatch to its fetch, or for a tick dispatched
+        ahead from the previous program's fetch to its own: the interval
+        between two results a client sees."""
         from ddl25spring_tpu.obs import flight
 
-        toks = jnp.asarray(
-            np.asarray(self._slot_last_tok, np.int32)
-        )
-        counts = self._tick_counts()
-        t0 = time.perf_counter()
-        with self._span("serve.decode_tick", **counts) as span:
-            self.pool, new_tok, ok = self._tick(
-                self.params, self.pool, toks, self._split_key()
+        t_enter = time.perf_counter()
+        with self._span("serve.decode_tick") as span:
+            tick = self._in_flight or self._dispatch_tick(
+                ahead=False, t_start=t_enter
             )
-            new_tok, probe = self._split_pass(
-                span, jax.device_get(new_tok), self.max_slots, t0
+            span.add(**tick.counts, ahead=int(tick.ahead))
+            self._in_flight = None
+            if self._runs_ahead():
+                self._in_flight = self._dispatch_tick(ahead=True)
+            new_tok, probe, _table, ok = self._split_pass(
+                span, jax.device_get(tick.packed), self.max_slots,
+                tick.t_dispatch,
             )
-        wall = time.perf_counter() - t0
-        if not bool(ok):
+        t_fetch = time.perf_counter()
+        if self._in_flight is not None:
+            self._in_flight.t_start = t_fetch
+        wall = t_fetch - tick.t_start
+        if not ok:
             self.pool_ok_failures += 1
         self.tick_wall_s.append(wall)
         self._ticks += 1
         self._advance(self.tick_s)
         now = self.now()
         with self._span("serve.emit"):
-            for slot, req in enumerate(self.slots):
-                if req is not None:
-                    self._emit_token(slot, req, int(new_tok[slot]), now,
-                                     None if probe is None else probe[slot])
+            for slot, req in tick.rows:
+                self._owed[slot] -= 1
+                self._emit_token(slot, req, int(new_tok[slot]), now,
+                                 None if probe is None else probe[slot])
             self._track_pages()
         if self._ticks % 8 == 0 or self._ticks <= 2:
             # active and queue: what the tick STARTED with, as its span
             flight.record(
                 kind="serve_tick", step=self._ticks,
-                wall_s=round(wall, 6), active=counts["active"],
-                queue=counts["queue"], pages_used=self._host_pages_used(),
+                wall_s=round(wall, 6), active=tick.counts["active"],
+                queue=tick.counts["queue"],
+                pages_used=self._host_pages_used(),
             )
 
     def _run_spec_round(self) -> None:
@@ -1993,7 +2144,10 @@ class ServeEngine:
         draft_fn = self._draft_k1 if steps == k + 1 else self._draft_k
 
         jlim = jnp.asarray(limits)
-        counts = self._tick_counts()
+        t = time.perf_counter()
+        counts = self._tick_counts(t)
+        # a round is counted as a tick, and none goes ahead of a fetch
+        self._sample("serve.tick_ahead", 0, t)
         t0 = time.perf_counter()
         with self._span("serve.draft", steps=steps, **counts):
             self.draft_pool, drafts_dev, ok_d = draft_fn(
@@ -2127,7 +2281,10 @@ class ServeEngine:
             if req is None:
                 used += self._pending_pages[slot]
                 continue
-            written = req.prompt_len + max(len(req.tokens) - 1, 0)
+            # a token a dispatched pass owes the slot counts as generated:
+            # the device holds what that pass writes
+            sampled = len(req.tokens) + self._owed[slot]
+            written = req.prompt_len + max(sampled - 1, 0)
             used += self._slot_fresh_pages(slot, written)
         return used
 
@@ -2187,16 +2344,27 @@ class ServeEngine:
     @property
     def drained(self) -> bool:
         """True once a draining replica holds no live work: every slot
-        released and nothing queued (the queue was handed off at
-        ``begin_drain``; rejects-at-the-door keep it empty after)."""
-        return all(r is None for r in self.slots) and not self.queue
+        released, nothing queued (the queue was handed off at
+        ``begin_drain``; rejects-at-the-door keep it empty after) and no
+        tick on the device whose tokens are not fetched."""
+        return (all(r is None for r in self.slots) and not self.queue
+                and self._in_flight is None)
 
     def step(self) -> bool:
         """One scheduler iteration: flush releases, admit + prefill,
-        then one packed decode tick.  Returns True when any program
-        ran (False = fully idle)."""
+        then one packed decode tick (the one a previous step dispatched
+        ahead, where there is one: :meth:`_run_decode_tick`).  Returns
+        True when any program ran (False = fully idle)."""
         with self._span("serve.step"):
             ran = False
+            if self._in_flight is not None and self._may_admit():
+                # an arrival met a tick dispatched ahead (an open loop):
+                # land it (no other goes ahead while a request is
+                # admittable) before the flush, which releases what it
+                # completes, so that the pass has nothing unfetched ahead
+                # of it and admission sees the pages as they are
+                self._run_decode_tick()
+                ran = True
             self._flush_releases()
             self.queue_depths.append(len(self.queue))
             with self._accounting_span("serve.admit", queue=len(self.queue)):
@@ -2744,11 +2912,7 @@ def describe(mesh, program: str = "decode", model_axis: str = "model",
     # one pass, of any width: 2 row-parallel psums per block
     ar_count = 2 * cfg.n_layers
     if program == "decode":
-        args = (
-            params, pool,
-            jnp.ones((max_slots,), jnp.int32),
-            jax.random.PRNGKey(1),
-        )
+        args = (params, pool, jax.random.PRNGKey(1))
         ar_positions = max_slots
         lowered = "decode_step"
     else:

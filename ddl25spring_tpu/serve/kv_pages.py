@@ -105,8 +105,12 @@ __all__ = [
 ]
 
 # the pool's bookkeeping entries; every other entry is a plane, but for
-# the one entry that holds the model's slot state (a dict of arrays)
-ACCOUNTING = ("page_table", "seq_len", "active", "free", "refcount")
+# the one entry that holds the model's slot state (a dict of arrays).
+# ``last_tok`` is each slot's newest sampled token, the one the next
+# decode tick appends: the passes write it and read it, the host never
+# uploads it
+ACCOUNTING = ("page_table", "seq_len", "active", "free", "refcount",
+              "last_tok")
 SLOT_STATE = "slot_state"
 
 
@@ -219,6 +223,7 @@ def init_page_pool(
         "page_table": jnp.full((max_slots, pages_per_seq), -1, jnp.int32),
         "seq_len": jnp.zeros((max_slots,), jnp.int32),
         "active": jnp.zeros((max_slots,), bool),
+        "last_tok": jnp.zeros((max_slots,), jnp.int32),
         # free is kept exactly == (refcount == 0) by every mutator; the
         # redundancy buys the allocation argsort a bool mask and keeps
         # the PR-10 pool contract (`~pool["free"]` = used) intact

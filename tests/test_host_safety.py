@@ -457,9 +457,7 @@ def test_decode_tick_hlo_identical_with_sanitizer_toggled(
     pool = kv_pages.init_page_pool(
         CFG, n_pages=16, page_len=4, max_slots=2, pages_per_seq=4,
     )
-    args = (
-        params, pool, jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(0),
-    )
+    args = (params, pool, jax.random.PRNGKey(0))
 
     def lower(flag: str):
         monkeypatch.setenv("DDL25_SANITIZE", flag)

@@ -1,7 +1,7 @@
 """The pool's accounting ops run on the accounting arrays alone.
 
 ``release``, ``ref``, ``unref`` and ``truncate`` read and write only
-``kv_pages.ACCOUNTING``; the engine hands their programs those five arrays
+``kv_pages.ACCOUNTING``; the engine hands their programs those six arrays
 (``ServeEngine._account``) and merges the result back on the host, so
 
 - no plane is a parameter or a result of the program that is dispatched,
@@ -142,8 +142,8 @@ def test_the_dispatched_program_has_no_plane_among_its_avals(
         assert not (ins | outs) & plane_shapes(eng), (ins, outs)
         assert set(seen[0]) == set(kv_pages.ACCOUNTING)
         assert set(lowered.out_info) == set(kv_pages.ACCOUNTING)
-        # five accounting arrays and the op's own arguments, nothing else
-        assert len(jax.tree.leaves(lowered.in_avals)) == 5 + len(args)
+        # six accounting arrays and the op's own arguments, nothing else
+        assert len(jax.tree.leaves(lowered.in_avals)) == 6 + len(args)
 
 
 # --------------------------------- (b) the planes are the buffers they were

@@ -109,7 +109,7 @@ def serve_programs(named: bool):
         )
         key = jax.random.PRNGKey(0)
         tick = jax.jit(make_decode_tick(CFG, sentinel=False)).lower(
-            params, pool, jnp.zeros((2,), jnp.int32), key
+            params, pool, key
         )
         prefill = jax.jit(
             make_prefill(CFG, max_prompt_len=8, sentinel=False)

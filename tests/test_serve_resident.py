@@ -213,15 +213,15 @@ def args_of(name: str, params):
     """A pool and the arguments after it: for a pass, a full batch of its
     shape, prompts of different lengths, the first across a page boundary;
     for the tick, the pages a two-row pass left (written with ``params``)
-    and two live rows."""
+    and two live rows, whose tokens the pass left in the pool."""
     if name != "tick":
         rows, width = name[len("prefill"):].split("x")
         return fresh_pool(), prompt_batch(int(rows), int(width))
-    pool, _first, ok = jitted("prefill")(
+    pool, packed, _key = jitted("prefill")(
         params, fresh_pool(), *prompt_batch(2, MAX_PROMPT // 2)
     )
-    assert bool(ok)
-    return pool, (jnp.asarray([5, 9, 0, 0], jnp.int32), jax.random.PRNGKey(2))
+    assert int(packed[-1]) == 1  # the pool flag rides last
+    return pool, (jax.random.PRNGKey(2),)
 
 
 def prompt_batch(rows: int, width: int):
@@ -250,14 +250,15 @@ def test_pass_on_resident_weights_is_bitwise_the_pass_on_masters(
     every page the pass wrote."""
     fn = jitted(name)
     pool, args = args_of(name, masters)  # both sides start from one pool
-    want_pool, want, ok_m = fn(masters, pool, *args)
-    got_pool, got, ok_r = fn(resident, pool, *args)
-    assert bool(ok_m) and bool(ok_r)
+    want_pool, want, _key = fn(masters, pool, *args)
+    got_pool, got, _key = fn(resident, pool, *args)
+    assert int(want[-1]) == int(got[-1]) == 1  # the pool flag rides last
     same_bits(got, want)
     same_bits(got_pool, want_pool)
     n = SLOTS if name == "tick" else args[0].shape[0]
     live = 2 if name == "tick" else n
-    logits = np.asarray(want[n:]).view(np.float32).reshape(n, CFG.vocab_size)
+    probe = np.asarray(want[n: n + n * CFG.vocab_size])
+    logits = probe.view(np.float32).reshape(n, CFG.vocab_size)
     assert np.isfinite(logits).all() and np.ptp(logits[0]) > 0.1
     np.testing.assert_array_equal(
         np.asarray(want[:n])[:live], logits.argmax(-1)[:live]
@@ -428,7 +429,7 @@ def test_a_model_served_in_its_own_type_offers_the_identity():
         cfg, n_pages=8, page_len=4, max_slots=2, pages_per_seq=4
     )
     tick = jax.jit(make_decode_tick(cfg))
-    args = (pool, jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(0))
+    args = (pool, jax.random.PRNGKey(0))
     assert tick.lower(eng.params, *args).as_text() == tick.lower(
         params, *args
     ).as_text()

@@ -353,9 +353,10 @@ def test_tp_describe_budgets_shrink(strategy_report, name):
     assert peak2 < peak1, (peak2, peak1)
     budget = r2["expected"]["memory"]["max_peak_hbm_bytes"]
     assert peak2 <= budget < peak1, (peak2, budget, peak1)
-    # per-chip residency: pure shape math, pinned exact
-    assert r1["meta"]["pool_bytes_per_chip"] == 17572
-    assert r2["meta"]["pool_bytes_per_chip"] == 8868
+    # per-chip residency: pure shape math, pinned exact (the 16 bytes of
+    # the slots' last tokens, replicated, on each chip)
+    assert r1["meta"]["pool_bytes_per_chip"] == 17588
+    assert r2["meta"]["pool_bytes_per_chip"] == 8884
     assert r1["meta"]["param_bytes_per_chip"] == 41280
     assert r2["meta"]["param_bytes_per_chip"] == (
         24768 if name == "serve-decode-zero3stream" else 24896

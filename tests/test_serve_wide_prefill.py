@@ -129,11 +129,12 @@ def test_wide_pass_equals_the_one_token_step(params, step, case):
         packed[s, : lens[s] - starts[s]] = p[starts[s]:]
     prefill = jax.jit(make_prefill(CFG, max_prompt_len=MAX_PROMPT,
                                    sentinel=False))
-    got, first, ok = prefill(
+    got, out, _key = prefill(
         params, pool, jnp.asarray(packed), jnp.asarray(lens),
         jnp.asarray(starts), jnp.asarray(slot_ids), jax.random.PRNGKey(0),
     )
-    assert bool(ok)
+    assert int(out[-1]) == 1  # the pool flag rides last
+    first = out[:SLOTS]
 
     # the same pages in the same tables (the one reservation allocates
     # in the order the position-by-position walk did) ...
@@ -149,14 +150,20 @@ def test_wide_pass_equals_the_one_token_step(params, step, case):
     assert int((~np.asarray(got["free"])).sum()) == written
     # the first tokens, and the greedy stream after them
     np.testing.assert_array_equal(np.asarray(first)[valid], ref_first[valid])
+    # which the pass left at its slots for the tick to read
+    np.testing.assert_array_equal(
+        np.asarray(got["last_tok"])[valid], ref_first[valid]
+    )
     tick = jax.jit(make_decode_tick(CFG, sentinel=False))
-    a = b = jnp.asarray(np.where(valid, ref_first, 0).astype(np.int32))
+    ref = {**ref, "last_tok": jnp.asarray(
+        np.where(valid, ref_first, 0).astype(np.int32)
+    )}
     for _ in range(6):
-        got, a, ok_a = tick(params, got, a, jax.random.PRNGKey(0))
-        ref, b, ok_b = tick(params, ref, b, jax.random.PRNGKey(0))
-        assert bool(ok_a) and bool(ok_b)
-        np.testing.assert_array_equal(np.asarray(a)[valid],
-                                      np.asarray(b)[valid])
+        got, a, _ = tick(params, got, jax.random.PRNGKey(0))
+        ref, b, _ = tick(params, ref, jax.random.PRNGKey(0))
+        assert int(a[-1]) == int(b[-1]) == 1
+        np.testing.assert_array_equal(np.asarray(a)[:SLOTS][valid],
+                                      np.asarray(b)[:SLOTS][valid])
 
 
 @pytest.mark.parametrize("lens", [
@@ -193,12 +200,13 @@ def test_wide_pass_writes_the_dense_paths_keys_and_values(params, lens):
     pool = kv_pages.activate_slots(
         fresh_pool(), jnp.asarray(slot_ids), jnp.asarray(valid)
     )
-    pool, first, ok = prefill(
+    pool, out, _key = prefill(
         params, pool, jnp.asarray(packed), jnp.asarray(lens),
         jnp.zeros((SLOTS,), jnp.int32), jnp.asarray(slot_ids),
         jax.random.PRNGKey(0),
     )
-    assert bool(ok)
+    assert int(out[-1]) == 1  # the pool flag rides last
+    first = out[:SLOTS]
     np.testing.assert_array_equal(np.asarray(first)[valid], want_first[valid])
     table = np.asarray(pool["page_table"])
     for key, want in zip(("k", "v"), cache):
@@ -227,14 +235,14 @@ def test_a_pool_too_small_fails_the_pass_whole(params):
     args = (jnp.asarray(packed), jnp.asarray(lens),
             jnp.zeros((SLOTS,), jnp.int32), jnp.asarray(slot_ids),
             jax.random.PRNGKey(0))
-    pool, _first, ok = prefill(params, fresh_pool(n_pages=5), *args)
-    assert not bool(ok)
+    pool, out, _key = prefill(params, fresh_pool(n_pages=5), *args)
+    assert int(out[-1]) == 0  # the pool flag rides last
     assert bool(np.asarray(pool["free"]).all())
     assert int(np.asarray(pool["refcount"]).sum()) == 0
     assert (np.asarray(pool["page_table"]) == -1).all()
     # one page more and the same pass fits
-    pool, _first, ok = prefill(params, fresh_pool(n_pages=6), *args)
-    assert bool(ok) and int((~np.asarray(pool["free"])).sum()) == 6
+    pool, out, _key = prefill(params, fresh_pool(n_pages=6), *args)
+    assert int(out[-1]) == 1 and int((~np.asarray(pool["free"])).sum()) == 6
 
 
 # --------------------------------------------------------- the ladder
@@ -350,13 +358,15 @@ def test_a_lone_request_leaves_at_the_small_shape_what_it_leaves_at_the_full(
         lens[0] = n
         slot_ids = np.full((rows,), -1, np.int32)
         slot_ids[0] = 2
-        pool, first, ok = prefill(
+        pool, out, _key = prefill(
             params, fresh_pool(), jnp.asarray(packed), jnp.asarray(lens),
             jnp.zeros((rows,), jnp.int32), jnp.asarray(slot_ids),
             jax.random.PRNGKey(0),
         )
-        assert bool(ok) and first.shape == (rows,)
-        return pool, int(first[0])
+        assert int(out[-1]) == 1  # the pool flag rides last
+        # the first tokens, each row's table entries, the flag
+        assert out.shape == (rows + rows * MAX_PROMPT // PAGE_LEN + 1,)
+        return pool, int(out[0])
 
     small, full = shape_for(shapes, 1, n), shapes[-1]
     assert small == (1, 32) and full == (SLOTS, MAX_PROMPT)
@@ -371,10 +381,9 @@ def test_a_lone_request_leaves_at_the_small_shape_what_it_leaves_at_the_full(
             err_msg=key,
         )
     tick = jax.jit(make_decode_tick(CFG, sentinel=False))
-    a = b = jnp.zeros((SLOTS,), jnp.int32).at[2].set(first)
     for _ in range(6):
-        got, a, _ = tick(params, got, a, jax.random.PRNGKey(0))
-        ref, b, _ = tick(params, ref, b, jax.random.PRNGKey(0))
+        got, a, _ = tick(params, got, jax.random.PRNGKey(0))
+        ref, b, _ = tick(params, ref, jax.random.PRNGKey(0))
         assert int(a[2]) == int(b[2])
 
 
@@ -399,11 +408,14 @@ def test_radix_hits_ride_a_narrower_pass_than_their_cold_twin(params):
 
 @pytest.mark.parametrize("kw", [
     dict(prefix_cache=True), dict(spec_k=2),
-], ids=["prefix_cache", "drafter"])
+    dict(prefix_cache=True, clock="wall"),
+], ids=["prefix_cache", "drafter", "run_ahead"])
 def test_nothing_compiles_after_warmup(params, kw):
     """Every shape of the ladder (the drafter's passes too), a radix hit
     with a COW'd partial page and the decode tick or speculative round run
-    on programs ``warmup()`` already compiled."""
+    on programs ``warmup()`` already compiled; on the wall clock too,
+    where a tick goes to the device behind a pass, on its key and its
+    pool."""
     eng = make_engine(params, prefill_batch=4, **kw)
     eng.warmup()
     shapes = pass_shapes(eng.prefill_batch, MAX_PROMPT)

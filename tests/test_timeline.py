@@ -24,7 +24,6 @@ from __future__ import annotations
 import json
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -340,9 +339,7 @@ def test_decode_tick_hlo_identical_when_disabled(params):
     pool = kv_pages.init_page_pool(
         CFG, n_pages=16, page_len=4, max_slots=2, pages_per_seq=4,
     )
-    args = (
-        params, pool, jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(0),
-    )
+    args = (params, pool, jax.random.PRNGKey(0))
 
     def lower():
         tick = make_decode_tick(CFG, temperature=0.0, sentinel=False)
